@@ -8,19 +8,6 @@ and pallas kernels for the fused hot ops.
 """
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax<0.5 ships shard_map only under experimental; the framework (and
-    # its tests) use the stable jax.shard_map spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _jax.shard_map = _shard_map
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax<0.6 has no explicit replicated->varying cast; its shard_map
-    # infers replication instead, so the cast is an identity there
-    _jax.lax.pcast = lambda x, axes=None, to=None, **_kw: x
-
 # core
 from .core.tensor import Tensor, Parameter, to_tensor  # noqa: F401
 from .core.autograd import no_grad, enable_grad, grad  # noqa: F401

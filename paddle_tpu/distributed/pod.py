@@ -27,11 +27,13 @@ death a *detected, recoverable* event for the survivors:
 - **Host collectives** (:meth:`PodRuntime.allreduce`): gather-sum-
   broadcast through the coordinator in float64 with a deterministic
   (rank-sorted) reduction order. This is the cross-process data-parallel
-  gradient path on backends whose XLA build has no cross-process
-  collectives (jaxlib < 0.5 CPU — the virtual-pod CI reality); on real
-  multi-host TPU the same runtime layers *under*
-  ``jax.distributed.initialize`` (``jax_init="auto"``) and XLA carries
-  the tensor traffic while the pod carries liveness + control.
+  gradient path of an ELASTIC pod: jax's own coordination service has a
+  fixed world and terminates every survivor when a peer stops
+  heartbeating, so it cannot sit under a pod that re-forms
+  (``jax_init="never"``, the default). A pod that never re-forms may
+  layer ``jax.distributed.initialize`` underneath
+  (``jax_init="always"``): XLA then carries the tensor traffic while
+  the pod carries liveness + control.
 - **Elastic re-formation** (:meth:`PodRuntime.reform`): after a failure
   the survivors re-form at the smaller world size — dense re-rank, new
   generation, fresh leases — and drive the PR-7 elastic restore path
@@ -174,6 +176,7 @@ class PodCoordinator(socketserver.ThreadingTCPServer):
         self._reform_result = {}  # old gen -> {"gen", "map"}
         self._lobby = {}     # origin -> joiner info, parked until reform
         self._admitted = {}  # origin -> {"gen","rank","world"} (post-reform)
+        self._joined = set()  # origins whose join ever reached this service
         self._hb_gaps = {}   # origin -> deque of heartbeat gaps (seconds)
         self._straggling = set()  # origins currently past the threshold
         self._cond = lockwatch.Condition(name="pod.coordinator")
@@ -213,6 +216,13 @@ class PodCoordinator(socketserver.ThreadingTCPServer):
                 {"origin": origin, "reason": reason, "t": time.time(),
                  "member": False})
         return False
+
+    def ever_joined(self, origin):
+        """Did ORIGIN's join ever reach this service (member or lobby)?
+        A child reaped before that died at import or build: the pod it
+        belongs to can never form, and no lease will ever say so."""
+        with self._cond:
+            return origin in self._joined
 
     def state(self):
         with self._cond:
@@ -409,6 +419,7 @@ class PodCoordinator(socketserver.ThreadingTCPServer):
         nprocs = int(req["nprocs"])
         deadline = time.time() + float(req.get("timeout", 60.0))
         with self._cond:
+            self._joined.add(int(req.get("origin", rank)))
             formed = (self.expected is not None
                       and len(self._members) >= self.expected) \
                 or self.gen != 0
@@ -830,7 +841,7 @@ class PodRuntime:
     def __init__(self, coordinator, num_processes, process_id, *,
                  heartbeat_interval=0.5, lease_ttl=None,
                  barrier_timeout=60.0, join_timeout=60.0,
-                 jax_init="auto"):
+                 jax_init="never"):
         self.coordinator = coordinator
         self.num_processes = int(num_processes)
         self.origin = int(process_id)
@@ -838,6 +849,9 @@ class PodRuntime:
         self.lease_ttl = lease_ttl  # served back by the coordinator
         self.barrier_timeout = float(barrier_timeout)
         self.join_timeout = float(join_timeout)
+        if jax_init not in ("never", "always"):
+            raise ValueError(
+                f"jax_init must be 'never' or 'always', got {jax_init!r}")
         self.jax_init = jax_init
         self.uid = None
         self._lock = lockwatch.RLock(name="pod.runtime")
@@ -941,13 +955,10 @@ class PodRuntime:
         return self
 
     def _maybe_init_jax(self):
-        """Layer ``jax.distributed.initialize`` under the pod when the
-        backend can actually carry cross-process collectives.
-        ``jax_init``: "auto" (skip on pre-0.5 CPU — the known jaxlib
-        gap), "always", or "never"."""
+        """Layer ``jax.distributed.initialize`` under the pod
+        (``jax_init="always"``; see the module docstring for why an
+        elastic pod keeps the default ``"never"``)."""
         if self.jax_init == "never" or self.num_processes < 2:
-            return
-        if self.jax_init == "auto" and not _jax_cross_process_capable():
             return
         addr = os.environ.get("JAX_COORDINATOR_ADDRESS")
         if not addr:
@@ -1258,6 +1269,7 @@ class PodSupervisor:
         self._incarnation = {}     # origin -> spawn count (1 = original)
         self._pending_respawn = {}  # origin -> earliest respawn time
         self._closing = False      # terminate() in progress: no respawns
+        self._startup_death = None  # first RankExit reaped before its join
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
@@ -1377,6 +1389,9 @@ class PodSupervisor:
             if ret != 0:
                 reason = (f"killed by {ex.signal}" if ex.signal
                           else f"exited with code {ret}")
+                if not (self._closing or self._startup_death
+                        or self.coordinator.ever_joined(tp.rank)):
+                    self._startup_death = ex
                 self.coordinator.mark_failed(tp.rank, reason)
                 self._schedule_respawn(tp.rank, reason)
         self._spawn_due_respawns(alive)
@@ -1391,6 +1406,15 @@ class PodSupervisor:
         deadline = time.time() + float(timeout)
         while True:
             alive = self.watch_once()
+            if self._startup_death is not None:
+                # a child died at import or build, before its join: its
+                # peers would sit out their join/lease timeouts waiting
+                # for a rank that never existed — fail NOW, with its log
+                self.terminate()
+                raise PodError(
+                    f"{self._startup_death!r} died before joining the "
+                    f"pod (import or build failure). Logs under "
+                    f"{self.log_dir}: " + self.tail_logs())
             if not alive and not self._pending_respawn:
                 return dict(self.exits)
             if time.time() > deadline:
@@ -1477,19 +1501,3 @@ class PodSupervisor:
 def _repo_root():
     return os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-
-
-def _jax_cross_process_capable():
-    """Can THIS jax build run cross-process collectives on the selected
-    backend? jaxlib < 0.5 cannot on CPU (the documented container gap);
-    any non-CPU platform is assumed capable."""
-    try:
-        import jax
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    except Exception:
-        return False
-    platform = (os.environ.get("JAX_PLATFORMS")
-                or os.environ.get("JAX_PLATFORM_NAME") or "")
-    if platform and platform != "cpu":
-        return True
-    return ver >= (0, 5)

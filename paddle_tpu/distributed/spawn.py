@@ -149,6 +149,12 @@ class _Context:
                 p.join(join_s)
                 if p.is_alive():
                     p.terminate()
+                    # jax.distributed installs a SIGTERM (preemption)
+                    # handler: a rank inside it outlives terminate() by
+                    # ~100 s unless the reaping escalates
+                    p.join(2.0)
+                    if p.is_alive():
+                        p.kill()
         errors = [f"rank {r} failed:\n{payload}"
                   for r, (_, status, payload) in sorted(out.items())
                   if status == "error"]

@@ -196,6 +196,10 @@ class TelemetryCallback(Callback):
     ``flops_per_token``: per-model override (``model.flops_per_token(seq)``)
     — exact attention-aware MFU accounting; takes precedence over the
     6*N*T estimate.
+    ``peak_flops``: the chip's peak; when None it is looked up by the
+    attached device's kind (observability/step.py PEAK_BF16_FLOPS). On a
+    device with no published peak the implicit estimate is skipped and
+    an explicit ``flops_per_*`` request is an error.
     """
 
     def __init__(self, tokens_per_batch=None, examples_per_batch=None,
@@ -231,10 +235,17 @@ class TelemetryCallback(Callback):
     def on_begin(self, mode, logs=None):
         if mode != "train":
             return
-        from ..observability.step import StepTimer
+        import jax
+
+        from ..observability.step import PEAK_BF16_FLOPS, StepTimer
         flops = self.flops_per_step
+        # the implicit 6*N*T estimate needs a peak to divide by: stated
+        # by the caller, or published for the attached device — on any
+        # other device there is no MFU to report
+        peak_known = (self.peak_flops is not None
+                      or jax.devices()[0].device_kind in PEAK_BF16_FLOPS)
         if (flops is None and self.flops_per_token is None
-                and self.tokens_per_batch):
+                and self.tokens_per_batch and peak_known):
             n = self._n_params()
             flops = 6.0 * n * self.tokens_per_batch if n else None
         self.timer = StepTimer(window=self.window,

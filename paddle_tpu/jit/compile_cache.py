@@ -7,12 +7,19 @@ compile options + backend version, so a restart replays the compile from
 disk. This module owns the policy:
 
 - ``configure_from_env()`` runs at ``paddle_tpu`` import and only RECORDS
-  the policy (env vars below) — it must not touch the backend, because
+  the on/off switch — it must not touch the backend, because
   ``import paddle_tpu`` stays backend-clean for multi-process init.
 - ``ensure_enabled()`` runs at first ``to_static`` build, when the backend
   is initialized anyway: default ON for accelerators, OFF for CPU smoke
-  (cache writes would churn on every tiny test program). An explicit env
-  dir/switch overrides the backend default in either direction.
+  (cache writes would churn on every tiny test program) unless
+  ``JAX_COMPILATION_CACHE_DIR`` asks for one. The switch overrides the
+  default in either direction.
+- placement: where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own
+  setting places the cache and this module sets no directory in code;
+  where it is not, the cache lives at :data:`DEFAULT_CACHE_DIR`, a fixed
+  path inside the checkout: a directory that moves (home, temp name,
+  pid) never hits, and a machine that keeps only the checkout keeps
+  only this.
 - cache effectiveness is observable: jax's ``/jax/compilation_cache/*``
   monitoring events are mirrored into the shared monitor registry
   (``jit_persistent_cache_hits`` / ``_misses`` / ``_saved_ns``) next to
@@ -22,8 +29,7 @@ disk. This module owns the policy:
 Env:
     PADDLE_TPU_COMPILE_CACHE       "1"/"on" force-enable (any backend),
                                    "0"/"off" disable.
-    PADDLE_TPU_COMPILE_CACHE_DIR   cache directory; setting it implies
-                                   enable. Default ~/.cache/paddle_tpu/xla.
+    JAX_COMPILATION_CACHE_DIR      jax's own placement; wins when set.
 """
 import os
 
@@ -31,23 +37,19 @@ __all__ = ["configure_from_env", "ensure_enabled", "enable", "disable",
            "is_enabled", "cache_dir", "DEFAULT_CACHE_DIR"]
 
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "paddle_tpu", "xla")
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _ENV_SWITCH = "PADDLE_TPU_COMPILE_CACHE"
-_ENV_DIR = "PADDLE_TPU_COMPILE_CACHE_DIR"
+_ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 # policy: None = decide from backend at first compile; True/False = forced
-_state = {"policy": None, "dir": DEFAULT_CACHE_DIR, "enabled": False,
-          "resolved": False}
+_state = {"policy": None, "enabled": False, "resolved": False}
 _events_installed = [False]
 
 
 def configure_from_env():
-    """Record the env policy (import-time safe: no jax backend access)."""
-    d = os.environ.get(_ENV_DIR)
-    if d:
-        _state["dir"] = d
-        _state["policy"] = True
+    """Record the env switch (import-time safe: no jax backend access)."""
     switch = os.environ.get(_ENV_SWITCH, "").strip().lower()
     if switch in ("1", "on", "true", "yes"):
         _state["policy"] = True
@@ -61,10 +63,8 @@ def _install_event_mirror():
     has no unregister-one API, so install once and gate on enabled."""
     if _events_installed[0]:
         return
-    try:
-        from jax import monitoring as _jm
-    except Exception:
-        return
+    from jax import monitoring as _jm
+
     from .. import monitor
 
     def _on_event(event, **kwargs):
@@ -89,15 +89,23 @@ def _install_event_mirror():
 
 def enable(directory=None, min_compile_time_secs=None):
     """Turn the persistent cache on (explicit API; also used by
-    ``ensure_enabled``). ``min_compile_time_secs=0`` caches every program
-    — the right setting for tests; the jax default (1s) skips trivial
-    programs in production."""
+    ``ensure_enabled``). ``directory`` places it for this process (tests,
+    a trainer that owns its cache); with none given the placement rule of
+    the module docstring applies. ``min_compile_time_secs=0`` caches every
+    program — the right setting for tests; the jax default (1s) skips
+    trivial programs in production."""
     import jax
 
-    if directory is not None:
-        _state["dir"] = directory
-    os.makedirs(_state["dir"], exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", _state["dir"])
+    if os.environ.get(_ENV_JAX_DIR):
+        if directory is not None:
+            raise ValueError(
+                f"{_ENV_JAX_DIR} is set and places the compile cache; "
+                f"refusing to move it to {directory!r} in code")
+    else:
+        directory = directory or DEFAULT_CACHE_DIR
+        os.makedirs(directory, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_enable_compilation_cache", True)
     if min_compile_time_secs is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
@@ -108,26 +116,22 @@ def enable(directory=None, min_compile_time_secs=None):
     _state["enabled"] = True
     _state["resolved"] = True
     _install_event_mirror()
-    return _state["dir"]
+    return cache_dir()
 
 
 def _reset_jax_cache():
     """jax initializes its cache object ONCE per process and never
-    re-reads the config after that, so flipping the dir mid-process (a
+    re-reads the config after that, so flipping it mid-process (a
     long-lived trainer enabling the cache after warmup compiles, or the
     tests) needs an explicit re-init."""
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:
-        pass  # pre-reset jax: the import-time config still applies
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    _cc.reset_cache()
 
 
 def disable():
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_enable_compilation_cache", False)
     _reset_jax_cache()
     _state["enabled"] = False
     _state["resolved"] = True
@@ -135,18 +139,19 @@ def disable():
 
 def ensure_enabled():
     """Resolve the policy once, at first compile (backend already up):
-    accelerators default on, CPU defaults off, env overrides both."""
+    accelerators default on, CPU defaults off unless jax's own env
+    setting places a cache, the switch overrides both."""
     if _state["resolved"]:
         return _state["enabled"]
     policy = _state["policy"]
     if policy is None:
-        try:
-            import jax
-            policy = jax.default_backend() != "cpu"
-        except Exception:
-            policy = False
+        import jax
+        policy = (bool(os.environ.get(_ENV_JAX_DIR))
+                  or jax.default_backend() != "cpu")
     if policy:
         enable()
+    elif _state["policy"] is False:
+        disable()  # jax's env setting would otherwise cache on its own
     else:
         _state["resolved"] = True
     return _state["enabled"]
@@ -157,4 +162,8 @@ def is_enabled():
 
 
 def cache_dir():
-    return _state["dir"] if _state["enabled"] else None
+    """Where the cache lives while enabled (jax's own setting)."""
+    if not _state["enabled"]:
+        return None
+    import jax
+    return jax.config.jax_compilation_cache_dir

@@ -142,13 +142,6 @@ def _leaf_key(x):
         return ("static", repr(x))
 
 
-def _shard_map():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def jnp_issubdtype(dtype):
     """Inexact leaves are pmean-able; ints (indices, counters) must be
     rank-invariant already and pass through untouched."""
@@ -1260,12 +1253,12 @@ class StaticFunction:
             rog_specs = [_spec(i) for i in ro_grad_idx]
             # ys are pmean'd replicated in the body; final carry values
             # reassemble per their PartitionSpec
-            smapped = _shard_map()(
+            smapped = jax.shard_map(
                 pure_fn2, mesh=mesh,
                 in_specs=(cv_specs, cg_specs, list(xs_specs), ro_specs,
                           rog_specs),
                 out_specs=(PartitionSpec(), cv_specs, cg_specs),
-                check_rep=False)
+                check_vma=False)
             jitted = self._jit(smapped, donate_argnums=donate)
         else:
             jitted = self._jit(pure_fn2, donate_argnums=donate)

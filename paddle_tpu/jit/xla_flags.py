@@ -28,14 +28,15 @@ Flags ride ``jax.jit(..., compiler_options=...)``. XLA validates them at
 the FIRST CALL (or AOT compile), not at ``jit()`` time, and rejects
 options the backend doesn't register — ``xla_tpu_*`` flags on the CPU
 smoke mesh raise ``INVALID_ARGUMENT: No such compile option``. That is
-expected on the A/B's control host, so :class:`FlaggedJit` degrades
-gracefully: the unknown-flag error triggers ONE silent recompile
-without the options, and the fallback is recorded as provenance
-(``applied=False`` + the error) in :meth:`FlaggedJit.provenance`,
-bench-record metadata, and a ``xla_flags_fallback`` run-log event —
-the A/B row then says honestly that the treatment never applied,
-instead of comparing two identical programs. Any other compile error
-propagates.
+expected on the A/B's CPU control host, so there :class:`FlaggedJit`
+degrades: the unknown-flag error triggers ONE recompile without the
+options, and the fallback is recorded as provenance (``applied=False``
++ the error) in :meth:`FlaggedJit.provenance`, bench-record metadata,
+and a ``xla_flags_fallback`` run-log event — the A/B row then says
+honestly that the treatment never applied, instead of comparing two
+identical programs. On a TPU backend the options are the treatment: one
+the compiler rejects is an error, never a second compile. Any other
+compile error propagates.
 """
 import os
 
@@ -153,9 +154,13 @@ def resolve(xla_flags):
     return merge(base, env_flags())
 
 
-def _is_unknown_flag_error(exc):
+def _fallback_allowed(exc):
+    """An option the backend does not register — and a backend where
+    running without it is the designed control (not a TPU)."""
+    import jax
     msg = str(exc)
-    return "No such compile option" in msg or "Unknown flag" in msg
+    return (("No such compile option" in msg or "Unknown flag" in msg)
+            and jax.default_backend() != "tpu")
 
 
 _BACKEND_ACCEPTS = {}  # flag-set key -> bool, cached per process
@@ -180,7 +185,7 @@ def backend_accepts(flags):
                     compiler_options=dict(flags))(jnp.float32(0))
             _BACKEND_ACCEPTS[key] = True
         except Exception as e:
-            if not _is_unknown_flag_error(e):
+            if not _fallback_allowed(e):
                 raise
             _BACKEND_ACCEPTS[key] = False
     return _BACKEND_ACCEPTS[key]
@@ -212,7 +217,7 @@ class _FlaggedLowered:
                 owner.applied = True
                 return compiled
             except Exception as e:
-                if not _is_unknown_flag_error(e):
+                if not _fallback_allowed(e):
                     raise
                 owner._note_fallback(e)
         return self._lowered.compile()
@@ -255,7 +260,7 @@ class FlaggedJit:
                 self.applied = True
                 return out
             except Exception as e:
-                if not _is_unknown_flag_error(e):
+                if not _fallback_allowed(e):
                     raise
                 self._note_fallback(e)
         return self._jitted(*args, **kwargs)

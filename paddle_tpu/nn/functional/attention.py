@@ -9,9 +9,10 @@ import jax.numpy as jnp
 
 from ...core.dispatch import call_op
 
-# Measured crossover on v5e (BLOCK 128x128, head_dim 64): XLA's fused
-# attention wins up to ~1k tokens; the pallas flash kernel wins beyond
-# (1.1-1.3x at 2-4k) and keeps memory O(S) instead of O(S^2).
+# Crossover measured in rounds 2-4 on the shared v5e of that time (BLOCK
+# 128x128, head_dim 64; not re-measured since): XLA's fused attention won
+# up to ~1k tokens; the pallas flash kernel won beyond (1.1-1.3x at 2-4k)
+# and keeps memory O(S) instead of O(S^2).
 _FLASH_MIN_SEQ = 1024
 
 
@@ -23,17 +24,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
     q_shape = query.shape
     seq_len = q_shape[1]
-    use_flash = False
     dropout_inactive = dropout_p == 0.0 or not training
+    use_flash = False
     if dropout_inactive and attn_mask is None and seq_len >= _FLASH_MIN_SEQ:
-        try:
-            from ...kernels import flash_attention as _fa
-            use_flash = _fa.is_available()
-        except Exception:
-            use_flash = False
+        # imported here (pallas costs ~0.8 s per process) and unguarded: a
+        # kernel that fails to import, lower or compile on a TPU raises,
+        # nothing hands long sequences back to the XLA path quietly
+        from ...kernels import flash_attention as _fa
+        use_flash = _fa.is_available()
 
     if use_flash:
-        from ...kernels import flash_attention as _fa
 
         def _flash(q, k, v):
             return _fa.flash_attention_bshd(q, k, v, causal=is_causal,

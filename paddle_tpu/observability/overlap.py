@@ -478,7 +478,7 @@ def _eqn_compute_ns(eqn, hbm_gbps, peak_flops):
     model: dot/conv by the geometric-mean FLOP heuristic, everything
     else one FLOP per output element; collectives and data-movement ops
     score 0; call-like equations recurse."""
-    import jax
+    from jax.extend.core import Var
     prim = eqn.primitive.name
     if prim in _COLLECTIVE_PRIMS or prim in _MOVEMENT_PRIMS:
         return 0.0
@@ -501,7 +501,7 @@ def _eqn_compute_ns(eqn, hbm_gbps, peak_flops):
         flops = float(out_elems)
     nbytes = out_bytes + sum(
         _aval_bytes(v) for v in eqn.invars
-        if isinstance(v, jax.core.Var))
+        if isinstance(v, Var))
     return max(nbytes / hbm_gbps, flops / (peak_flops / 1e9))
 
 
@@ -544,6 +544,7 @@ def schedulable_stats(fun, example_args, mesh=None,
     via its ``_fun``); ``example_args`` are the abstract or concrete
     arguments of one call."""
     import jax
+    from jax.extend.core import Var
     inner = getattr(fun, "_fun", fun)
     jaxpr = jax.make_jaxpr(inner)(*example_args)
 
@@ -562,23 +563,23 @@ def schedulable_stats(fun, example_args, mesh=None,
                 continue
             nbytes = max(
                 [_aval_bytes(v) for v in list(eqn.outvars) + [
-                    i for i in eqn.invars if isinstance(i, jax.core.Var)
+                    i for i in eqn.invars if isinstance(i, Var)
                 ]] or [0])
             group = _prim_group_size(eqn, mesh)
             factor = RING_FACTORS.get(base, lambda _: 1.0)(group)
             coll_ns = nbytes * factor / link_gbps
             taint = {v for v in eqn.outvars
-                     if isinstance(v, jax.core.Var)}
+                     if isinstance(v, Var)}
             avail = 0.0
             for j in range(idx + 1, len(eqns)):
                 nxt = eqns[j]
                 p2 = nxt.primitive.name
                 tainted = any(iv in taint for iv in nxt.invars
-                              if isinstance(iv, jax.core.Var))
+                              if isinstance(iv, Var))
                 if p2 in _MOVEMENT_PRIMS:
                     if tainted:
                         taint.update(v for v in nxt.outvars
-                                     if isinstance(v, jax.core.Var))
+                                     if isinstance(v, Var))
                     continue
                 if tainted:
                     break  # first real consumer ends the window

@@ -19,16 +19,32 @@ Each ``step()`` publishes the current window to the export gauge board
 telemetry without the trainer doing anything else.
 """
 import collections
-import os
 import time
 
 from .. import monitor
 from . import export as export_mod
 
-__all__ = ["StepTimer", "DEFAULT_PEAK_FLOPS"]
+__all__ = ["StepTimer", "PEAK_BF16_FLOPS", "peak_bf16_flops"]
 
-# v5e bf16 peak; override per deployment via env or the peak_flops arg
-DEFAULT_PEAK_FLOPS = float(os.environ.get("PADDLE_TPU_PEAK_FLOPS", 197e12))
+# Published per-chip bf16 peak FLOP/s, keyed by jax's ``device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_bf16_flops(device_kind=None):
+    """The published bf16 peak of ``device_kind`` (default: the first
+    attached device). A device that is not in the table is an error,
+    never a default: a utilization against a guessed peak is no
+    measurement."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            f"no published bf16 peak for device kind {device_kind!r} "
+            f"(known: {sorted(PEAK_BF16_FLOPS)}); pass peak_flops= to "
+            "state one")
+    return PEAK_BF16_FLOPS[device_kind]
 
 _COMPILE_COUNTERS = ("jit_compile_ns", "executor_compile_ns",
                      "jit_backend_compile_ns")
@@ -60,7 +76,8 @@ class StepTimer:
         # set it takes precedence and MFU follows the window's actual
         # token count, so variable-size batches stay correct
         self.flops_per_token = flops_per_token
-        self.peak_flops = peak_flops or DEFAULT_PEAK_FLOPS
+        # None = look the attached device up at the first MFU reading
+        self.peak_flops = peak_flops
         self.publish_as = publish_as
         # (dt_s, tokens, examples, wait_ns, compile_ns) per completed step
         self._window = collections.deque(maxlen=self.window)
@@ -140,10 +157,13 @@ class StepTimer:
             out["tokens_per_s"] = tokens / wall
         if examples:
             out["examples_per_s"] = examples / wall
+        achieved = None
         if self.flops_per_token is not None and tokens and wall:
-            out["mfu"] = (self.flops_per_token * tokens / wall
-                          / self.peak_flops)
+            achieved = self.flops_per_token * tokens / wall
         elif self.flops_per_step is not None and wall:
             achieved = self.flops_per_step * len(w) / wall
+        if achieved is not None:
+            if self.peak_flops is None:
+                self.peak_flops = peak_bf16_flops()
             out["mfu"] = achieved / self.peak_flops
         return out

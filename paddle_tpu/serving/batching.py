@@ -192,6 +192,10 @@ class DynamicBatcher:
         that calls back into the batcher (pending(), a fallback submit)
         would self-deadlock the worker."""
         for r in expired:
+            # count BEFORE waking the caller: whoever the exception
+            # wakes may read the expiry counters at once
+            if self._on_expired is not None:
+                self._on_expired(r)
             try:
                 r.future.set_exception(DeadlineExceeded(
                     f"request waited "
@@ -199,8 +203,6 @@ class DynamicBatcher:
                     "in queue, past its deadline"))
             except InvalidStateError:
                 pass  # caller cancelled while queued
-            if self._on_expired is not None:
-                self._on_expired(r)
 
     def _loop(self):
         while True:

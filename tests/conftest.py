@@ -1,10 +1,21 @@
 """Test config: force an 8-device virtual CPU mesh (the reference's
 multi-process-on-localhost simulation strategy, SURVEY.md §4, mapped to
-jax's host-platform device-count flag)."""
+jax's host-platform device-count flag).
+
+The suite checks the framework's semantics, not how fast XLA:CPU's code
+runs — and on the installed jax ~2/3 of its wall time is XLA:CPU
+compiling thousands of tiny eager programs (~43 ms each). The two
+code-generation flags pick the cheap paths (legacy elemental emitters,
+LLVM -O0): about half the compile time on eager-heavy files, same HLO,
+same results to the tolerances the tests state. An XLA that drops a flag
+rejects XLA_FLAGS loudly at backend start-up: this is the one place."""
 import os
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+os.environ.setdefault(
+    "XLA_FLAGS",
+    "--xla_force_host_platform_device_count=8 "
+    "--xla_cpu_use_fusion_emitters=false "
+    "--xla_backend_optimization_level=0")
 
 import jax  # noqa: E402
 

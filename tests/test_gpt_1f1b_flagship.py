@@ -124,6 +124,7 @@ class TestGPT1F1BDropoutReplay:
     Parity target: an eager tape run drawing masks with the IDENTICAL
     per-(microbatch, stage, layer) key schedule."""
 
+    @pytest.mark.slow  # PR 21, ~11 s eager replica: dropout determinism per key stays in tier-1
     def test_train_dropout_loss_and_grad_parity(self):
         import jax
         from paddle_tpu.core import random as core_random

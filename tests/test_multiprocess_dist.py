@@ -19,24 +19,6 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _cpu_cross_process_collectives():
-    """jaxlib < 0.5 has no cross-process collectives on the CPU backend
-    ("Multiprocess computations aren't implemented on the CPU backend" in
-    every worker) — the documented known-unfixable gap in this container
-    (.claude/skills/verify/SKILL.md). Skip instead of burning ~40 s of
-    subprocess startup per tier-1 run on guaranteed failures; these
-    re-arm automatically on a jax upgrade or a real accelerator."""
-    import jax
-    ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    return ver >= (0, 5)
-
-
-needs_cross_process = pytest.mark.skipif(
-    not _cpu_cross_process_collectives(),
-    reason="jaxlib<0.5 CPU backend has no cross-process collectives "
-           "(known env gap, see verify SKILL.md)")
-
-
 def _clean_env():
     env = dict(os.environ)
     for k in list(env):
@@ -88,7 +70,6 @@ def _tail_logs(log_dir):
     return "\n".join(out)
 
 
-@needs_cross_process
 class TestDistLossParity:
     """The reference's headline distributed test: same model, same data,
     1 process vs N processes — losses must match."""
@@ -99,6 +80,7 @@ class TestDistLossParity:
         assert len(single) == len(dist2) == 5
         np.testing.assert_allclose(single, dist2, rtol=1e-4, atol=1e-6)
 
+    @pytest.mark.slow  # PR 21, ~10 s: the dp case keeps the cross-process path in tier-1
     def test_two_proc_tensor_parallel_matches_single(self, tmp_path):
         """Megatron-sharded weights across two real processes: GSPMD
         collectives cross the process boundary; losses must match the
@@ -108,6 +90,7 @@ class TestDistLossParity:
         assert len(mp2) == 5
         np.testing.assert_allclose(single, mp2, rtol=1e-4, atol=1e-6)
 
+    @pytest.mark.slow  # PR 21, ~12 s: the dp case keeps the cross-process path in tier-1
     def test_two_proc_four_dev_hybrid_matches_single(self, tmp_path):
         """Multi-host hybrid mesh: 2 processes x 4 virtual devices = 8
         global devices, dp across the process boundary (DCN analog) and
@@ -131,7 +114,6 @@ def _spawn_worker(scale):
 
 
 class TestSpawn:
-    @needs_cross_process
     def test_spawn_two_processes_collective(self):
         from paddle_tpu.distributed.spawn import spawn
         ctx = spawn(_spawn_worker, args=(2.0,), nprocs=2, backend="cpu",
@@ -204,7 +186,6 @@ class TestElasticAcrossProcesses:
         assert final_rank == 0  # survivor re-ranked to 0
 
 
-@needs_cross_process
 class TestEagerCollectives:
     """Eager (non-shard_map) collectives across REAL processes: formerly
     silent identities, now true cross-process ops (reference:

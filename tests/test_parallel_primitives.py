@@ -51,8 +51,10 @@ def test_ring_attention_grads_match():
     def ref_loss(q, k, v):
         return jnp.sum(_full_attention(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    # one jitted program each: an eager shard_map grad dispatches (and
+    # compiles) op by op across the mesh, ~10x the wall time
+    g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-3, atol=1e-4)
@@ -120,8 +122,8 @@ def test_spmd_pipeline_backward_trains():
             h = jnp.tanh(h @ w[s])
         return jnp.mean((h - tgt) ** 2)
 
-    g_pp = np.asarray(jax.grad(loss_fn)(w))
-    g_ref = np.asarray(jax.grad(ref_loss)(w))
+    g_pp = np.asarray(jax.jit(jax.grad(loss_fn))(w))
+    g_ref = np.asarray(jax.jit(jax.grad(ref_loss))(w))
     np.testing.assert_allclose(g_pp, g_ref, rtol=1e-4, atol=1e-5)
 
     # and a few SGD steps reduce the loss inside one jit

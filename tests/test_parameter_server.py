@@ -267,6 +267,7 @@ class TestDownpourTrainer:
     DistMultiTrainer via train_from_dataset, SURVEY CS5): thread-local
     model replicas over one shared PS client, async push/pull."""
 
+    @pytest.mark.slow  # PR 21, ~11 s: joins the PS cluster runs already in the slow tier
     def test_two_threads_train_from_dataset(self):
         import numpy as np
 

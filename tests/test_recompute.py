@@ -216,19 +216,30 @@ def test_policy_names_and_errors():
     assert fn is jax.checkpoint_policies.dots_saveable
 
 
-def test_offload_falls_back_loudly_on_cpu():
-    assert rc.host_offload_available() is False  # CPU: unpinned_host only
+def test_offload_falls_back_loudly_without_pinned_host(monkeypatch):
+    # the installed CPU backend has a pinned_host space: offload is real
+    assert rc.host_offload_available() is True
+    assert rc.resolve_policy("offload", strict=True)[1] == "offload"
+    # a backend without one: loud fallback, not a silent no-op
+    monkeypatch.setattr(rc, "host_offload_available", lambda: False)
     with pytest.warns(UserWarning, match="pinned_host"):
         fn, name = rc.resolve_policy("offload")
-    assert name == "selective"  # loud fallback, not a silent no-op
+    assert name == "selective"
     with pytest.raises(RuntimeError, match="pinned_host"):
         rc.resolve_policy("offload", strict=True)
 
 
-def test_offload_policy_trains_with_fallback():
-    with pytest.warns(UserWarning, match="pinned_host"):
-        got = _run(True, 0, 1, 1, policy="offload")
+@pytest.mark.parametrize("pinned_host", [True, False])
+def test_offload_policy_trains(pinned_host, monkeypatch):
+    """Offloaded residuals (or, without a pinned_host space, the loud
+    selective fallback) change where values live, never the loss."""
     ref = _run(False, 0, 1, 1)
+    if pinned_host:
+        got = _run(True, 0, 1, 1, policy="offload")
+    else:
+        monkeypatch.setattr(rc, "host_offload_available", lambda: False)
+        with pytest.warns(UserWarning, match="pinned_host"):
+            got = _run(True, 0, 1, 1, policy="offload")
     assert ref[0].tobytes() == got[0].tobytes()
 
 

@@ -5,6 +5,8 @@ The scan program must be OBSERVABLY identical to the python-unrolled
 control: same per-inner-step losses from the same seed, same final
 params, same @GRAD survival semantics through the carry.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -179,11 +181,14 @@ def test_scan_steps_validation():
 
 # -- persistent compile cache ----------------------------------------------
 
-def test_persistent_cache_warm_start(tmp_path):
+def test_persistent_cache_warm_start(tmp_path, monkeypatch):
     """Acceptance: with the persistent cache on, a second StaticFunction
     over the same fn hits the disk cache instead of re-running the
     backend compile (restart-shaped workload, one process)."""
     from paddle_tpu.jit import compile_cache
+
+    # the test places its own cache; an ambient placement would refuse
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
 
     def fn(x):
         return (x * 2.0 + 1.0).sum()
@@ -210,7 +215,12 @@ def test_persistent_cache_warm_start(tmp_path):
         compile_cache.disable()
 
 
-def test_compile_cache_env_policy(monkeypatch):
+def test_compile_cache_env_policy(monkeypatch, tmp_path):
+    """The switch is recorded at import; placement follows one rule:
+    JAX_COMPILATION_CACHE_DIR set -> jax's own setting stands and no
+    directory is set in code; unset -> the fixed in-checkout path."""
+    import jax
+
     from paddle_tpu.jit import compile_cache
 
     monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", "off")
@@ -218,11 +228,23 @@ def test_compile_cache_env_policy(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", "1")
     assert compile_cache.configure_from_env() is True
     monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE")
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE_DIR", "/tmp/x")
-    assert compile_cache.configure_from_env() is True
-    # restore the ambient policy for the rest of the suite
-    monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR")
-    compile_cache._state["policy"] = None
+    compile_cache._state["policy"] = None  # the suite's ambient policy
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == before
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            compile_cache.enable(str(tmp_path / "elsewhere"))
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == compile_cache.DEFAULT_CACHE_DIR
+        assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+    finally:
+        compile_cache.disable()
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # -- stacked-batch device prefetch -----------------------------------------

@@ -33,6 +33,16 @@ def _mlp(in_dim=8, hidden=16, out_dim=4, seed=7):
     return m
 
 
+def assert_matches_eager(got, want):
+    """Against the LIVE model run eagerly — op by op, at the request's
+    own row count — the engine's fused bucket program may round a dot
+    an ulp or two differently on XLA:CPU (another vector width, another
+    FMA contraction). Bitwise equality is the contract with the
+    unbatched Predictor, which runs the same exported program; the
+    eager model is held to float32 rounding."""
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
 @pytest.fixture(scope="module")
 def artifact(tmp_path_factory):
     """Saved batch-polymorphic StableHLO artifact + the live model."""
@@ -172,7 +182,7 @@ class TestConcurrentBatching:
         assert len(results) == 40
         for x, (out,) in results.values():
             assert out.shape[0] == x.shape[0]
-            np.testing.assert_array_equal(out, model(Tensor(x)).numpy())
+            assert_matches_eager(out, model(Tensor(x)).numpy())
         assert stats["requests"] == 40
         assert stats["multi_request_batches"] >= 1
         assert stats["batches"] < 40  # coalescing actually happened
@@ -291,7 +301,7 @@ class TestPassPipeline:
             assert eng.output_names == ["output_1"]
             outs = eng.predict(x)
         assert len(outs) == 1
-        np.testing.assert_array_equal(outs[0], wb.numpy())
+        assert_matches_eager(outs[0], wb.numpy())
         with pytest.raises(ValueError, match="valid output names"):
             serving.Engine(prefix, outputs=["output_9"])
 
@@ -460,7 +470,7 @@ class TestPredictorDelegation:
         pred.run()
         _wa, wb = model(Tensor(x))
         out = pred.get_output_handle("output_1").copy_to_cpu()
-        np.testing.assert_array_equal(out, wb.numpy())
+        assert_matches_eager(out, wb.numpy())
         with pytest.raises(ValueError, match="valid output names"):
             pred.get_output_handle("output_0")  # pruned away
         pred.close()

@@ -90,14 +90,6 @@ def _batches(k, batch=16):
     return paddle.to_tensor(x), paddle.to_tensor(y)
 
 
-def _shard_map():
-    import jax
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 # -- the budget predictor table ---------------------------------------------
 
 def test_predict_budget_table():
@@ -197,8 +189,8 @@ def test_seeded_replication_blowup(_mesh):
     def f(b, x):
         return (x * b[0, 0]).sum(axis=1)
 
-    fn = _shard_map()(f, mesh=_mesh, in_specs=(P(), P("dp")),
-                      out_specs=P("dp"))
+    fn = jax.shard_map(f, mesh=_mesh, in_specs=(P(), P("dp")),
+                       out_specs=P("dp"))
     jx = jax.make_jaxpr(fn)(big, xs)
     fs, stats = shardcheck.analyze_jaxpr(jx)
     hits = [f for f in fs if f.rule == "replication-blowup"]
@@ -225,8 +217,8 @@ def test_seeded_materialization_window(_mesh):
         return (jax.lax.all_gather(u, "dp", tiled=True),
                 jax.lax.all_gather(v, "dp", tiled=True))
 
-    fn = _shard_map()(f, mesh=_mesh, in_specs=(P("dp"), P("dp")),
-                      out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(f, mesh=_mesh, in_specs=(P("dp"), P("dp")),
+                       out_specs=(P(), P()), check_vma=False)
     jx = jax.make_jaxpr(fn)(a, b)
     fs, stats = shardcheck.analyze_jaxpr(jx, budget=1)
     hits = [f for f in fs if f.rule == "materialization-window"]

@@ -1,0 +1,129 @@
+"""Compile for the chip without the chip (on-chip-measurement guide §2.3).
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+chip that is DESCRIBED, not attached: what it refuses here (a slice off
+the tiling, too much VMEM, a kernel that cannot be partitioned) it would
+refuse on the machine, and finding that here costs no chip time. These
+cases hold the pallas flash kernel — interpret mode everywhere else in
+the suite — to the real Mosaic lowering at the widths the models use:
+forward and backward, `gpt3_1p3b` head geometry (16 x 128) at seq
+1024/2048/8192 and BERT-base's (12 x 64) at the dispatch gate. Nothing
+runs, so they say nothing about results or times; `chip_smoke.py` does.
+
+Plus the compile-cache placement rule, in a subprocess so jax's config is
+as a fresh program finds it.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described v5e device; the persistent compile cache is off
+    around these compiles (an entry written for an unattached chip can
+    never be read back, and warns on every later attempt)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (batch, seq, heads, head_dim), dtype, causal
+FLASH_CASES = [
+    ((2, 1024, 16, 128), jnp.bfloat16, True),
+    ((2, 2048, 16, 128), jnp.bfloat16, True),
+    ((1, 8192, 16, 128), jnp.bfloat16, True),
+    ((2, 1024, 12, 64), jnp.bfloat16, False),
+    ((1, 2048, 16, 128), jnp.float32, True),
+]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize(
+    "shape,dtype,causal", FLASH_CASES,
+    ids=[f"b{s[0]}_s{s[1]}_h{s[2]}x{s[3]}_{jnp.dtype(d).name}"
+         f"{'_causal' if c else ''}" for s, d, c in FLASH_CASES])
+def test_flash_kernel_compiles_for_v5e(v5e_chip, shape, dtype, causal,
+                                       direction):
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def fwd(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+    # as the chip compiles it, not at the -O0 conftest gives the CPU
+    text = jax.jit(fn).lower(x, x, x).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    # the kernel itself, not an XLA rewrite: forward is one Mosaic call,
+    # backward re-runs it for the residuals then the dq and dkv kernels
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+
+
+_PLACEMENT_PROBE = """
+import json
+import jax
+from paddle_tpu.jit import compile_cache
+
+set_in_code = []
+update = jax.config.update
+def spy(name, value):
+    if name == "jax_compilation_cache_dir":
+        set_in_code.append(value)
+    return update(name, value)
+jax.config.update = spy
+before = jax.config.jax_compilation_cache_dir
+compile_cache.enable()
+print(json.dumps({"before": before, "set_in_code": set_in_code,
+                  "after": jax.config.jax_compilation_cache_dir,
+                  "cache_dir": compile_cache.cache_dir()}))
+"""
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["env_places_it", "fixed_in_checkout"])
+def test_compile_cache_placement(placed, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set -> jax's own setting stands and
+    nothing sets a directory in code; unset -> the fixed path inside the
+    checkout (never the home directory, a temp name, a pid or a time)."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    r = subprocess.run([sys.executable, "-c", _PLACEMENT_PROBE],
+                       capture_output=True, text=True, env=env,
+                       cwd=str(tmp_path), timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    if placed:
+        assert got["set_in_code"] == []
+        assert got["before"] == got["after"] == got["cache_dir"] \
+            == str(tmp_path)
+    else:
+        fixed = os.path.join(REPO, ".jax_cache")
+        assert got["before"] is None
+        assert got["set_in_code"] == [fixed]
+        assert got["after"] == got["cache_dir"] == fixed

@@ -1,6 +1,7 @@
 """End-to-end training (reference model: tests/book/ 'book' e2e suite +
 test_mnist dygraph tests): LeNet must actually learn the synthetic MNIST."""
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import nn
@@ -74,6 +75,7 @@ def test_hapi_predict_save_load(tmp_path):
         np.testing.assert_allclose(v1.numpy(), v2.numpy())
 
 
+@pytest.mark.slow  # PR 21, ~16 s: eager resnet18 fwd+bwd compiles op by op; LeNet e2e and the conv/bn layer tests keep tier-1 coverage
 def test_resnet18_smoke():
     from paddle_tpu.vision.models import resnet18
     m = resnet18(num_classes=10)

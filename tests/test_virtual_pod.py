@@ -814,6 +814,29 @@ def test_spawn_join_reaps_signal_death_quickly():
     assert took < 60, f"join took {took:.0f}s — it hung instead of reaping"
 
 
+@pytest.mark.parametrize("who_dies", ["one_rank", "every_rank"])
+def test_child_death_before_join_fails_pod_at_once(tmp_path, who_dies):
+    """A rank that dies at import or build never joins. The pod must
+    fail NOW, with the child's stderr tail — not after its peers have
+    sat out a join or lease timeout — and leave nothing running."""
+    from paddle_tpu.distributed.pod import PodError
+    script = tmp_path / "broken_rank.py"
+    script.write_text(
+        "import os, time\n"
+        f"if {who_dies == 'every_rank'} or "
+        "os.environ['PADDLE_TRAINER_ID'] == '1':\n"
+        "    raise ImportError('No module named broken_dependency')\n"
+        "time.sleep(120)  # a healthy rank, still importing\n")
+    pod = VirtualPod(2, str(script), workdir=str(tmp_path / "wd"))
+    t0 = time.time()
+    with pytest.raises(PodError, match="died before joining") as ei:
+        pod.run(timeout=120)
+    took = time.time() - t0
+    assert took < 20, f"took {took:.0f}s — it waited out a timeout"
+    assert "broken_dependency" in str(ei.value)
+    assert all(tp.proc.poll() is not None for tp in pod._procs)
+
+
 def test_watch_local_trainers_grace_lets_sigterm_hook_run(tmp_path):
     """On a trainer death the launcher tears the pod down with SIGTERM +
     grace before SIGKILL — a survivor's SIGTERM hook (the flight

@@ -131,21 +131,23 @@ def test_zero_state_lives_sharded_1_over_dp():
 
 
 def test_zero_hlo_replaces_psum_with_scatter_gather():
-    """The compiled program's reduction changes shape: control = one
-    all-reduce per param grad; zero = one reduce-scatter per bucket + one
-    all-gather per bucket (plus the scalar loss pmean)."""
+    """The compiled program's reduction changes shape: control = every
+    param grad all-reduced whole; zero = one reduce-scatter per bucket +
+    one all-gather per bucket (plus the scalar loss pmean)."""
     k = 2
     x, y = _batches(k)
-    s0, _m0, _o0 = _build(0, k, bf16=False)
+    s0, m0, _o0 = _build(0, k, bf16=False)
     s0(x, y)
     s1, _m1, _o1 = _build(1, k, bf16=False)
     s1(x, y)
 
     ctrl = {s["op"]: s for s in s0.collective_stats()}
     zero = {s["op"]: s for s in s1.collective_stats()}
-    # control: per-param psum — at least one all-reduce per trainable
-    # param (4: two weights + two biases) + the loss pmean
-    assert ctrl["all-reduce"]["count"] >= 5
+    # control: per-param psum of every trainable param + the loss pmean.
+    # The compiler may combine the five psums into one tuple all-reduce,
+    # so hold it to the bytes that cross, not the instruction count
+    grad_bytes = sum(int(np.prod(p.shape)) * 4 for p in m0.parameters())
+    assert ctrl["all-reduce"]["bytes"] >= grad_bytes + 4
     assert "reduce-scatter" not in ctrl
     # zero: bucketed scatter/gather; only the scalar loss pmean remains
     assert zero["all-reduce"]["bytes"] <= 8  # one f32 scalar
@@ -363,7 +365,13 @@ def test_scaler_manual_unscale_in_window_rejected():
 def test_zero_decay_fn_row_mask_and_missing_grads():
     """The two row-mask paths through the bound shard_map step: AdamW's
     apply_decay_param_fun becomes a per-row mask, and a param without a
-    grad holds still — both bitwise vs the replicated control."""
+    grad holds still. Losses and the held param are bitwise vs the
+    replicated control. The updated params are held to float32 rounding:
+    the hold-still `select` changes which multiply-adds LLVM contracts
+    into FMAs on XLA:CPU (always allowed there), so from the second
+    step — the first with non-zero moments — the two programs round
+    `b*m + (1-b)*g` differently by a few ulp (0 with
+    --xla_cpu_max_isa=SSE4_2, which has no FMA)."""
     k = 2
     x, y = _batches(k)
 
@@ -391,9 +399,12 @@ def test_zero_decay_fn_row_mask_and_missing_grads():
     s0, m0 = build(0)
     s1, m1 = build(1)
     assert s0(x, y).numpy().tobytes() == s1(x, y).numpy().tobytes()
+    assert np.asarray(m0[2].bias._value).tobytes() == \
+        np.asarray(m1[2].bias._value).tobytes()
     for p0, p1 in zip(m0.parameters(), m1.parameters()):
-        assert np.asarray(p0._value).tobytes() == \
-            np.asarray(p1._value).tobytes(), p0.name
+        np.testing.assert_allclose(np.asarray(p0._value),
+                                   np.asarray(p1._value),
+                                   rtol=5e-6, atol=1e-7, err_msg=p0.name)
 
 
 def test_overflow_skips_whole_update_zero_and_control():

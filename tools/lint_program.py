@@ -58,9 +58,9 @@ def main(argv=None):
 
     findings = []
     if run_ladder:
-        # the miniatures are smoke-scale: always verify on CPU (the env
-        # var alone is not honored once an accelerator plugin is
-        # installed; the config update must come before first jax use)
+        # the miniatures are smoke-scale: always verify on CPU, whatever
+        # backend the caller's environment selects (the update must come
+        # before first jax use)
         import jax
         jax.config.update("jax_platforms", "cpu")
         from paddle_tpu.analysis import ERROR, Finding, ladder

@@ -71,6 +71,13 @@ def _sig_of(obj):
     return sig
 
 
+def _is_ours(obj):
+    """Freeze this repo's names, not what it re-exports from jax or
+    numpy: their signatures and reprs move with every upgrade."""
+    mod = getattr(obj, "__module__", None) or ""
+    return mod == "paddle_tpu" or mod.startswith("paddle_tpu.")
+
+
 def collect():
     lines = []
     for modname in MODULES:
@@ -84,7 +91,7 @@ def collect():
             names = [n for n in dir(mod) if not n.startswith("_")]
         for name in sorted(set(names)):
             obj = getattr(mod, name, None)
-            if obj is None or inspect.ismodule(obj):
+            if obj is None or inspect.ismodule(obj) or not _is_ours(obj):
                 continue
             if inspect.isclass(obj):
                 lines.append(f"{modname}.{name} class{_sig_of(obj)}")
