@@ -458,7 +458,43 @@ struct PsServer {
   std::deque<TraceSpan> trace_ring;
   std::mutex trace_mu;
   std::atomic<uint64_t> span_seq{0};
+  // requests answered and not yet recorded: a handler adds a request's
+  // stats and trace span AFTER it has answered (both cover the send), so
+  // a client can hold the reply before either exists. The in-process
+  // exports wait (bounded) for this to reach zero — a reader then sees
+  // the record of every request that was answered.
+  std::atomic<int> unrecorded{0};
 };
+
+// One iteration of the handler loop: `send_resp` arms it just before
+// the answer leaves, and it holds `unrecorded` up until the iteration
+// ends, whichever way it ends. (A request parked in a barrier has not
+// answered, so it holds no reader.)
+struct AnsweredGuard;
+thread_local AnsweredGuard* tl_answering = nullptr;
+
+struct AnsweredGuard {
+  std::atomic<int>* n;
+  bool armed = false;
+  explicit AnsweredGuard(std::atomic<int>* counter) : n(counter) {
+    tl_answering = this;
+  }
+  void arm() {
+    if (!armed) {
+      armed = true;
+      n->fetch_add(1);
+    }
+  }
+  ~AnsweredGuard() {
+    tl_answering = nullptr;
+    if (armed) n->fetch_sub(1);
+  }
+};
+
+void wait_recorded(PsServer* ps) {
+  for (int i = 0; i < 2000 && ps->unrecorded.load() > 0; ++i)
+    std::this_thread::sleep_for(std::chrono::microseconds(100));
+}
 
 void record_trace_span(PsServer* ps, uint64_t trace, uint64_t parent,
                        uint32_t table, uint8_t op, bool dup, int64_t t0) {
@@ -596,6 +632,7 @@ bool write_all(int fd, const void* buf, size_t n) {
 }
 
 bool send_resp(int fd, const void* payload, uint32_t n) {
+  if (tl_answering) tl_answering->arm();
   if (!write_all(fd, &n, 4)) return false;
   return n == 0 || write_all(fd, payload, n);
 }
@@ -817,6 +854,7 @@ void handle_conn(PsServer* ps, int fd, size_t conn_idx) {
     // flagged frame too short for the prefix is malformed: drop.
     bool has_trace = false;
     uint64_t trace_id = 0, parent_span = 0;
+    AnsweredGuard answered(&ps->unrecorded);
     if (op & kTraceFlag) {
       if (psize < 16) break;
       memcpy(&trace_id, payload, 8);
@@ -1425,6 +1463,7 @@ PT_API int32_t pt_ps_trace_json(char* out, int32_t cap, int32_t drain) {
   std::lock_guard<std::mutex> lk(g_ps_mu);
   std::string s = "[";
   if (g_ps) {
+    wait_recorded(g_ps);
     std::lock_guard<std::mutex> tlk(g_ps->trace_mu);
     bool first = true;
     for (auto& sp : g_ps->trace_ring) {
@@ -1446,6 +1485,7 @@ PT_API int32_t pt_ps_stats_json(char* out, int32_t cap) {
   std::lock_guard<std::mutex> lk(g_ps_mu);
   std::string s = "[";
   if (g_ps) {
+    wait_recorded(g_ps);
     std::lock_guard<std::mutex> slk(g_ps->stats_mu);
     bool first = true;
     for (auto& kv : g_ps->op_stats) {

@@ -92,7 +92,7 @@ _HOST_CALLBACK_OPS = frozenset({"py_func", "pure_callback", "host_callback"})
 # device-touching jnp call is a per-op-dispatch cost
 HOT_PATHS = {
     os.path.join("paddle_tpu", "core", "dispatch.py"): {
-        "call_op", "call_op_nograd", "_call_op_impl",
+        "call_op", "call_op_nograd", "_dispatch", "_call_op_impl",
         "_call_op_nograd_impl", "_observed", "unwrap", "wrap",
     },
     os.path.join("paddle_tpu", "observability", "tracing.py"): {

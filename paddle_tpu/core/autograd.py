@@ -16,6 +16,8 @@ import numpy as np
 from jax import dtypes as _jax_dtypes
 import jax.numpy as jnp
 
+from ..observability import scopes as _scopes
+
 __all__ = [
     "TapeNode",
     "grad_enabled",
@@ -64,10 +66,13 @@ class TapeNode:
     ``inputs``: the differentiated input Tensors (strong refs — the eager graph
     lives until backward, as with the reference's GradOpNode chain).
     ``out_meta``: (shape, dtype) per output so missing cotangents can be zeros.
+    ``scope``: inside a `to_static` trace, the scope path the op was
+    recorded under; the backward re-enters it, so the transposed
+    instructions carry their forward's name on the device.
     """
 
     __slots__ = ("vjp_fn", "inputs", "out_meta", "name", "cotangents",
-                 "pending", "pure_fn", "in_dtypes", "__weakref__")
+                 "pending", "pure_fn", "in_dtypes", "scope", "__weakref__")
 
     def __init__(self, vjp_fn, inputs, out_meta, name="", pure_fn=None,
                  in_dtypes=None):
@@ -84,6 +89,12 @@ class TapeNode:
         # matches even outside the original auto_cast scope.
         self.pure_fn = pure_fn
         self.in_dtypes = in_dtypes
+        self.scope = _scopes.current_path()
+
+    def scoped(self):
+        """The context the node's backward runs in: the scope its
+        forward was recorded under (nothing outside a trace)."""
+        return _scopes.reenter(self.scope)
 
     def seed(self, index, value):
         if self.cotangents is None:
@@ -104,7 +115,8 @@ class TapeNode:
                     c = np.zeros(shape, _jax_dtypes.float0)
             elif c.dtype != dtype:
                 # AMP boundary: downstream ran in a different precision
-                c = c.astype(dtype)
+                with _scopes.scope("cast"):
+                    c = c.astype(dtype)
             out.append(c)
         return tuple(out)
 
@@ -152,15 +164,16 @@ def backward(tensor, grad_tensor=None, retain_graph=False):
             raise RuntimeError(
                 "autograd graph has been freed (backward already ran); "
                 "pass retain_graph=True to keep it")
-        in_cots = n.vjp_fn(n.materialized_cotangents())
-        for t, cot in zip(n.inputs, in_cots):
-            if cot is None:
-                continue
-            child = t._tape_node
-            if child is not None:
-                child.seed(t._tape_index, cot)
-            if child is None or t._retain_grads:
-                t._accumulate_grad(cot)
+        with n.scoped():
+            in_cots = n.vjp_fn(n.materialized_cotangents())
+            for t, cot in zip(n.inputs, in_cots):
+                if cot is None:
+                    continue
+                child = t._tape_node
+                if child is not None:
+                    child.seed(t._tape_index, cot)
+                if child is None or t._retain_grads:
+                    t._accumulate_grad(cot)
         n.cotangents = None
         if not retain_graph:
             n.vjp_fn = None
@@ -218,15 +231,17 @@ def grad(outputs, inputs, grad_outputs=None, retain_graph=None, create_graph=Fal
             raise RuntimeError(
                 "autograd graph has been freed (backward/grad already ran); "
                 "pass retain_graph=True to keep it")
-        in_cots = n.vjp_fn(n.materialized_cotangents())
-        for t, cot in zip(n.inputs, in_cots):
-            if cot is None:
-                continue
-            if id(t) in wanted:
-                table[id(t)] = cot if table[id(t)] is None else table[id(t)] + cot
-            child = t._tape_node
-            if child is not None:
-                child.seed(t._tape_index, cot)
+        with n.scoped():
+            in_cots = n.vjp_fn(n.materialized_cotangents())
+            for t, cot in zip(n.inputs, in_cots):
+                if cot is None:
+                    continue
+                if id(t) in wanted:
+                    table[id(t)] = (cot if table[id(t)] is None
+                                    else table[id(t)] + cot)
+                child = t._tape_node
+                if child is not None:
+                    child.seed(t._tape_index, cot)
         n.cotangents = None
         if not retain_graph:
             n.vjp_fn = None
@@ -329,8 +344,9 @@ def _grad_create_graph(outputs, inputs, grad_outputs, retain_graph,
 
         # differentiable wrt BOTH the original inputs and the cotangents:
         # re-derive the VJP from the pure closure at the live input values
-        in_cots = call_op(regrad, *n.inputs, *full,
-                          op_name=f"grad_{n.name}")
+        with n.scoped():
+            in_cots = call_op(regrad, *n.inputs, *full,
+                              op_name=f"grad_{n.name}")
         in_cots = in_cots if isinstance(in_cots, tuple) else (in_cots,)
         for t, cot in zip(n.inputs, in_cots):
             if cot is None:

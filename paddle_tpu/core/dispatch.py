@@ -11,6 +11,7 @@ arrays and under `to_static` tracing on tracers.
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes as _scopes
 from . import autograd
 from .dtype import is_inexact
 
@@ -145,10 +146,15 @@ def wrap(value, stop_gradient=True):
 
 
 def _amp_cast(op_name, values):
-    """AMP hook: bf16-cast inputs of allow-listed ops (see amp/auto_cast.py)."""
+    """AMP hook: bf16-cast inputs of allow-listed ops (see amp/auto_cast.py).
+    In a compiled step the casts are the autocast layer's only device
+    cost: they go under the scope `cast`."""
     from ..amp.auto_cast import _state, amp_cast_inputs
     if not _state.enabled:
         return values
+    if _scopes.tracing():
+        with _scopes.scope("cast"):
+            return amp_cast_inputs(op_name, values)
     return amp_cast_inputs(op_name, values)
 
 
@@ -163,12 +169,18 @@ def _amp_wrap_fn(fn, op_name, args):
     if dt is None:
         return fn
 
-    def wrapped(*a, **k):
-        out = fn(*a, **k)
+    def down(out):
         if isinstance(out, tuple):
             return tuple(o.astype(dt) if hasattr(o, "astype") else o
                          for o in out)
         return out.astype(dt) if hasattr(out, "astype") else out
+
+    def wrapped(*a, **k):
+        out = fn(*a, **k)
+        if _scopes.tracing():
+            with _scopes.scope("cast"):
+                return down(out)
+        return down(out)
 
     return wrapped
 
@@ -209,11 +221,19 @@ def call_op(fn, *args, op_name=None, **kwargs):
     over as a constant. Multi-output fns must return only floating-point
     outputs (mixed-dtype ops are built as composites in the ops library).
     """
+    if _scopes.tracing():
+        # a compiled step: the op's device time gets the op's name
+        with _scopes.scope(op_display_name(fn, op_name)):
+            return _dispatch(_call_op_impl, fn, args, op_name, kwargs)
+    return _dispatch(_call_op_impl, fn, args, op_name, kwargs)
+
+
+def _dispatch(impl, fn, args, op_name, kwargs):
     if _OBSERVER_LIST is not None and _STATIC_HOOK[0] is None:
         name = op_display_name(fn, op_name)
         return _observed(
-            name, lambda: _call_op_impl(fn, *args, op_name=op_name, **kwargs))
-    return _call_op_impl(fn, *args, op_name=op_name, **kwargs)
+            name, lambda: impl(fn, *args, op_name=op_name, **kwargs))
+    return impl(fn, *args, op_name=op_name, **kwargs)
 
 
 def _call_op_impl(fn, *args, op_name=None, **kwargs):
@@ -268,12 +288,10 @@ def _call_op_impl(fn, *args, op_name=None, **kwargs):
 
 def call_op_nograd(fn, *args, op_name=None, **kwargs):
     """Run without recording (non-diff inputs, no_grad scope, or int ops)."""
-    if _OBSERVER_LIST is not None and _STATIC_HOOK[0] is None:
-        name = op_display_name(fn, op_name)
-        return _observed(
-            name,
-            lambda: _call_op_nograd_impl(fn, *args, op_name=op_name, **kwargs))
-    return _call_op_nograd_impl(fn, *args, op_name=op_name, **kwargs)
+    if _scopes.tracing():
+        with _scopes.scope(op_display_name(fn, op_name)):
+            return _dispatch(_call_op_nograd_impl, fn, args, op_name, kwargs)
+    return _dispatch(_call_op_nograd_impl, fn, args, op_name, kwargs)
 
 
 def _note_capture_inputs(args, kwargs):

@@ -174,7 +174,6 @@ class PsServer:
             port = lib.pt_ps_start(self.port)
         if port < 0:
             raise RuntimeError(f"ps server failed to bind port {self.port}")
-        _obs.count("ps_server_starts", cat="ps")
         # per-table op latencies become scrapeable the moment the server
         # is up; the collector pulls fresh native counters per scrape
         from ...observability import export as _export

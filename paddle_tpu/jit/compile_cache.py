@@ -20,11 +20,24 @@ disk. This module owns the policy:
   path inside the checkout: a directory that moves (home, temp name,
   pid) never hits, and a machine that keeps only the checkout keeps
   only this.
-- cache effectiveness is observable: jax's ``/jax/compilation_cache/*``
-  monitoring events are mirrored into the shared monitor registry
-  (``jit_persistent_cache_hits`` / ``_misses`` / ``_saved_ns``) next to
-  the ``jit_backend_compile_ns`` counter the tracing hook maintains, so
-  the cold/warm compile delta shows up in any metrics scrape.
+- cache effectiveness is observable: jax's compile events are mirrored
+  into the shared monitor registry from the first build on, with no
+  switch (``jit_backend_compile_ns`` / ``jit_backend_compiles`` per
+  backend compile or cache load, ``jit_persistent_cache_load_ns``, and
+  while the cache is on ``jit_persistent_cache_hits`` / ``_misses`` /
+  ``_saved_ns``), so the cold/warm compile delta shows up in any
+  metrics scrape.
+- scoped and unscoped executables are kept apart: jax's cache key
+  leaves an instruction's ``op_name`` metadata out, so an executable
+  cached before the program entered ``observability.scopes`` would be a
+  hit for the scoped program and name nothing. The key does hold the
+  module's name, so every step program ``to_static`` compiles is named
+  by :func:`program_name` with :data:`METADATA_SCHEMA` in it: the step
+  programs (and only they) miss the older entries once, wherever the
+  placement rule put the cache. Bump the schema when what the program
+  writes into that metadata changes (file names and line numbers are
+  deliberately not in the key: every edit would cost every user a cold
+  compile).
 
 Env:
     PADDLE_TPU_COMPILE_CACHE       "1"/"on" force-enable (any backend),
@@ -34,7 +47,18 @@ Env:
 import os
 
 __all__ = ["configure_from_env", "ensure_enabled", "enable", "disable",
-           "is_enabled", "cache_dir", "DEFAULT_CACHE_DIR"]
+           "is_enabled", "cache_dir", "DEFAULT_CACHE_DIR", "program_name",
+           "METADATA_SCHEMA"]
+
+# what the program writes into instruction metadata: s2 = the `pt.`
+# scopes of observability.scopes (s1, unnamed: nothing)
+METADATA_SCHEMA = "s2"
+
+
+def program_name(name):
+    """The name a compiled step program goes by (its module's name,
+    which jax's persistent-cache key holds)."""
+    return f"{name}_{METADATA_SCHEMA}"
 
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -59,8 +83,12 @@ def configure_from_env():
 
 
 def _install_event_mirror():
-    """Count jax persistent-cache events into the monitor registry. jax
-    has no unregister-one API, so install once and gate on enabled."""
+    """Count jax's compile events into the monitor registry: every
+    backend compile (a load from the persistent cache fires it too) and
+    the cache's retrieval time always — a compile happens once per
+    program, a guard saves nothing — and the persistent cache's hits,
+    misses and saved time while this module has it enabled. jax has no
+    unregister-one API, so install once."""
     if _events_installed[0]:
         return
     from jax import monitoring as _jm
@@ -76,9 +104,14 @@ def _install_event_mirror():
             monitor.stat_add("jit_persistent_cache_misses", 1)
 
     def _on_duration(event, duration, **kwargs):
-        if not _state["enabled"]:
-            return
-        if event == "/jax/compilation_cache/compile_time_saved_sec":
+        if event == "/jax/core/compile/backend_compile_duration":
+            monitor.stat_add("jit_backend_compile_ns", int(duration * 1e9))
+            monitor.stat_add("jit_backend_compiles", 1)
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            monitor.stat_add("jit_persistent_cache_load_ns",
+                             int(duration * 1e9))
+        elif (event == "/jax/compilation_cache/compile_time_saved_sec"
+              and _state["enabled"]):
             monitor.stat_add("jit_persistent_cache_saved_ns",
                              int(duration * 1e9))
 
@@ -141,6 +174,7 @@ def ensure_enabled():
     """Resolve the policy once, at first compile (backend already up):
     accelerators default on, CPU defaults off unless jax's own env
     setting places a cache, the switch overrides both."""
+    _install_event_mirror()
     if _state["resolved"]:
         return _state["enabled"]
     policy = _state["policy"]

@@ -16,19 +16,57 @@ inputs are device_put onto NamedShardings before compilation, and GSPMD
 inserts the collectives (the analog of the reference's c_allreduce insertion
 by fleet meta-optimizers).
 """
+import contextlib
 import functools
+import time
 import weakref
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from .. import monitor
 from ..core import state as state_mod
 from ..core.tensor import Tensor
+from ..observability import scopes as _scopes
 from ..observability import tracing as _obs
 from ..testing import faults as _faults
 
 _is_tracing = False
+
+# the host phases of one step call, in order (`_CallPhases`)
+CALL_PHASES = ("flatten", "snapshot", "place", "key", "launch", "wrap")
+_PHASE_COUNTER = {name: f'to_static_call_ns{{phase="{name}"}}'
+                  for name in CALL_PHASES}
+
+
+class _CallPhases:
+    """The phases of one `StaticFunction` call: ``with phases("place"):``
+    is a child span of `executor/step` while tracing is on (and so an
+    annotation in any profile being captured), and always its
+    nanoseconds; `commit()` adds a call that hit the program cache to
+    the always-on counters ``to_static_calls`` and
+    ``to_static_call_ns{phase=}``."""
+
+    __slots__ = ("ns",)
+
+    def __init__(self):
+        self.ns = {}
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        with _obs.trace_span("executor/step/" + name, cat="executor"):
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                self.ns[name] = time.perf_counter_ns() - t0
+
+    def commit(self):
+        monitor.stat_add("to_static_calls", 1)
+        for name, ns in self.ns.items():
+            monitor.stat_add(_PHASE_COUNTER[name], ns)
+
 
 # step hooks: callables run inside every traced step body, after the
 # framework state swaps to tracers and before the user function — the seam
@@ -332,7 +370,12 @@ class StaticFunction:
         XLA compiler options (``jit.xla_flags``): unknown-flag errors
         degrade to an unflagged recompile with the fallback recorded as
         provenance — see :meth:`xla_flags`."""
+        from . import compile_cache
         from . import xla_flags as _xla_flags_mod
+        # the module's name carries the metadata schema, so that a
+        # persistent cache never serves this program an executable from
+        # before it had scopes (compile_cache.program_name)
+        fun.__name__ = compile_cache.program_name(fun.__name__)
         if self._xla_flags_default_pending:
             self._xla_flags_default_pending = False
             preset = _xla_flags_mod.PRESETS[
@@ -408,27 +451,33 @@ class StaticFunction:
             return self._call_impl(args, kwargs)
 
     def _call_impl(self, args, kwargs):
-        leaves, treedef = jax.tree_util.tree_flatten(
-            (args, kwargs), is_leaf=lambda x: isinstance(x, Tensor))
-        dyn_idx = [i for i, l in enumerate(leaves) if _is_dynamic(l)]
-        dyn_vals = [leaves[i]._value if isinstance(leaves[i], Tensor)
-                    else leaves[i] for i in dyn_idx]
-
-        state_items = state_mod.snapshot()
-        mesh = self._mesh()
-        if mesh is not None:
-            self._place_state(state_items, mesh)
-            dyn_vals = self._place_args(dyn_vals, mesh)
-
-        # registry version determines membership/order, so uids need not be
-        # part of the key; grad presence changes program structure
-        key = (treedef, tuple(_leaf_key(l) for l in leaves),
-               state_mod.version(),
-               tuple(t._grad is not None for _, t in state_items),
-               mesh is not None)
-        entry = self._cache.get(key)
-        if entry is None:
-            t0 = _obs.now_ns() if _obs.enabled("jit") else 0
+        phases = _CallPhases()
+        with phases("flatten"):
+            leaves, treedef = jax.tree_util.tree_flatten(
+                (args, kwargs), is_leaf=lambda x: isinstance(x, Tensor))
+            dyn_idx = [i for i, l in enumerate(leaves) if _is_dynamic(l)]
+            dyn_vals = [leaves[i]._value if isinstance(leaves[i], Tensor)
+                        else leaves[i] for i in dyn_idx]
+        with phases("snapshot"):
+            state_items = state_mod.snapshot()
+            mesh = self._mesh()
+        with phases("place"):
+            if mesh is not None:
+                self._place_state(state_items, mesh)
+                dyn_vals = self._place_args(dyn_vals, mesh)
+        with phases("key"):
+            # registry version determines membership/order, so uids need
+            # not be part of the key; grad presence changes program
+            # structure
+            key = (treedef, tuple(_leaf_key(l) for l in leaves),
+                   state_mod.version(),
+                   tuple(t._grad is not None for _, t in state_items),
+                   mesh is not None)
+            entry = self._cache.get(key)
+        hit = entry is not None
+        if not hit:
+            t0 = time.perf_counter_ns()
+            scopes_before = _scopes.entered()
             with _obs.trace_span("jit/compile", cat="jit",
                                  fn=getattr(self, "__name__", "fn"),
                                  cache_size=len(self._cache)):
@@ -441,12 +490,14 @@ class StaticFunction:
                     if not self._try_ast_fallback(e):
                         raise
                     entry = self._build(treedef, leaves, dyn_idx, state_items)
-            if t0:
-                # trace/build time only — XLA backend compile happens
-                # lazily on first execution and is captured by the
-                # jax.monitoring hook into jit_backend_compile_ns
-                _obs.count("jit_cache_miss")
-                _obs.count("jit_compile_ns", _obs.now_ns() - t0)
+            # once a build, so unguarded: the python trace of the body
+            # (the backend compile happens lazily on the first execution;
+            # compile_cache's jax.monitoring mirror counts it into
+            # jit_backend_compile_ns)
+            monitor.stat_add("jit_cache_miss", 1)
+            monitor.stat_add("jit_build_ns", time.perf_counter_ns() - t0)
+            entry[2]["traced_with_scopes"] = (
+                _scopes.entered() > scopes_before)
             from ..analysis import debug_enabled
             if debug_enabled():
                 # analysis debug mode: the fresh build's state partition
@@ -467,8 +518,13 @@ class StaticFunction:
         # training-step allocation failure on the exact path a real XLA
         # OOM surfaces (the flight recorder classifies and dumps it)
         _faults.kill_point("jit/step")
-        out_flat = compiled(dyn_vals)
-        return out_wrap(out_flat)
+        with phases("launch"):
+            out_flat = compiled(dyn_vals)
+        with phases("wrap"):
+            out = out_wrap(out_flat)
+        if hit:  # the building call is counted apart (jit_build_ns)
+            phases.commit()
+        return out
 
     def _make_aux(self, get_jitted, **meta):
         """Per-entry introspection handle: captures abstract twins of the
@@ -511,7 +567,19 @@ class StaticFunction:
                 # a backend without usable memory_analysis() must not
                 # break hlo_text(); memory_stats() re-raises
                 aux["memory_error"] = e
+            # what names each instruction: derived from the same text,
+            # and the newest kept in the process-wide program registry
+            # so that a reader need not hold the step
+            aux["scopes"] = _scopes.scope_table(
+                hlo, aux.get("traced_with_scopes"))
+            memory.record_program_scopes(
+                f"{getattr(self, '__name__', 'fn')}:"
+                f"{aux.get('kind', 'unrolled')}", aux["scopes"], hlo)
             aux["hlo"] = hlo
+
+        def scope_table():
+            _materialize()
+            return aux["scopes"]
 
         def hlo_text():
             _materialize()
@@ -580,6 +648,7 @@ class StaticFunction:
 
         aux["capture"] = capture
         aux["hlo_text"] = hlo_text
+        aux["scope_table"] = scope_table
         aux["memory_stats"] = memory_stats
         aux["traced_stats"] = traced_stats
         aux["schedulable_stats"] = schedulable_stats
@@ -593,6 +662,21 @@ class StaticFunction:
         if self._last_aux is None:
             raise RuntimeError("no compiled entry yet; call the step once")
         return self._last_aux["hlo_text"]()
+
+    def scope_table(self):
+        """``{instruction name: scope path}`` of the most recent entry's
+        compiled HLO (``observability.scopes.scope_table``): which
+        layer, op, attention path or optimizer part each device
+        instruction belongs to, from the ``pt.`` scopes entered while
+        the step was traced. ``table["stale"]`` is True when the step
+        was traced with scopes and its executable names none (an
+        executable kept by a persistent compile cache from before the
+        program had scopes). Shares the lazy AOT compile of
+        :meth:`hlo_text`; the newest table is also registered in
+        ``observability.memory.program_scopes()``."""
+        if self._last_aux is None:
+            raise RuntimeError("no compiled entry yet; call the step once")
+        return self._last_aux["scope_table"]()
 
     def collective_stats(self, per_execution=False):
         """In-trace collective accounting of the most recent entry: one
@@ -1327,7 +1411,6 @@ class StaticFunction:
 
         if getattr(self._fn, "_jst_transformed", False):
             return False
-        _obs.count("jit_ast_fallbacks", cat="jit")
         from .dy2static import convert_to_static
         try:
             fn = self._fn

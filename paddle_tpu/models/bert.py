@@ -15,6 +15,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import nn, ops
 from ..nn import functional as F
+from ..observability.scopes import scope
 
 
 class BertConfig:
@@ -165,10 +166,12 @@ class BertPretrainingHeads(nn.Layer):
 
     def forward(self, sequence_output, pooled_output):
         x = self.layer_norm(self.act(self.transform(sequence_output)))
-        logits = ops.matmul(x, self._tied, transpose_y=True)
-        # bias joins in the logits dtype: an fp32 bias would promote the
-        # [B*S, vocab] logits to fp32 (2x HBM on the biggest tensor)
-        logits = logits + ops.cast(self.decoder_bias, logits.dtype)
+        with scope("head"):
+            logits = ops.matmul(x, self._tied, transpose_y=True)
+            # bias joins in the logits dtype: an fp32 bias would promote
+            # the [B*S, vocab] logits to fp32 (2x HBM on the biggest
+            # tensor)
+            logits = logits + ops.cast(self.decoder_bias, logits.dtype)
         nsp = self.seq_relationship(pooled_output)
         return logits, nsp
 
@@ -190,10 +193,11 @@ class BertForPretraining(nn.Layer):
 
     def loss(self, prediction_logits, nsp_logits, masked_labels, nsp_labels,
              ignore_index=-100):
-        mlm = F.cross_entropy(prediction_logits, masked_labels,
-                              ignore_index=ignore_index)
-        nsp = F.cross_entropy(nsp_logits, nsp_labels)
-        return mlm + nsp
+        with scope("loss"):
+            mlm = F.cross_entropy(prediction_logits, masked_labels,
+                                  ignore_index=ignore_index)
+            nsp = F.cross_entropy(nsp_logits, nsp_labels)
+            return mlm + nsp
 
     def flops_per_token(self, seq_len=None):
         """Training FLOPs/token ≈ 6*N + attention (for MFU accounting)."""

@@ -8,6 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import nn, ops
 from ..nn import functional as F
+from ..observability.scopes import scope
 
 
 class GPTConfig:
@@ -103,12 +104,14 @@ class GPTForCausalLM(nn.Layer):
     def forward(self, input_ids):
         hidden = self.gpt(input_ids)
         # weight-tied LM head
-        return ops.matmul(hidden, self.gpt.wte.weight, transpose_y=True)
+        with scope("head"):
+            return ops.matmul(hidden, self.gpt.wte.weight, transpose_y=True)
 
     def loss(self, logits, labels):
         b, s, v = logits.shape
-        return F.cross_entropy(ops.reshape(logits[:, :-1], [-1, v]),
-                               ops.reshape(labels[:, 1:], [-1]))
+        with scope("loss"):
+            return F.cross_entropy(ops.reshape(logits[:, :-1], [-1, v]),
+                                   ops.reshape(labels[:, 1:], [-1]))
 
     def flops_per_token(self, seq_len=None):
         cfg = self.config

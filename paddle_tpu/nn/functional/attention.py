@@ -8,6 +8,7 @@ kernel (paddle_tpu.kernels.flash_attention) on TPU for long sequences.
 import jax.numpy as jnp
 
 from ...core.dispatch import call_op
+from ...observability.scopes import scope
 
 # Crossover measured in rounds 2-4 on the shared v5e of that time (BLOCK
 # 128x128, head_dim 64; not re-measured since): XLA's fused attention won
@@ -19,7 +20,16 @@ _FLASH_MIN_SEQ = 1024
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
                                  scale=None):
-    """q/k/v: [batch, seq, heads, head_dim] (paddle layout)."""
+    """q/k/v: [batch, seq, heads, head_dim] (paddle layout). In a
+    compiled step its device time goes under the scope `attention`, with
+    the path taken beneath it (`flash` / `xla`)."""
+    with scope("attention"):
+        return _sdpa_dispatch(query, key, value, attn_mask, dropout_p,
+                              is_causal, training, scale)
+
+
+def _sdpa_dispatch(query, key, value, attn_mask, dropout_p, is_causal,
+                   training, scale):
     from ...core import random as core_random
 
     q_shape = query.shape
@@ -39,7 +49,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             return _fa.flash_attention_bshd(q, k, v, causal=is_causal,
                                             scale=scale)
 
-        return call_op(_flash, query, key, value, op_name="flash_attention")
+        with scope("flash"):
+            return call_op(_flash, query, key, value,
+                           op_name="flash_attention")
 
     drop_key = core_random.next_key() if (dropout_p > 0.0 and training) else None
 
@@ -71,4 +83,5 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         return jnp.swapaxes(out, 1, 2)  # back to [B, S, H, D]
 
     args = (query, key, value) + ((attn_mask,) if attn_mask is not None else ())
-    return call_op(_sdpa, *args, op_name="scaled_dot_product_attention")
+    with scope("xla"):
+        return call_op(_sdpa, *args, op_name="scaled_dot_product_attention")

@@ -10,6 +10,7 @@ from collections import OrderedDict
 import numpy as np
 
 from ...core.tensor import Parameter, Tensor
+from ...observability import scopes as _scopes
 from .. import initializer as I
 
 
@@ -313,6 +314,13 @@ class Layer:
 
     # ----------------------------------------------------------- __call__
     def __call__(self, *inputs, **kwargs):
+        if _scopes.tracing():
+            # device time gets this layer's name (observability.scopes)
+            with _scopes.layer_scope(self):
+                return self._run_forward(inputs, kwargs)
+        return self._run_forward(inputs, kwargs)
+
+    def _run_forward(self, inputs, kwargs):
         for hook in self._forward_pre_hooks.values():
             result = hook(self, inputs)
             if result is not None:

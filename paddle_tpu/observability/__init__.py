@@ -17,6 +17,13 @@ Quick start::
     print(obs.export.prometheus_text())          # scrape text
     obs.disable()
 
+Device time gets the program's names through ``obs.scope(name)``
+(``scopes.py``): the layer, op, attention and optimizer seams enter it
+while a ``to_static`` step is traced, ``StaticFunction.scope_table()``
+maps the compiled step's instructions to scope paths, and
+``obs.device_time_by_scope(trace_dir, table)`` reduces a device profile
+to seconds per scope path and per kind.
+
 Scraping a live job: ``obs.export.start_http_server(9100)`` serves
 ``/metrics``; ``hapi.callbacks.TelemetryCallback`` publishes per-step
 tokens/s / MFU / data-wait gauges into it. The perf gate:
@@ -25,12 +32,13 @@ tokens/s / MFU / data-wait gauges into it. The perf gate:
 """
 from .. import profiler as _profiler
 from . import export, flight, gate, hlo_bytes, runlog, step  # noqa: F401
-from . import memory, overlap, tracing  # noqa: F401
+from . import memory, overlap, scopes, tracing  # noqa: F401
 from .gate import compare, load_results  # noqa: F401
 from .hlo_bytes import collective_stats, export_collective_bytes  # noqa: F401
 from .memory import state_ledger  # noqa: F401
 from .overlap import export_overlap_stats, overlap_stats  # noqa: F401
 from .runlog import start_run, stop_run  # noqa: F401
+from .scopes import device_time_by_scope, scope, scope_table  # noqa: F401
 from .step import StepTimer  # noqa: F401
 from .tracing import (CATEGORIES, attach_context, count,  # noqa: F401
                       current_span, disable, enable, enabled,
@@ -43,8 +51,9 @@ __all__ = [
     "overlap_stats", "export_overlap_stats",
     "trace_context", "attach_context", "mint_context", "record_span",
     "start_run", "stop_run",
+    "scope", "scope_table", "device_time_by_scope",
     "tracing", "export", "gate", "hlo_bytes", "step", "runlog", "flight",
-    "memory", "overlap",
+    "memory", "overlap", "scopes",
 ]
 
 

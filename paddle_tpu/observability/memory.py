@@ -39,6 +39,7 @@ from .. import monitor
 __all__ = ["program_stats", "peak_bytes", "top_buffers",
            "state_ledger", "export_state_ledger", "classify_tensor",
            "record_program_memory", "program_memory",
+           "record_program_scopes", "program_scopes",
            "export_program_memory", "snapshot", "runlog_snapshot",
            "flight_section", "is_oom_error", "attribute_program",
            "compile_program_twin",
@@ -181,6 +182,30 @@ def program_memory():
 def clear_program_memory():
     with _programs_lock:
         _programs.clear()
+        _program_scopes.clear()
+
+
+# the newest compiled step's scope table with the HLO text it came from
+# (one record: the text is megabytes, and a flight dump has no use for it)
+_program_scopes = {}
+
+
+def record_program_scopes(entry, table, hlo_text):
+    """Register a compiled program's ``observability.scopes`` table and
+    its HLO text; the newest replaces the one before."""
+    with _programs_lock:
+        _program_scopes.clear()
+        _program_scopes.update(entry=str(entry), table=table,
+                               hlo=hlo_text)
+
+
+def program_scopes():
+    """``{"entry", "table", "hlo"}`` of the newest compiled program whose
+    introspection aux was materialised (``StaticFunction.hlo_text()`` /
+    ``scope_table()`` / ``memory_stats()``), or None — for a reader in
+    the same process that does not hold the step."""
+    with _programs_lock:
+        return dict(_program_scopes) or None
 
 
 def export_program_memory(entry, stats):

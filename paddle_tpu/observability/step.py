@@ -9,7 +9,7 @@ A StepTimer marks step boundaries; over a sliding window it derives
   ``flops_per_token`` override — e.g. ``model.flops_per_token(seq)`` —
   for exact attention-aware accounting),
 - compile-stall fraction: time the window spent building/compiling
-  programs (``jit_compile_ns`` + ``executor_compile_ns`` + XLA
+  programs (``jit_build_ns`` + ``executor_compile_ns`` + XLA
   ``jit_backend_compile_ns``, all maintained by the instrumentation),
 - data-wait fraction: time the window spent blocked on input
   (``dataloader_wait_ns``).
@@ -46,7 +46,7 @@ def peak_bf16_flops(device_kind=None):
             "state one")
     return PEAK_BF16_FLOPS[device_kind]
 
-_COMPILE_COUNTERS = ("jit_compile_ns", "executor_compile_ns",
+_COMPILE_COUNTERS = ("jit_build_ns", "executor_compile_ns",
                      "jit_backend_compile_ns")
 _WAIT_COUNTER = "dataloader_wait_ns"
 

@@ -24,9 +24,15 @@ This module is the unified emission API the runtime instruments against:
   OFF by default even under ``enable()`` — it is sampled, and still the
   only category with per-op cost.
 - a ``jax.monitoring`` listener mirrors XLA compile events (trace time,
-  backend compile wall time) into the span stream and the
-  ``jit_backend_compile_ns`` counter — the compile-cache visibility the
-  CUPTI timeline gave the reference's device side.
+  backend compile wall time) into the span stream — the compile-cache
+  visibility the CUPTI timeline gave the reference's device side. The
+  counters of the same events (``jit_backend_compile_ns``) are always on
+  (``jit/compile_cache.py``).
+- every ``Span`` is also a ``jax.profiler.TraceAnnotation("pt/<name>")``
+  for its lifetime: whenever anyone captures a device trace, the
+  program's spans are in the same xplane on the same clock
+  (:func:`epoch_offset_ns` maps a span's ``t0`` onto it). Device-side
+  names are ``observability.scopes``' part.
 
 Completed spans fan out to three sinks: the profiler event buffer (the
 chrome-trace exporter), the flight-recorder ring (``flight.py`` — crash
@@ -36,12 +42,16 @@ evidence), and, when a run-log is active, the per-run JSONL stream
 """
 import random
 import threading
+import time
+
+from jax.profiler import TraceAnnotation
 
 from .. import monitor, profiler
 from . import flight, runlog
 
 __all__ = ["enable", "disable", "enabled", "trace_span", "current_span",
-           "count", "now_ns", "CATEGORIES", "DEFAULT_CATEGORIES",
+           "count", "now_ns", "epoch_offset_ns", "CATEGORIES",
+           "DEFAULT_CATEGORIES",
            "trace_context", "attach_context", "mint_context",
            "record_span"]
 
@@ -78,6 +88,25 @@ def _new_id():
 
 def now_ns():
     return profiler._now_ns()
+
+
+ANNOTATION_PREFIX = "pt/"
+
+
+def epoch_offset_ns():
+    """What to add to a span-clock time (:func:`now_ns`, monotonic) to
+    get nanoseconds since the epoch — the clock of a profiler trace,
+    whose event times count from its ``profile_start_time``. Read it
+    near the spans it is for: the two clocks drift apart by NTP's
+    slew."""
+    best = None
+    for _ in range(3):
+        before = profiler._now_ns()
+        wall = time.time_ns()
+        after = profiler._now_ns()
+        if best is None or after - before < best[0]:
+            best = (after - before, wall - (before + after) // 2)
+    return best[1]
 
 
 def trace_context():
@@ -177,7 +206,7 @@ class Span:
     parent_id) is inherited from the enclosing span, an attached remote
     context, or minted fresh for a root span."""
 
-    __slots__ = ("name", "cat", "attrs", "_t0",
+    __slots__ = ("name", "cat", "attrs", "_t0", "_annotation",
                  "trace_id", "span_id", "parent_id")
 
     def __init__(self, name, cat, attrs):
@@ -185,6 +214,7 @@ class Span:
         self.cat = cat
         self.attrs = attrs
         self._t0 = None
+        self._annotation = None
         self.trace_id = 0
         self.span_id = 0
         self.parent_id = 0
@@ -209,10 +239,19 @@ class Span:
             self.trace_id, self.parent_id = _new_id(), 0
         self.span_id = _new_id()
         stack.append(self)
+        # outside a profiler session a TraceMe is a flag test
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + self.name)
         self._t0 = profiler._now_ns()
+        self._annotation.__enter__()
         return self
 
+    @property
+    def t0(self):
+        """Start of the span on the span clock (:func:`now_ns`)."""
+        return self._t0
+
     def __exit__(self, *exc):
+        self._annotation.__exit__(None, None, None)
         end = profiler._now_ns()
         stack = _tls.stack
         if stack and stack[-1] is self:
@@ -290,12 +329,13 @@ def _install_jax_hook():
         if leaf.endswith("_duration"):
             leaf = leaf[: -len("_duration")]
         profiler.record_span(f"jax/{leaf}", "jit", end - dur_ns, end)
-        if "backend_compile" in event:
-            monitor.stat_add("jit_backend_compile_ns", dur_ns)
-            monitor.stat_add("jit_backend_compiles", 1)
 
     _jm.register_event_duration_secs_listener(_on_duration)
     _jax_hook_installed[0] = True
+    # the counters of the same events (jit_backend_compile_ns, ...) are
+    # always on and live with the compile cache's mirror
+    from ..jit import compile_cache
+    compile_cache._install_event_mirror()
 
 
 # -- sampled op-dispatch observer -----------------------------------------

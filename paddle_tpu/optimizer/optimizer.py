@@ -16,6 +16,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..nn.clip import ClipGradBase
+from ..observability.scopes import scope
 
 
 class _LRValue:
@@ -189,23 +190,25 @@ class _ZeroBucket:
         """Per-param arrays -> the [rows, 1024] bucket layout in ``dtype``
         (f32 for gradients/moments, the param dtype for stage-3 stores)."""
         segs = []
-        for v, n_rows, size in zip(vals, self.n_rows, self.sizes):
-            flat = jnp.ravel(v)
-            if flat.dtype != dtype:
-                flat = flat.astype(dtype)
-            pad = n_rows * _FLAT_LANES - size
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros((pad,), dtype)])
-            segs.append(flat.reshape(n_rows, _FLAT_LANES))
-        if self.pad_rows:
-            segs.append(jnp.zeros((self.pad_rows, _FLAT_LANES), dtype))
-        return segs[0] if len(segs) == 1 else jnp.concatenate(segs)
+        with scope("zero.bucket_copy"):
+            for v, n_rows, size in zip(vals, self.n_rows, self.sizes):
+                flat = jnp.ravel(v)
+                if flat.dtype != dtype:
+                    flat = flat.astype(dtype)
+                pad = n_rows * _FLAT_LANES - size
+                if pad:
+                    flat = jnp.concatenate([flat, jnp.zeros((pad,), dtype)])
+                segs.append(flat.reshape(n_rows, _FLAT_LANES))
+            if self.pad_rows:
+                segs.append(jnp.zeros((self.pad_rows, _FLAT_LANES), dtype))
+            return segs[0] if len(segs) == 1 else jnp.concatenate(segs)
 
     def unflatten(self, rows):
         """[rows, 1024] bucket layout -> per-param arrays (store dtype)."""
-        return [rows[off:off + n].reshape(-1)[:size].reshape(shape)
-                for off, n, size, shape in zip(self.row_offs, self.n_rows,
-                                               self.sizes, self.shapes)]
+        with scope("zero.bucket_copy"):
+            return [rows[off:off + n].reshape(-1)[:size].reshape(shape)
+                    for off, n, size, shape in zip(
+                        self.row_offs, self.n_rows, self.sizes, self.shapes)]
 
     def shard_of(self, rows_full, axis, bound):
         """This rank's [rows/degree, width] shard of a full row-aligned
@@ -844,8 +847,9 @@ class Optimizer:
             bound = dp_mode and parallel_env.axis_bound(axis)
             shard = sdict["param"].tensor._value
             if bound:
-                return jax.lax.all_gather(shard, axis, axis=0,
-                                          tiled=True)
+                with scope("zero.gather"):
+                    return jax.lax.all_gather(shard, axis, axis=0,
+                                              tiled=True)
             if dp_mode:
                 # abstract analysis trace: shape-only stand-in
                 return jnp.concatenate([shard] * degree, axis=0)
@@ -923,8 +927,9 @@ class Optimizer:
             vals.append(g)
         gfull = zb.flatten(vals)
         if bound:
-            gred = jax.lax.psum_scatter(
-                gfull, axis, scatter_dimension=0, tiled=True)
+            with scope("zero.reduce_scatter"):
+                gred = jax.lax.psum_scatter(
+                    gfull, axis, scatter_dimension=0, tiled=True)
             if not defer_mean:
                 gred = gred / degree
         elif dp_mode:
@@ -950,10 +955,8 @@ class Optimizer:
         into the sharded ``gacc`` window accumulator, so no full gradient
         outlives its micro step — the DeepSpeed-style trade of per-micro
         reduction traffic for 1/degree accumulation memory."""
-        from .. import monitor
         from ..distributed import parallel_env
         cfg = self._zero
-        monitor.stat_add("zero_accum_steps")
         if cfg["stage"] < 2:
             return
         axis, degree = cfg["axis"], cfg["degree"]
@@ -985,7 +988,6 @@ class Optimizer:
         (summation order differs from the per-param control by design —
         parity there is tolerance-level, not bitwise)."""
         from jax.sharding import NamedSharding, PartitionSpec
-        from .. import monitor
         from ..distributed import parallel_env
         from ..nn.clip import ClipGradByGlobalNorm, ClipGradByValue
         cfg = self._zero
@@ -1086,14 +1088,16 @@ class Optimizer:
                     ok = jnp.all(jnp.isfinite(gred))
                     all_ok = ok if all_ok is None else (all_ok & ok)
                 if isinstance(clip, ClipGradByGlobalNorm):
-                    s = jnp.sum(jnp.square(gred))
-                    sq_sum = s if sq_sum is None else sq_sum + s
+                    with scope("clip"):
+                        s = jnp.sum(jnp.square(gred))
+                        sq_sum = s if sq_sum is None else sq_sum + s
         if sq_sum is not None:
-            if bound:  # each rank holds 1/degree of the rows: psum completes
-                sq_sum = jax.lax.psum(sq_sum, axis)
-            global_norm = jnp.sqrt(sq_sum)
-            clip_scale = clip.clip_norm / jnp.maximum(global_norm,
-                                                      clip.clip_norm)
+            with scope("clip"):
+                if bound:  # each rank holds 1/degree of the rows
+                    sq_sum = jax.lax.psum(sq_sum, axis)
+                global_norm = jnp.sqrt(sq_sum)
+                clip_scale = clip.clip_norm / jnp.maximum(global_norm,
+                                                          clip.clip_norm)
 
         found_inf = None
         if scaler_pending:
@@ -1109,13 +1113,18 @@ class Optimizer:
 
         # shard-local clip/decay + update of one bucket, then publish its
         # params (stage 3: write the local shard rows; stage <=2: gather)
-        n_bytes = [0]
 
         def _apply_bucket(zb, sdict, gred, present):
+            with scope("update"):
+                _update_bucket(zb, sdict, gred, present)
+
+        def _update_bucket(zb, sdict, gred, present):
             if clip_scale is not None:
-                gred = gred * clip_scale
+                with scope("clip"):
+                    gred = gred * clip_scale
             elif isinstance(clip, ClipGradByValue):
-                gred = jnp.clip(gred, clip.min, clip.max)
+                with scope("clip"):
+                    gred = jnp.clip(gred, clip.min, clip.max)
             if stage == 3:
                 pstore = sdict["param"]
                 pshard = pstore.tensor._value
@@ -1226,8 +1235,9 @@ class Optimizer:
                     # gather would move: bitwise-identical, one step
                     # early.
                     if bound:
-                        nxt = jax.lax.all_gather(new_store, axis,
-                                                 axis=0, tiled=True)
+                        with scope("zero.gather"):
+                            nxt = jax.lax.all_gather(new_store, axis,
+                                                     axis=0, tiled=True)
                     elif dp_mode:  # analysis stand-in: shape only
                         nxt = jnp.concatenate([new_store] * degree,
                                               axis=0)
@@ -1238,8 +1248,9 @@ class Optimizer:
                     p._grad = None
             else:
                 if bound:
-                    full_new = jax.lax.all_gather(new_p, axis, axis=0,
-                                                  tiled=True)
+                    with scope("zero.gather"):
+                        full_new = jax.lax.all_gather(new_p, axis, axis=0,
+                                                      tiled=True)
                 elif dp_mode:  # analysis stand-in: shape only
                     full_new = jnp.concatenate([new_p] * degree, axis=0)
                 else:
@@ -1257,7 +1268,6 @@ class Optimizer:
                         # rank-divergent and would poison a replicated
                         # carry)
                         p._grad = None
-            n_bytes[0] += zb.rows * _FLAT_LANES * 4
 
         if barrier or not prefetch:
             # two-pass serial schedule: reduce every bucket, then update
@@ -1285,12 +1295,18 @@ class Optimizer:
                        if i + 1 < len(items) else None)
                 _apply_bucket(zb, sdict, _norm_bucket(sdict, gred),
                               present)
-        monitor.stat_add("zero_steps")
-        monitor.stat_add("zero_reduced_bytes", n_bytes[0])
         if scaler_pending:
             cfg["last_found_inf"] = found_inf
 
     def step(self):
+        """One update. In a compiled step its device time goes under the
+        scope `optimizer`: beneath it `update` (the elementwise rule and
+        the master-to-parameter cast), `clip`, and for ZeRO
+        `zero.reduce_scatter`, `zero.gather`, `zero.bucket_copy`."""
+        with scope("optimizer"):
+            return self._step()
+
+    def _step(self):
         from ..distributed import parallel_env
         acc = parallel_env.current_accum()
         if self._zero is not None:
@@ -1320,13 +1336,18 @@ class Optimizer:
         if self._grad_clip is not None:
             # sparse grads participate: they contribute their row values to
             # the global norm and get scaled as SelectedRows
-            params_grads = self._grad_clip(params_grads)
+            with scope("clip"):
+                params_grads = self._grad_clip(params_grads)
         dense = [(p, g) for p, g in params_grads
                  if not isinstance(g, SelectedRows)]
         sparse = [(p, g) for p, g in params_grads
                   if isinstance(g, SelectedRows)]
         self._step_count._value = self._step_count._value + 1
         lr = self._lr.value()
+        with scope("update"):
+            self._update(dense, sparse, lr)
+
+    def _update(self, dense, sparse, lr):
         for p, g in dense:
             if g is None:
                 continue

@@ -95,13 +95,13 @@ def test_category_toggle_and_unknown_category(tmp_path):
 # -- hot-path instrumentation ---------------------------------------------
 
 def test_jit_cache_counters_and_compile_span(tracing, tmp_path):
-    _reset("jit_cache_hit", "jit_cache_miss", "jit_compile_ns")
+    _reset("jit_cache_hit", "jit_cache_miss", "jit_build_ns")
     f = paddle.jit.to_static(lambda x: x * 3.0)
     x = paddle.to_tensor(np.ones((2, 2), np.float32))
     f(x)
     assert monitor.stat_get("jit_cache_miss") == 1
     assert monitor.stat_get("jit_cache_hit") == 0
-    assert monitor.stat_get("jit_compile_ns") > 0
+    assert monitor.stat_get("jit_build_ns") > 0
     f(x)
     assert monitor.stat_get("jit_cache_hit") == 1
     # shape change -> second miss
@@ -252,7 +252,7 @@ def test_event_buffer_cap_drops_not_grows(tracing, tmp_path):
 # -- step telemetry --------------------------------------------------------
 
 def test_step_timer_window_rates(tracing):
-    _reset("dataloader_wait_ns", "jit_compile_ns", "executor_compile_ns",
+    _reset("dataloader_wait_ns", "jit_build_ns", "executor_compile_ns",
            "jit_backend_compile_ns")
     timer = obs.StepTimer(window=4, publish_as="ttest").start()
     assert timer.step(tokens=100, examples=10) is not None or True

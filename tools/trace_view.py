@@ -21,11 +21,14 @@ Usage:
     python tools/trace_view.py RUNLOG.jsonl [...] -o trace.json
     python tools/trace_view.py logs/*.jsonl --trace <16-hex-trace-id>
     python tools/trace_view.py logs/*.jsonl --stats
+    python tools/trace_view.py --scopes TRACE_DIR [--table TABLE.json|HLO.txt]
 
 ``--trace`` restricts the output to one trace id plus everything
 reachable from it through parent edges and links — the "show me this
 p99 request" view. ``--stats`` prints a per-trace/per-process summary
-instead of writing a file.
+instead of writing a file. ``--scopes`` reads a device profile
+(``jax.profiler.start_trace``) instead of run-logs and prints its device
+time per scope path and per kind (``observability.scopes``).
 
 The module doubles as a library: ``load_events``, ``build_chrome_trace``
 and ``connected_spans`` are importable (the test suite reconstructs
@@ -301,10 +304,32 @@ def print_stats(events, n_bad, file=None):
             f"{t[:8]}…×{n}" for t, n in top), file=file)
 
 
+def print_scopes(trace_dir, table_path=None, file=sys.stdout):
+    """Device time of a profiler trace by the program's scopes."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from paddle_tpu.observability import scopes
+    table = None
+    if table_path and not table_path.endswith(".json"):
+        with open(table_path) as f:  # a compiled step's HLO text
+            table = scopes.scope_table(f.read())
+    elif table_path:
+        table = scopes.load_table(table_path)
+    print(scopes.format_by_scope(
+        scopes.device_time_by_scope(trace_dir, table)), file=file)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="merge run-log JSONL files into one chrome-trace")
-    ap.add_argument("logs", nargs="+", help="run-log .jsonl files")
+    ap.add_argument("logs", nargs="*", help="run-log .jsonl files")
+    ap.add_argument("--scopes", metavar="TRACE_DIR",
+                    help="print a profiler trace directory's device time "
+                    "per scope path and per kind, by the scope table "
+                    "saved beside it (observability.scopes.save_table) "
+                    "or given with --table")
+    ap.add_argument("--table", help="with --scopes: a scope table's JSON "
+                    "file, or a compiled step's HLO text to derive it from")
     ap.add_argument("-o", "--out", default="trace.json",
                     help="chrome-trace output path (default trace.json)")
     ap.add_argument("--trace", help="restrict to one trace id (16-hex) "
@@ -313,6 +338,11 @@ def main(argv=None):
                     help="print a summary instead of writing the trace")
     args = ap.parse_args(argv)
 
+    if args.scopes:
+        print_scopes(args.scopes, args.table)
+        return 0
+    if not args.logs:
+        ap.error("give run-log files, or --scopes TRACE_DIR")
     events, n_bad = load_events(args.logs)
     if args.stats:
         print_stats(events, n_bad)
