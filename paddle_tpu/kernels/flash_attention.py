@@ -3,13 +3,30 @@
 The analog of the reference's hand-written fused CUDA attention
 (`operators/fused/fused_attention_op.cu` family): online-softmax tiling keeps
 the S×S score matrix out of HBM entirely. Forward saves only the logsumexp
-row stats; backward recomputes scores blockwise (dq kernel + dkv kernel) with
-f32 accumulation. Layout [B, S, H, D] outside (framework attention layout),
-[B*H, S, D] inside.
+row stats; backward recomputes scores blockwise (dq kernel + dkv kernel).
+Layout [B, S, H, D] outside (framework attention layout), [B*H, S, D] inside.
 
-Block sizes 128×128 match the MXU tile; inputs may be bf16 (accumulation is
-always f32). Sequence is padded to a 128 multiple by the wrapper; padded key
-positions are masked with the true length.
+What is multiplied in which dtype: every `dot_general` takes its operands in
+the dtype the call's inputs arrive in and accumulates in float32. bf16 inputs
+go to the MXU as they are, and `p` (forward PV, backward dV) and `ds` (dQ, dK)
+are rounded to bf16 just before their product, as XLA's path does with
+`probs`; float32 inputs keep float32 operands. Everything that is not an MXU
+operand is float32: the scores (the softmax scale is applied to them, so it
+costs no rounding), the running max and sum, `exp`, `lse`, `delta` and the
+output accumulators.
+
+Blocks: a score tile is `block x block` with `block` the largest of 512, 256,
+128 that divides the sequence padded to a 128 multiple (measured on the v5e,
+PR 26: the kernels are bound by the latency of one loop iteration and by
+vector spills, not by the MXU, and 512 x 512 runs 2.4x faster than 128 x 128).
+The dkv kernel computes its scores transposed (`k q^T`, the row statistics as
+rows), so that each of its four products is a plain or a transposed-weights
+matmul and nothing is transposed on the vector units.
+
+Which blocks are masked: causal calls mask every block they visit by position
+(blocks wholly above the diagonal are not visited); padded key (forward, dq)
+or query (dkv) positions are masked with the true length where the wrapper
+padded, and a call with neither builds no mask at all.
 """
 import functools
 
@@ -17,9 +34,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_Q = 128
-BLOCK_KV = 128
+BLOCKS = (512, 256, 128)
 NEG_INF = -1e30
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def is_available():
@@ -27,40 +46,60 @@ def is_available():
     return jax.default_backend() == "tpu"
 
 
+def _block(padded_len):
+    return next(b for b in BLOCKS if padded_len % b == 0)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _rows(ref, i, n):
+    """Rows [i*n, (i+1)*n) of the [1, S, D] block `ref`."""
+    return ref[0, pl.ds(pl.multiple_of(i * n, n), n), :]
+
+
+def _keep(q_pos, k_pos, causal, padded_pos, true_len):
+    """Which scores of a tile count (None: all). `padded_pos` is the side
+    the wrapper padded past `true_len`, or None."""
+    keep = None if padded_pos is None else padded_pos < true_len
+    if causal:
+        keep = q_pos >= k_pos if keep is None else keep & (q_pos >= k_pos)
+    return keep
+
+
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
                 causal, scale, block_kv):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # [BQ, D]
-    bq, d = q.shape
-    q_pos = qi * BLOCK_Q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    bq, d = q_ref.shape[1:]
+    q = q_ref[0]
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
-    n_kv = pl.cdiv(k_ref.shape[1], block_kv)
+    s_k = k_ref.shape[1]
+    n_kv = s_k // block_kv
     if causal:
         # only blocks whose first key position <= last query position
-        n_kv = jnp.minimum(n_kv, (qi * BLOCK_Q + bq + block_kv - 1) // block_kv)
+        n_kv = jnp.minimum(n_kv, pl.cdiv((qi + 1) * bq, block_kv))
 
     def body(ki, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        k = _rows(k_ref, ki, block_kv)
+        v = _rows(v_ref, ki, block_kv)
+        s = _dot(q, k, _NT) * scale
         k_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_kv), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        s = jnp.where(mask, s, NEG_INF)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
+        keep = _keep(q_pos, k_pos, causal,
+                     k_pos if kv_len < s_k else None, kv_len)
+        if keep is not None:
+            s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * corr + _dot(p.astype(v.dtype), v, _NN)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
@@ -74,25 +113,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
 
 
 def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
-    """q/k/v: [BH, S, D] (seq padded to BLOCK multiples); kv_len = true
+    """q/k/v: [BH, S, D] (seq padded to 128 multiples); kv_len = true
     unpadded key length for masking."""
     bh, s_q, d = q.shape
     s_k = k.shape[1]
-    grid = (bh, s_q // BLOCK_Q)
+    block_q, block_kv = _block(s_q), _block(s_k)
     kernel = functools.partial(
         _fwd_kernel, kv_len=kv_len, causal=causal, scale=scale,
-        block_kv=BLOCK_KV)
+        block_kv=block_kv)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, s_q // block_q),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_Q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
@@ -108,33 +147,30 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    *, kv_len, causal, scale, block_kv):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
+    bq, d = q_ref.shape[1:]
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0]      # [BQ, 1]
     delta = delta_ref[0]  # [BQ, 1]
-    bq, d = q.shape
-    q_pos = qi * BLOCK_Q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
-    n_kv = pl.cdiv(k_ref.shape[1], block_kv)
+    s_k = k_ref.shape[1]
+    n_kv = s_k // block_kv
     if causal:
-        n_kv = jnp.minimum(n_kv, (qi * BLOCK_Q + bq + block_kv - 1) // block_kv)
+        n_kv = jnp.minimum(n_kv, pl.cdiv((qi + 1) * bq, block_kv))
 
     def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        k = _rows(k_ref, ki, block_kv)
+        v = _rows(v_ref, ki, block_kv)
+        p = jnp.exp(_dot(q, k, _NT) * scale - lse)
         k_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_kv), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+        keep = _keep(q_pos, k_pos, causal,
+                     k_pos if kv_len < s_k else None, kv_len)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        ds = p * (_dot(do, v, _NT) - delta)
+        return dq + _dot(ds.astype(k.dtype), k, _NN)
 
     dq = jax.lax.fori_loop(0, n_kv, body, jnp.zeros((bq, d), jnp.float32))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
@@ -142,44 +178,36 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, q_len, causal, scale, block_q):
+    """Scores, `p` and `ds` are held transposed, [BKV, BQ]; `lse_ref` and
+    `delta_ref` are [1, S_Q / BQ, 1, BQ], one row a query block."""
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    bkv, d = k.shape
-    k_pos = ki * BLOCK_KV + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+    bkv, d = k_ref.shape[1:]
+    k = k_ref[0]
+    v = v_ref[0]
+    k_pos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bkv, 1), 0)
 
-    n_q = pl.cdiv(q_ref.shape[1], block_q)
-    start_q = 0
-    if causal:
-        start_q = (ki * BLOCK_KV) // block_q  # earlier q blocks are masked
+    s_q = q_ref.shape[1]
+    start_q = (ki * bkv) // block_q if causal else 0  # earlier: all masked
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), :]      # [bq, 1]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), :]  # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        q = _rows(q_ref, qi, block_q)
+        do = _rows(do_ref, qi, block_q)
+        p = jnp.exp(_dot(k, q, _NT) * scale - lse_ref[0, qi])
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        mask = q_pos < q_len
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dv_new = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_new = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
+            jnp.int32, (1, block_q), 1)
+        keep = _keep(q_pos, k_pos, causal,
+                     q_pos if q_len < s_q else None, q_len)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        dv_new = dv + _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v, do, _NT) - delta_ref[0, qi])
+        dk_new = dk + _dot(ds.astype(q.dtype), q, _NN)
         return dk_new, dv_new
 
-    dk0 = jnp.zeros((bkv, d), jnp.float32)
-    dv0 = jnp.zeros((bkv, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, n_q, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    zero = jnp.zeros((bkv, d), jnp.float32)
+    dk, dv = jax.lax.fori_loop(start_q, s_q // block_q, body, (zero, zero))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -187,48 +215,51 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
                interpret):
     bh, s_q, d = q.shape
     s_k = k.shape[1]
+    block_q, block_kv = _block(s_q), _block(s_k)
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [BH, S, 1]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, kv_len=kv_len, causal=causal,
-                          scale=scale, block_kv=BLOCK_KV),
-        grid=(bh, s_q // BLOCK_Q),
+                          scale=scale, block_kv=block_kv),
+        grid=(bh, s_q // block_q),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_Q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_Q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    n_q = s_q // block_q
+    stat_spec = pl.BlockSpec((1, n_q, 1, block_q), lambda b, i: (b, 0, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, q_len=q_len, causal=causal,
-                          scale=scale, block_q=BLOCK_Q),
-        grid=(bh, s_k // BLOCK_KV),
+                          scale=scale, block_q=block_q),
+        grid=(bh, s_k // block_kv),
         in_specs=[
             pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, BLOCK_KV, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_KV, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_q, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_q, 1), lambda b, i: (b, 0, 0)),
+            stat_spec, stat_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, BLOCK_KV, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_KV, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse.reshape(bh, n_q, 1, block_q),
+      delta.reshape(bh, n_q, 1, block_q))
     return dq, dk, dv
 
 
@@ -275,9 +306,9 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=False):
     def to_bhsd(x):
         return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
 
-    qf, _ = _pad_seq(to_bhsd(q), BLOCK_Q)
-    kf, _ = _pad_seq(to_bhsd(k), BLOCK_KV)
-    vf, _ = _pad_seq(to_bhsd(v), BLOCK_KV)
+    qf, _ = _pad_seq(to_bhsd(q), BLOCKS[-1])
+    kf, _ = _pad_seq(to_bhsd(k), BLOCKS[-1])
+    vf, _ = _pad_seq(to_bhsd(v), BLOCKS[-1])
     out = _flash(qf, kf, vf, causal, float(scale), s_q, s_k, interpret)
     out = out[:, :s_q]
     return jnp.swapaxes(out.reshape(b, h, s_q, d), 1, 2)

@@ -10,10 +10,13 @@ import jax.numpy as jnp
 from ...core.dispatch import call_op
 from ...observability.scopes import scope
 
-# Crossover measured in rounds 2-4 on the shared v5e of that time (BLOCK
-# 128x128, head_dim 64; not re-measured since): XLA's fused attention won
-# up to ~1k tokens; the pallas flash kernel won beyond (1.1-1.3x at 2-4k)
-# and keeps memory O(S) instead of O(S^2).
+# Crossover measured in rounds 2-4 on the shared v5e of that time, with the
+# kernel of that time (128x128 blocks, head_dim 64): XLA's fused attention
+# won up to ~1k tokens; the pallas flash kernel won beyond (1.1-1.3x at
+# 2-4k) and keeps memory O(S) instead of O(S^2). The kernel under the gate
+# changed in PR 26 (512x512 blocks, 2.4-2.9x faster at seq 1024-2048 on
+# today's chip); the gate is still the old chip's and has not been
+# re-measured (ROADMAP S1).
 _FLASH_MIN_SEQ = 1024
 
 

@@ -7,7 +7,9 @@ refuse on the machine, and finding that here costs no chip time. These
 cases hold the pallas flash kernel — interpret mode everywhere else in
 the suite — to the real Mosaic lowering at the widths the models use:
 forward and backward, `gpt3_1p3b` head geometry (16 x 128) at seq
-1024/2048/8192 and BERT-base's (12 x 64) at the dispatch gate. Nothing
+1024/2048/8192 (512 x 512 score tiles; s8192 bounds the VMEM they may
+take), at 1280 (256 tiles) and 1100 (a padded tail, 128 tiles), and
+BERT-base's (12 x 64) at the dispatch gate and causal. Nothing
 runs, so they say nothing about results or times; `chip_smoke.py` does.
 
 Plus the compile-cache placement rule, in a subprocess so jax's config is
@@ -53,6 +55,9 @@ FLASH_CASES = [
     ((1, 8192, 16, 128), jnp.bfloat16, True),
     ((2, 1024, 12, 64), jnp.bfloat16, False),
     ((1, 2048, 16, 128), jnp.float32, True),
+    ((2, 1280, 16, 128), jnp.bfloat16, True),
+    ((2, 1100, 16, 128), jnp.bfloat16, True),
+    ((2, 2048, 12, 64), jnp.bfloat16, True),
 ]
 
 
