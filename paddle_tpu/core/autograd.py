@@ -21,6 +21,8 @@ from ..observability import scopes as _scopes
 __all__ = [
     "TapeNode",
     "grad_enabled",
+    "differentiated",
+    "functional_region",
     "no_grad",
     "enable_grad",
     "backward",
@@ -31,6 +33,7 @@ __all__ = [
 class _GradState(threading.local):
     def __init__(self):
         self.enabled = True
+        self.functional = False
 
 
 _state = _GradState()
@@ -40,14 +43,36 @@ def grad_enabled() -> bool:
     return _state.enabled
 
 
+def differentiated() -> bool:
+    """Will what runs now be differentiated? True while the tape
+    records, and inside a :func:`functional_region`."""
+    return _state.enabled or _state.functional
+
+
 @contextmanager
 def no_grad():
-    prev = _state.enabled
+    prev = (_state.enabled, _state.functional)
+    _state.enabled = _state.functional = False
+    try:
+        yield
+    finally:
+        _state.enabled, _state.functional = prev
+
+
+@contextmanager
+def functional_region():
+    """The tape is off because ONE enclosing op differentiates the whole
+    closure (``jax.vjp`` over a rolled loop's body): no node is
+    recorded, but the seams that shape the backward still apply — a
+    layer with ``enable_recompute`` stages its ``jax.checkpoint``. Where
+    nothing is being differentiated this is plain :func:`no_grad`."""
+    prev = (_state.enabled, _state.functional)
+    _state.functional = differentiated()
     _state.enabled = False
     try:
         yield
     finally:
-        _state.enabled = prev
+        _state.enabled, _state.functional = prev
 
 
 @contextmanager

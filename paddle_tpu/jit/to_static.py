@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import monitor
 from ..core import state as state_mod
+from ..core.dispatch import _CAPTURE as _dispatch_capture
 from ..core.tensor import Tensor
 from ..observability import scopes as _scopes
 from ..observability import tracing as _obs
@@ -126,6 +127,21 @@ _DATA_DEPENDENT_ERRORS = _data_dependent_errors()
 
 def in_tracing():
     return _is_tracing
+
+
+# what the newest trace of a step body staged, by kind: rolled regions'
+# trip counts and remat segments. Every trace of a build stages the same
+# structure, so the tally restarts with each and the build publishes the
+# last one (`jit_rolled_loop_trips`, `jit_recompute_segments`).
+_STRUCTURE = {"rolled_loop_trips": 0, "recompute_segments": 0}
+
+
+def note_structure(kind, count=1):
+    """Called by a construct as it stages itself into a step trace
+    (nothing outside one, nothing from a capture pass, whose operations
+    are dropped)."""
+    if _is_tracing and not _dispatch_capture.stack:
+        _STRUCTURE[kind] += count
 
 
 def _is_dynamic(x):
@@ -496,6 +512,8 @@ class StaticFunction:
             # jit_backend_compile_ns)
             monitor.stat_add("jit_cache_miss", 1)
             monitor.stat_add("jit_build_ns", time.perf_counter_ns() - t0)
+            for kind, count in _STRUCTURE.items():
+                monitor.stat_add("jit_" + kind, count)
             entry[2]["traced_with_scopes"] = (
                 _scopes.entered() > scopes_before)
             from ..analysis import debug_enabled
@@ -871,6 +889,8 @@ class StaticFunction:
 
         def pure_fn(state_vals, dyn_vals, grad_vals):
             from ..distributed import parallel_env
+            for kind in _STRUCTURE:
+                _STRUCTURE[kind] = 0
             leaves = list(template_leaves)
             for i, v in zip(dyn_idx, dyn_vals):
                 leaves[i] = Tensor(v)

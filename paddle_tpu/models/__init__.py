@@ -8,6 +8,9 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForCausalLM, gpt_small, gpt3_1p3b,
     build_pipeline_layer, synthetic_lm_batch,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig, OuroModel, OuroForCausalLM,
+)
 from .ctr import (  # noqa: F401
     WideAndDeep, synthetic_ctr_batches, build_ctr_scan_step,
     train_ctr_windows,

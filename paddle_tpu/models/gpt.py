@@ -9,9 +9,10 @@ from jax.sharding import PartitionSpec as P
 from .. import nn, ops
 from ..nn import functional as F
 from ..observability.scopes import scope
+from .decoder import DecoderBlock, DecoderConfig
 
 
-class GPTConfig:
+class GPTConfig(DecoderConfig):
     def __init__(self, vocab_size=50304, hidden_size=768, num_layers=12,
                  num_heads=12, intermediate_size=None, max_seq_len=1024,
                  hidden_dropout=0.1, attention_dropout=0.1, use_mp=False):
@@ -34,41 +35,9 @@ def gpt_small(**kw):
     return GPTConfig(**kw)
 
 
-class GPTBlock(nn.Layer):
-    def __init__(self, cfg):
-        super().__init__()
-        h = cfg.hidden_size
-        self.ln1 = nn.LayerNorm(h)
-        self.qkv = nn.Linear(h, 3 * h)
-        self.proj = nn.Linear(h, h)
-        self.ln2 = nn.LayerNorm(h)
-        self.fc1 = nn.Linear(h, cfg.intermediate_size)
-        self.fc2 = nn.Linear(cfg.intermediate_size, h)
-        self.dropout = nn.Dropout(cfg.hidden_dropout)
-        self.num_heads = cfg.num_heads
-        self.head_dim = h // cfg.num_heads
-        self.attn_dropout_p = cfg.attention_dropout
-        if cfg.use_mp:
-            self.qkv.weight.pspec = P(None, "mp")
-            self.qkv.bias.pspec = P("mp")
-            self.proj.weight.pspec = P("mp", None)
-            self.fc1.weight.pspec = P(None, "mp")
-            self.fc1.bias.pspec = P("mp")
-            self.fc2.weight.pspec = P("mp", None)
-
-    def forward(self, x):
-        b, s = x.shape[0], x.shape[1]
-        h = self.ln1(x)
-        qkv = ops.reshape(self.qkv(h), [b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = ops.unstack(qkv, axis=2)
-        ctx = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True, dropout_p=self.attn_dropout_p,
-            training=self.training)
-        ctx = ops.reshape(ctx, [b, s, self.num_heads * self.head_dim])
-        x = x + self.dropout(self.proj(ctx))
-        h = self.ln2(x)
-        x = x + self.dropout(self.fc2(F.gelu(self.fc1(h))))
-        return x
+# the one decoder block (models/decoder.py) as GPT-2/3 configures it:
+# pre-LN, learned positions, a fused biased qkv, a GELU FFN
+GPTBlock = DecoderBlock
 
 
 class GPTModel(nn.Layer):

@@ -36,7 +36,7 @@ from .layer.transformer import (  # noqa: F401
 from .layer.rnn import SimpleRNN, LSTM, GRU, RNNCellBase, LSTMCell, GRUCell, SimpleRNNCell, BeamSearchDecoder, dynamic_decode  # noqa: F401
 from .clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue  # noqa: F401
 from .control_flow import (  # noqa: F401
-    while_loop, cond, case, switch_case,
+    while_loop, fixed_loop, cond, case, switch_case,
     create_array, array_write, array_read, array_length,
 )
 from .layer.extras import (  # noqa: F401

@@ -1,4 +1,5 @@
-"""Control flow: while_loop / cond / case / switch_case (+ TensorArray ops).
+"""Control flow: while_loop / fixed_loop / cond / case / switch_case
+(+ TensorArray ops).
 
 TPU-native redesign of the reference's control-flow operators
 (`/root/reference/paddle/fluid/operators/controlflow/while_op.cc`,
@@ -20,6 +21,13 @@ there are two regimes:
   operands so `jax.vjp` differentiates the whole construct; the reference
   obtains the same operand set from sub-block external-variable analysis.
 
+`fixed_loop(body, loop_vars, trips)` is the reference's `while_op` with a
+static bound and no predicate: eagerly a python loop, under tracing ONE
+`lax.scan(length=trips)` whose body is captured once. It returns every
+trip's loop vars stacked `[trips, ...]`, and the gradients of the
+parameters the body reads are the sum over trips — a stack of layers
+applied several times over the same weights (models/ouro.py).
+
 Branch bodies must be side-effect free (no state mutation), matching XLA
 semantics; the capture pass runs each branch once at trace time.
 """
@@ -29,10 +37,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import autograd, dispatch
+from ..core import state as state_mod
 from ..core.dispatch import call_op, call_op_nograd, unwrap, bind_values
 from ..core.tensor import Tensor
+from ..observability.scopes import scope
 
-__all__ = ["while_loop", "cond", "case", "switch_case",
+__all__ = ["while_loop", "fixed_loop", "cond", "case", "switch_case",
            "create_array", "array_write", "array_read", "array_length"]
 
 
@@ -104,10 +114,10 @@ def _merge_ext(*ext_lists):
     return merged
 
 
-def _functional(branch, ext, ext_vals, *args):
+def _functional(branch, ext, ext_vals, *args, tape_off=autograd.no_grad):
     """Re-run a branch with captured externals bound to functional values,
     tape recording off (the enclosing call_op owns differentiation)."""
-    with bind_values(ext, ext_vals), autograd.no_grad(), \
+    with bind_values(ext, ext_vals), tape_off(), \
             _suspend_static_hook():
         out = branch(*args)
         # flatten INSIDE the bind scope: a branch may return a bound tensor
@@ -258,6 +268,10 @@ def case(pred_fn_pairs, default=None, name=None):
 # while_loop
 # ---------------------------------------------------------------------------
 
+def _as_var_list(out):
+    return list(out) if isinstance(out, (list, tuple)) else [out]
+
+
 def while_loop(cond, body, loop_vars, is_test=False, name=None,
                maximum_trip_count=None):
     """`while cond(*vars): vars = body(*vars)`; returns the final vars list.
@@ -284,8 +298,7 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
         while bool(np.asarray(
                 unwrap(c) if isinstance((c := cond(*vars_)), Tensor) else c
                 ).reshape(())):
-            out = body(*vars_)
-            vars_ = list(out) if isinstance(out, (list, tuple)) else [out]
+            vars_ = _as_var_list(body(*vars_))
         return vars_
 
     flat, treedef = jax.tree_util.tree_flatten(
@@ -293,8 +306,7 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
     ext_c, _ = _capture(cond, *vars_)
     ext_b, body_out = _capture(body, *vars_)
     ext = _merge_ext(ext_c, ext_b)
-    _, out_def = _flatten_out(
-        list(body_out) if isinstance(body_out, (list, tuple)) else [body_out])
+    _, out_def = _flatten_out(_as_var_list(body_out))
     if out_def != treedef:
         raise ValueError(
             f"body must return the loop_vars structure: {treedef}, "
@@ -365,6 +377,104 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
 
         outs = call_op(run, *ext, *flat, op_name="while")
 
+    outs = outs if isinstance(outs, tuple) else (outs,)
+    return jax.tree_util.tree_unflatten(treedef, list(outs))
+
+
+# ---------------------------------------------------------------------------
+# fixed_loop
+# ---------------------------------------------------------------------------
+
+def _signature(leaves):
+    return [(tuple(jnp.shape(unwrap(l))), jnp.result_type(unwrap(l)))
+            for l in leaves]
+
+
+def fixed_loop(body, loop_vars, trips, rolled=None):
+    """`for _ in range(trips): vars = body(*vars)`; returns the list of
+    loop vars with every trip's value stacked on a new first axis
+    (`out[i][t]` is var i after trip t+1, `out[i][-1]` the final one).
+
+    Reference: while_op (`operators/controlflow/while_op.cc`) under a
+    bound known when the program is built. `rolled=None` rolls the loop
+    exactly when the loop vars are traced (`to_static`): the body is
+    captured once, the tensors it reads from the enclosing scope become
+    operands, and `lax.scan(length=trips)` runs it as one tape node, so
+    their gradients are summed over the trips by the scan's transpose.
+    There is no predicate and no select over the carry. Eagerly (or with
+    `rolled=False`) it is the python loop, differentiated by the tape op
+    by op. A layer inside the body with `enable_recompute` keeps its
+    remat segment in both forms. In a compiled step the region's device
+    time goes under the scope `loop`.
+
+    The body returns the loop vars' structure, shapes and dtypes, and
+    must not mutate framework state (dropout's generator, BN statistics)
+    when rolled."""
+    if not isinstance(loop_vars, (list, tuple)) or not loop_vars:
+        raise ValueError("loop_vars must be a non-empty list/tuple")
+    trips = int(trips)
+    if trips < 1:
+        raise ValueError(f"trips must be >= 1, got {trips}")
+    vars_ = list(loop_vars)
+
+    def flatten(tree):
+        return jax.tree_util.tree_flatten(
+            tree, is_leaf=lambda x: isinstance(x, Tensor))
+
+    flat, treedef = flatten(vars_)
+    if rolled is None:
+        rolled = any(_is_traced(unwrap(v)) for v in flat) \
+            and not _static_recording()
+
+    def check(out_leaves, out_def):
+        if out_def != treedef or _signature(out_leaves) != _signature(flat):
+            raise TypeError(
+                f"fixed_loop: the body must return the loop vars as it "
+                f"got them: {treedef} {_signature(flat)}, got {out_def} "
+                f"{_signature(out_leaves)}")
+
+    with scope("loop"):
+        if not rolled:
+            per_trip = []
+            for _ in range(trips):
+                leaves, out_def = flatten(_as_var_list(body(*vars_)))
+                check(leaves, out_def)
+                vars_ = jax.tree_util.tree_unflatten(treedef, leaves)
+                per_trip.append(leaves)
+            outs = [call_op(lambda *v: jnp.stack(v), *trip, op_name="stack")
+                    for trip in zip(*per_trip)]
+            return jax.tree_util.tree_unflatten(treedef, outs)
+
+        before = [(t, t._value) for _uid, t in state_mod.snapshot()]
+        ext, body_out = _capture(body, *vars_)
+        for t, value in before:
+            if t._value is not value:
+                t._value = value
+                raise RuntimeError(
+                    f"fixed_loop: the body changed framework state "
+                    f"({t.name!r}); a rolled body must be free of side "
+                    f"effects (dropout inside it needs rolled=False)")
+        check(*flatten(_as_var_list(body_out)))
+        n_ext = len(ext)
+
+        def rebuild(carry):
+            return jax.tree_util.tree_unflatten(
+                treedef, [Tensor(v) for v in carry])
+
+        def run(*vals):
+            ext_vals, var_vals = vals[:n_ext], vals[n_ext:]
+
+            def trip(carry, _):
+                new, _ = _functional(body, ext, ext_vals, *rebuild(carry),
+                                     tape_off=autograd.functional_region)
+                return tuple(new), tuple(new)
+
+            _, stacked = lax.scan(trip, tuple(var_vals), None, length=trips)
+            return stacked
+
+        from ..jit.to_static import note_structure
+        note_structure("rolled_loop_trips", trips)
+        outs = call_op(run, *ext, *flat, op_name="fixed_loop")
     outs = outs if isinstance(outs, tuple) else (outs,)
     return jax.tree_util.tree_unflatten(treedef, list(outs))
 

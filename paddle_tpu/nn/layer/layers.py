@@ -221,7 +221,7 @@ class Layer:
     def enable_recompute(self, policy="full"):
         """Run this layer's forward as an activation-recompute segment
         (``paddle_tpu.recompute``): activations inside are dropped per
-        ``policy`` (``full`` / ``selective`` / ``offload``) and
+        ``policy`` (``full`` / ``selective`` / ``kernels`` / ``offload``) and
         rematerialized in backward — dropout replays bitwise via the
         threaded RNG state. Applies in train mode while gradients are
         enabled; eval/no-grad calls run the plain forward. Returns
@@ -327,8 +327,8 @@ class Layer:
                 inputs = result if isinstance(result, tuple) else (result,)
         rc_policy = self.__dict__.get("_recompute_policy")
         if rc_policy is not None and self.training:
-            from ...core.autograd import grad_enabled
-            if grad_enabled():
+            from ...core.autograd import differentiated
+            if differentiated():
                 # always-immediate call shape: the public recompute()
                 # returns a WRAPPER for no-arg calls, and a forward
                 # taking zero inputs must still run here

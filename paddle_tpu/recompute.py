@@ -23,6 +23,10 @@ Policies::
                chain (``jax.checkpoint_policies.checkpoint_dots`` class
                of policy) — the usual sweet spot: dots are the expensive
                ops AND the big activations are mostly elementwise chains
+    kernels    save what the Pallas kernels wrote (the flash kernel's
+               output and log-sum-exp: 1/h of a layer's activations),
+               recompute everything else — ``full`` without replaying
+               the one operation XLA cannot fuse into its neighbours
     offload    save dot outputs but park them in PINNED HOST memory
                (``offload_dot_with_no_batch_dims('device',
                'pinned_host')``): device residency of ``full`` with the
@@ -84,7 +88,7 @@ from .core.tensor import Tensor
 __all__ = ["recompute", "resolve_policy", "host_offload_available",
            "remat_replay", "is_remat_replay", "POLICIES"]
 
-POLICIES = ("none", "full", "selective", "offload")
+POLICIES = ("none", "full", "selective", "kernels", "offload")
 
 # host memory kind used by the offload policy (pjit memory kinds)
 OFFLOAD_MEMORY_KIND = "pinned_host"
@@ -117,6 +121,10 @@ def _reset_offload_probe():
         _offload_probe[0] = None
 
 
+def _kernel_outputs_saveable(prim, *_avals, **_params):
+    return prim.name == "pallas_call"
+
+
 def resolve_policy(policy, strict=False):
     """``(jax_policy_or_None, effective_name)`` for a policy name (or a
     raw ``jax.checkpoint_policies`` callable, passed through for power
@@ -145,6 +153,8 @@ def resolve_policy(policy, strict=False):
         # checkpoint_dots analog that does not hoard the big batched
         # activations
         return cp.dots_with_no_batch_dims_saveable, "selective"
+    if name == "kernels":
+        return _kernel_outputs_saveable, "kernels"
     # offload
     if host_offload_available():
         return (cp.offload_dot_with_no_batch_dims(
@@ -303,6 +313,9 @@ def _segment_call(fn, args, kwargs, policy):
 
     run.__name__ = "recompute"
     run._remat_policy = effective
+    if effective != "none":
+        from .jit.to_static import note_structure
+        note_structure("recompute_segments")
     _seg_counter[0] += 1
     run._remat_segment = _seg_counter[0]
 
