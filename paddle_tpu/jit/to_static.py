@@ -130,10 +130,13 @@ def in_tracing():
 
 
 # what the newest trace of a step body staged, by kind: rolled regions'
-# trip counts and remat segments. Every trace of a build stages the same
+# trip counts, remat segments and activation factors saved for the
+# backward (`F.gelu`'s erfc). Every trace of a build stages the same
 # structure, so the tally restarts with each and the build publishes the
-# last one (`jit_rolled_loop_trips`, `jit_recompute_segments`).
-_STRUCTURE = {"rolled_loop_trips": 0, "recompute_segments": 0}
+# last one (`jit_rolled_loop_trips`, `jit_recompute_segments`,
+# `jit_saved_activation_factors`).
+_STRUCTURE = {"rolled_loop_trips": 0, "recompute_segments": 0,
+              "saved_activation_factors": 0}
 
 
 def note_structure(kind, count=1):

@@ -4,6 +4,7 @@ them into adjacent matmuls/convs, replacing the reference's hand-fused CUDA.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.dispatch import call_op
 from ...ops.math import _unary
@@ -25,9 +26,48 @@ def tanh(x):
     return _unary(jnp.tanh, x, "tanh")
 
 
+def _sqrt_half(x):
+    return np.sqrt(0.5).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _gelu_exact(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+def _gelu_exact_fwd(x):
+    """`jax.nn.gelu`'s own expression, `0.5 * x * erfc(-x * sqrt(0.5))`,
+    with the erfc factor `t` pinned and saved. XLA:TPU stores no GELU
+    output: left alone it recomputes the erfc polynomial (~110 float32
+    vector operations an element) inside each consumer's fusion — the
+    next matmul's forward, its weight gradient and its input gradient —
+    and those matmuls wait for it. Behind the barrier the polynomial runs
+    once; `x * t / 2` stays free to fuse anywhere (store `t`, not `y`:
+    the derivative needs `t`)."""
+    from ...jit.to_static import note_structure
+    note_structure("saved_activation_factors")
+    t = jax.lax.optimization_barrier(jax.lax.erfc(-x * _sqrt_half(x)))
+    return 0.5 * x * t, (x, t)
+
+
+def _gelu_exact_bwd(res, g):
+    # jax's own rule for that expression, term for term and in its
+    # dtype, reading the saved factor: g * (t / 2 + x * pdf(x))
+    x, t = res
+    c = _sqrt_half(x)
+    du = (np.array(-2.0 / np.sqrt(np.pi), x.dtype) * (0.5 * x * g)
+          * jnp.exp(-jnp.square(-x * c)))
+    return (-(du * c) + 0.5 * (g * t),)
+
+
+_gelu_exact.defvjp(_gelu_exact_fwd, _gelu_exact_bwd)
+
+
 def gelu(x, approximate=False):
-    return call_op(lambda v: jax.nn.gelu(v, approximate=approximate), x,
-                   op_name="gelu")
+    if approximate:
+        return call_op(lambda v: jax.nn.gelu(v, approximate=True), x,
+                       op_name="gelu")
+    return call_op(_gelu_exact, x, op_name="gelu")
 
 
 def silu(x):

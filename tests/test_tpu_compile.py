@@ -86,6 +86,66 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, shape, dtype, causal,
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
 
 
+def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
+                                                         monkeypatch):
+    """A GPT block at the cells' widths (h 2048, FFN 8192), forward and
+    backward under bf16 autocast, compiled for the chip: the erfc
+    polynomial of the exact GELU (an `exponential` and a `divide` pair
+    of its own, over `[.., 8192]`) is in the program once, and the
+    derivative's `exp(-x^2/2)` beside it. Left to itself XLA:TPU stores
+    no GELU and recomputes the polynomial in fc2's forward, its weight
+    gradient and its input gradient (4 `exponential`, 6 `divide`):
+    `F.gelu` keeps the factor behind a barrier, and this case fails the
+    day the compiler duplicates through it."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.decoder import DecoderBlock
+    from paddle_tpu.models.gpt import GPTConfig
+
+    block = DecoderBlock(GPTConfig(
+        vocab_size=128, hidden_size=2048, num_layers=1, num_heads=16,
+        intermediate_size=8192, max_seq_len=512, hidden_dropout=0.0,
+        attention_dropout=0.0))
+    block.to("bfloat16")
+
+    def fwd_bwd(x):
+        with paddle.amp.auto_cast(enable=True, dtype="bfloat16"):
+            loss = block(x).sum()
+        loss.backward()
+        return loss
+
+    # to_static's own program, handed to the chip's compiler in place of
+    # the attached backend's: keep the function it would jit and the
+    # arguments of its first call
+    held = {}
+
+    class Staged(Exception):
+        pass
+
+    def keep(self, fun, **kwargs):
+        def call(*args):
+            held.update(fun=fun, kwargs=kwargs, args=args)
+            raise Staged
+        return call
+
+    step = paddle.jit.to_static(fwd_bwd)
+    monkeypatch.setattr(type(step), "_jit", keep)
+    x = paddle.to_tensor(jnp.zeros((2, 512, 2048), jnp.bfloat16),
+                         stop_gradient=False)
+    with pytest.raises(Staged):
+        step(x)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                       sharding=v5e_chip), held["args"])
+    text = jax.jit(held["fun"], **held["kwargs"]).lower(*shapes).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    ffn_wide = {op: len(re.findall(
+        r"= \w+\[[\d,]*8192\]\S* %s\(" % op, text))
+        for op in ("exponential", "divide")}
+    assert ffn_wide == {"exponential": 2, "divide": 2}
+
+
 _PLACEMENT_PROBE = """
 import json
 import jax
