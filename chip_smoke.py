@@ -36,7 +36,7 @@ SEED = 0
 LR = 1e-4
 # BertConfig's defaults ARE the published base widths (12 x 768, 12
 # heads, FFN 3072); vocab padded to a multiple of 128, dropout off so
-# two program structures can be compared from one seed (bench.py's cell)
+# two program structures can be compared from one seed
 BERT = dict(vocab_size=30720, hidden_dropout=0.0, attention_dropout=0.0)
 BATCH, SEQ = 16, 512
 SCAN_K, UNROLL_K, TRAIN_STEPS = 4, 2, 12
@@ -128,7 +128,7 @@ def mlm_batches(seeds, batch, seq, vocab):
 
 
 def build_bert_step(structure, k, cfg_kw, dp_axis=None, zero_stage=0):
-    """bench.py's cell: pure-bf16 params, fp32 masters in AdamW, bf16
+    """The BERT-base cell: pure-bf16 params, fp32 masters in AdamW, bf16
     autocast, `optimization_barrier` between backward and update — as a
     scan step (`scan_steps=k`) or as k python-unrolled steps, both over
     [k, ...]-stacked batches."""

@@ -29,7 +29,7 @@ and error findings raise ``VerifyError`` — the fluid-era "Pass validates
 the graph before execution" contract. Findings always export as
 observability counters (``analysis_findings{rule=...,severity=...}``).
 The repo-level front-end is ``tools/lint_program.py`` (CI gate: source
-lint + the verified benchmark-ladder miniatures in `ladder`).
+lint + the verified tiny programs in `ladder`).
 """
 import os
 

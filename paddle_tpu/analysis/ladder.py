@@ -1,15 +1,13 @@
-"""Verified miniatures of the default benchmark ladder's programs.
+"""The analyzers' set of verified tiny programs (the "ladder").
 
-Every config in ``benchmarks/run_all.py``'s default ladder has a tiny
-static-graph twin here — same workload class (conv+BN for resnet,
+One static-graph miniature per workload class (conv+BN for resnet,
 embedding+attention-ish matmuls for gpt/bert, ragged-ish head for
 detection, table lookup for hbm_cache, per-rank collective sequences for
 allreduce) at smoke scale, recorded as a Program and pushed through the
 full analyzer (graph verifier, dtype/shape checker, donation checker,
 program lint, collective-order checker). ``tools/lint_program.py
---ladder`` runs them in CI, and ``run_all.py --write-baseline`` refuses to
-pin a perf baseline while any of them fails verification — the ladder's
-timings are only meaningful for programs the verifier accepts.
+--ladder`` verifies them; ``tools/mem_view.py --ladder`` and
+``tools/overlap_view.py --ladder`` attribute them.
 """
 
 __all__ = ["LADDER_BUILDERS", "build_ladder_programs", "verify_ladder",
@@ -400,9 +398,8 @@ def verify_ladder(configs=None, mesh_axes=("dp",), memory=True,
     """Run the full analyzer over every ladder program — including
     XLA memory attribution of each twin (``observability.memory
     .attribute_program``): a twin whose executable yields no byte
-    accounting refuses the ladder exactly like a verify failure, so a
-    perf baseline is never pinned from programs the memory gate cannot
-    measure. ``programs`` takes pre-built ``{name: pairs}`` (from
+    accounting refuses the ladder exactly like a verify failure.
+    ``programs`` takes pre-built ``{name: pairs}`` (from
     :func:`build_ladder_programs`) so a caller running both this and
     :func:`attribute_memory` builds the twins once. Returns
     ``(findings, summary)`` where summary maps config -> op counts per

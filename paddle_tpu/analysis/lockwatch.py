@@ -14,8 +14,7 @@ Opt-in via ``PADDLE_TPU_LOCKWATCH=1`` (set before the process imports
 paddle_tpu to cover module-level locks; the virtual-pod chaos tier arms
 its child ranks this way) or :func:`enable` before constructing a
 subsystem. Disarmed, the factories return the raw ``threading``
-primitives — near-zero cost (the ``lockwatch_overhead`` bench row pins
-the ratio).
+primitives (``tests/test_analysis.py`` checks that they are).
 
 Recipe::
 

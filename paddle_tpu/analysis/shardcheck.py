@@ -80,8 +80,7 @@ whose params are never re-gathered is a ``collective-budget-mismatch``
 Findings route through the shared ``analysis_findings{rule=,severity=}``
 counter export and the ``# lint: <rule>`` structured-suppression syntax
 like every other checker; ``check_static_function`` runs shardcheck by
-default, and an ERROR refuses ``run_all.py --write-baseline`` exactly
-like an unverified ladder does.
+default.
 """
 import re
 

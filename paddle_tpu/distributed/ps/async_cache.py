@@ -35,8 +35,7 @@ armed flight recorder dumps with the kill site as the last span.
 Overlap telemetry: the prefetcher accounts total plan time (host dedupe
 + PS pull + device install) against the consumer-visible wait in
 :meth:`CachePrefetcher.take`; ``overlap_efficiency()`` = the fraction
-of that pipeline time hidden behind compute — the number the
-``ctr_overlap_efficiency`` bench row reports.
+of that pipeline time hidden behind compute.
 """
 import queue
 import sys

@@ -1,6 +1,6 @@
 """Dygraph MoE layer over parallel.moe (name-compatible with the later
 reference releases' paddle.incubate.distributed.models.moe.MoELayer; this
-snapshot has no MoE — see COMPONENTS.md 'Beyond the reference')."""
+snapshot has no MoE)."""
 import zlib
 
 import jax

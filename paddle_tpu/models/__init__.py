@@ -1,5 +1,5 @@
 """Flagship model families (PaddleNLP/PaddleClas-parity models running on the
-TPU-native framework — see BASELINE.md configs)."""
+TPU-native framework)."""
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining, bert_base, bert_large,
     synthetic_mlm_batch,

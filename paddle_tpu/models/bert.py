@@ -1,5 +1,5 @@
-"""BERT/ERNIE-style encoder for pretraining — the flagship bench model
-(BASELINE.md config 3: ERNIE-1.0 / BERT-base pretraining, Fleet DP).
+"""BERT/ERNIE-style encoder for pretraining
+(ERNIE-1.0 / BERT-base pretraining, Fleet DP).
 
 TPU-first: bf16 activations, fused XLA attention (pallas flash for long seq),
 GSPMD sharding specs on every parameter (dp-replicated / mp-sharded per the

@@ -1,4 +1,4 @@
-"""GPT-style decoder (BASELINE.md config 4: GPT-3 1.3B, Fleet sharding + PP).
+"""GPT-style decoder (GPT-3 1.3B, Fleet sharding + PP).
 
 TPU-first: causal flash attention, GSPMD mp sharding on qkv/ffn, ZeRO via
 optimizer-state specs, and a PipelineLayer description for pp segmentation.
@@ -122,7 +122,7 @@ def build_pipeline_layer(cfg, num_stages, loss_fn=None):
 
 def build_gpt_1f1b_step(model, mesh, axis_pp="pp", axis_dp=None):
     """Fused dp x pp 1F1B training step over the REAL model's parameters
-    (BASELINE.md config 4 — the reference's PipelineOptimizer + sharding
+    (the reference's PipelineOptimizer + sharding
     hybrid, as one XLA program via parallel.spmd_pipeline_1f1b).
 
     The per-stage computation reuses GPTBlock.forward itself: block

@@ -16,7 +16,7 @@ from ...observability.scopes import scope
 # 2-4k) and keeps memory O(S) instead of O(S^2). The kernel under the gate
 # changed in PR 26 (512x512 blocks, 2.4-2.9x faster at seq 1024-2048 on
 # today's chip); the gate is still the old chip's and has not been
-# re-measured (ROADMAP S1).
+# re-measured (ROADMAP S5, "the flash gate").
 _FLASH_MIN_SEQ = 1024
 
 

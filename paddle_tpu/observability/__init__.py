@@ -1,5 +1,5 @@
-"""Unified observability: span tracing, counters, step telemetry,
-metric exporters, and the perf-regression gate.
+"""Unified observability: span tracing, counters, step telemetry and
+metric exporters.
 
 Built on the two primitives the reference stack ships (profiler.py
 ``RecordEvent``/chrome-trace export ≈ `platform/profiler.cc`; monitor.py
@@ -26,14 +26,11 @@ to seconds per scope path and per kind.
 
 Scraping a live job: ``obs.export.start_http_server(9100)`` serves
 ``/metrics``; ``hapi.callbacks.TelemetryCallback`` publishes per-step
-tokens/s / MFU / data-wait gauges into it. The perf gate:
-``python benchmarks/run_all.py --gate BASELINE.json`` or
-``python tools/perf_gate.py --baseline BASELINE.json``.
+tokens/s / MFU / data-wait gauges into it.
 """
 from .. import profiler as _profiler
-from . import export, flight, gate, hlo_bytes, runlog, step  # noqa: F401
+from . import export, flight, hlo_bytes, runlog, step  # noqa: F401
 from . import memory, overlap, scopes, tracing  # noqa: F401
-from .gate import compare, load_results  # noqa: F401
 from .hlo_bytes import collective_stats, export_collective_bytes  # noqa: F401
 from .memory import state_ledger  # noqa: F401
 from .overlap import export_overlap_stats, overlap_stats  # noqa: F401
@@ -52,7 +49,7 @@ __all__ = [
     "trace_context", "attach_context", "mint_context", "record_span",
     "start_run", "stop_run",
     "scope", "scope_table", "device_time_by_scope",
-    "tracing", "export", "gate", "hlo_bytes", "step", "runlog", "flight",
+    "tracing", "export", "hlo_bytes", "step", "runlog", "flight",
     "memory", "overlap", "scopes",
 ]
 

@@ -7,7 +7,7 @@ buffer assignment. The CPU backend, however, STRIPS optimization
 barriers and lets CSE/scheduling undo rematerialization entirely (the
 compiled CPU program of a remat'd and a plain step are byte-identical),
 so XLA byte accounting on the smoke host cannot show what activation
-recompute saves — the one claim the remat bench rows exist to gate.
+recompute saves.
 
 This module meters the STRUCTURE instead: a sequential liveness walk
 over the traced (pre-XLA) jaxpr of the step program. Every value born
@@ -20,8 +20,8 @@ them inside its own (recursively metered) working set, so the
 forward→backward residual edges shrink exactly as the policy promises.
 
 Deterministic (pure structure, no wall clock, no backend), so the
-``*_jaxpr_peak_mb`` bench rows VALUE-gate between CPU runs the same way
-the PR-10 byte rows do. The XLA ``memory_analysis`` numbers ride along
+traced peak reads the same on every run and every backend, the same way
+the PR-10 byte counts do. The XLA ``memory_analysis`` numbers ride along
 as metadata, and the TPU re-pin (ROADMAP) re-captures the executable
 view where it is meaningful.
 """

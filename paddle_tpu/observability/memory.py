@@ -20,9 +20,9 @@ Two complementary views answer "where does HBM go?":
   the number that proves ZeRO-3's model state really lives 1/dp per
   chip, numerically, not by HLO pattern-matching).
 
-Byte accounting is backend-deterministic (unlike wall time), so the
-``*_hbm_peak_mb`` / ``*_state_resident_mb`` bench rows value-gate even
-on the CPU smoke host — see ``observability.gate`` direction handling.
+Byte accounting is backend-deterministic (unlike wall time): the same
+program reports the same bytes on the CPU host as on the chip's
+compiler, so tests pin these numbers exactly.
 
 The flight recorder embeds :func:`flight_section` in every crash dump;
 combined with :func:`is_oom_error` classification a

@@ -59,9 +59,7 @@ source the jaxpr-liveness memory meter trusts — and
 into its report when the traced program is available. The text-order
 walk remains as the fallback for standalone HLO dumps (honest there
 too: it reports what the final schedule left hideable). That makes the
-restructure value-gateable on the CPU smoke mesh
-(``*_schedulable_overlap`` rows, direction up) while the
-measured-efficiency re-capture waits on TPU time.
+restructure checkable on the CPU mesh (``tests/test_zero_prefetch.py``).
 
 Cost-model assumptions (all overridable per call, recorded in the
 result's ``assumptions``): the schedule is the only evidence — no
@@ -77,20 +75,20 @@ import re
 from .hlo_bytes import (COLLECTIVE_HLO_OPS, _axis_name, _comp_multipliers,
                         _group_size, _shape_bytes)
 from .jaxpr_walk import sub_jaxprs as _sub_jaxprs
+from .step import PEAK_BF16_FLOPS
 
 __all__ = ["overlap_stats", "schedulable_stats", "export_overlap_stats",
            "attribute_program",
            "DEFAULT_LINK_GBPS", "DEFAULT_HBM_GBPS", "DEFAULT_PEAK_FLOPS",
            "RING_FACTORS"]
 
-# Defaults are v5e-shaped provenance, matching benchmarks/run_all.py's
-# PEAK_BF16_FLOPS pin: 197 TFLOP/s bf16, ~819 GB/s HBM, ~100 GB/s
-# usable per-direction ICI. Absolute nanoseconds are only as good as
-# these rates; the efficiency RATIO is what the gauges gate on, and it
-# is much less sensitive to them.
+# Defaults are v5e-shaped provenance: step.py's published bf16 peak,
+# ~819 GB/s HBM, ~100 GB/s usable per-direction ICI. Absolute
+# nanoseconds are only as good as these rates; the efficiency RATIO is
+# what the gauges report, and it is much less sensitive to them.
 DEFAULT_LINK_GBPS = 100.0
 DEFAULT_HBM_GBPS = 819.0
-DEFAULT_PEAK_FLOPS = 197e12
+DEFAULT_PEAK_FLOPS = PEAK_BF16_FLOPS["TPU v5 lite"]
 
 # wire-bytes factor per payload byte for a ring implementation on a
 # group of n: all-reduce moves ~2(n-1)/n, gather/scatter ~(n-1)/n,

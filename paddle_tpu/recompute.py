@@ -133,7 +133,7 @@ def resolve_policy(policy, strict=False):
     ``offload`` degrades to ``selective`` WITH A WARNING when the
     backend has no ``pinned_host`` memory space; ``strict=True`` raises
     instead (for callers that must not fake the residency claim, e.g. a
-    bench row explicitly pinning offload behavior)."""
+    test explicitly pinning offload behavior)."""
     if callable(policy):
         return policy, getattr(policy, "__name__", "custom")
     name = str(policy)

@@ -1,0 +1,115 @@
+"""The names the benchmark's per-layer metrics take from the program.
+
+A metric's data file under ``chipbench/metrics/`` (read here, never
+changed) names a scope, a counter or an ``op_name`` mark; a renamed one
+makes the metric read ``null`` on the chip and nothing fail here. One
+case a file: a tiny step of each family its ``workloads`` name
+(``BENCHMARK.json``), built through the public API, must still carry
+that name.
+"""
+import glob
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import monitor
+from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+VOCAB, SEQ, K = 128, 16, 2
+# XLA:TPU lands GELU's fusions under the matmuls' scopes (PERF.md
+# section 5), so a metric may name it and find nothing rooted there
+MAY_ROOT_NONE = {"gelu"}
+
+
+def _metric_files():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cells = {w["name"]: w["config"] for w in manifest["workloads"]}
+    families = {e["name"]: sorted({cells[w].split("_")[0] for w in
+                                   e.get("workloads", cells)})
+                for e in manifest["per_layer"]}
+    out = {}
+    for path in sorted(glob.glob(
+            os.path.join(REPO, "chipbench", "metrics", "*.json"))):
+        with open(path) as f:
+            data = json.load(f)
+        name = os.path.basename(path)[:-len(".json")]
+        args = data.get("args", {})
+        if name in families and (data["reader"] == "program_stat" or
+                                 args.get("scopes") or args.get("marks")):
+            out[name] = (args, families[name])
+    return out
+
+
+METRICS = _metric_files()
+COUNTERS = sorted({a[key] for a, _f in METRICS.values()
+                   for key in ("counter", "per") if key in a})
+
+
+def _compiled(model, forward_loss):
+    """The step a user's loop calls, called twice: autocast, backward,
+    AdamW with fp32 masters, `to_static(scan_steps=2)`."""
+    opt = paddle.optimizer.AdamW(parameters=model.parameters(),
+                                 learning_rate=1e-3, multi_precision=True)
+
+    def one_step(ids, labels):
+        with paddle.amp.auto_cast(enable=True, dtype="bfloat16"):
+            loss = forward_loss(ids, labels)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    step = paddle.jit.to_static(one_step, scan_steps=K)
+    ids = paddle.to_tensor(np.random.RandomState(0).randint(
+        0, VOCAB, (K, 2, SEQ)).astype("int32"))
+    before = {c: monitor.stat_get(c) for c in COUNTERS}
+    for _call in range(2):  # the build, then one call the counters keep
+        assert np.isfinite(step(ids, ids).numpy()).all()
+    table = step.scope_table()
+    assert not table["stale"]
+    return {"components": {c for rec in table["instructions"].values()
+                           for c in rec["path"].split("/") if c},
+            "op_names": re.findall(r'op_name="([^"]*)"', step.hlo_text()),
+            "counted": {c: monitor.stat_get(c) - v
+                        for c, v in before.items()}}
+
+
+@pytest.fixture(scope="module")
+def gpt3():
+    paddle.seed(7)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=VOCAB, hidden_size=32, num_layers=2, num_heads=2,
+        max_seq_len=SEQ, hidden_dropout=0.0, attention_dropout=0.0))
+    return _compiled(model,
+                     lambda ids, labels: model.loss(model(ids), labels))
+
+
+@pytest.fixture(scope="module")
+def ouro():
+    paddle.seed(7)
+    model = OuroForCausalLM(OuroConfig(
+        vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=2, total_ut_steps=2,
+        max_position_embeddings=SEQ)).enable_layer_recompute("kernels")
+    return _compiled(model, lambda ids, labels: model(ids, labels))
+
+
+@pytest.mark.parametrize("metric", sorted(METRICS))
+def test_the_program_still_carries_what_the_metric_reads(metric, request):
+    args, families = METRICS[metric]
+    for family in families:
+        program = request.getfixturevalue(family)
+        for name in set(args.get("scopes", ())) - MAY_ROOT_NONE:
+            assert name in program["components"], (family, name)
+        for key in ("counter", "per"):
+            if key in args:
+                assert program["counted"][args[key]] > 0, (family, args[key])
+        for mark in args.get("marks", ()):
+            assert any(mark in n for n in program["op_names"]), (family, mark)

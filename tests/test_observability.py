@@ -1,10 +1,8 @@
 """Unified observability layer tests: spans, counters, exporters,
 StepTimer, hot-path instrumentation (executor / jit cache / dataloader /
-collectives / PS RPC), and the perf-regression gate."""
+collectives / PS RPC), and the chip peaks."""
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -17,7 +15,6 @@ from paddle_tpu import _native, monitor, profiler
 from paddle_tpu.io import DataLoader
 from paddle_tpu.io.dataset import TensorDataset
 from paddle_tpu.observability import export as export_mod
-from paddle_tpu.observability import gate as gate_mod
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -384,144 +381,20 @@ def test_collector_errors_do_not_kill_scrape():
         export_mod.unregister_collector("obs_test_broken")
 
 
-# -- perf gate -------------------------------------------------------------
+# -- chip peaks --------------------------------------------------------------
 
-def _rec(metric, value, unit):
-    return {"metric": metric, "value": value, "unit": unit}
-
-
-def test_gate_compare_directions_and_tolerance():
-    base = {"a": _rec("a", 100.0, "img/s"), "b": _rec("b", 50.0, "ms")}
-    ok, rep = gate_mod.compare(base, {"a": _rec("a", 95.0, "img/s"),
-                                      "b": _rec("b", 54.0, "ms")},
-                               tolerance=0.10)
-    assert ok and all(e["status"] == "OK" for e in rep)
-    # throughput drop beyond tolerance fails
-    ok, rep = gate_mod.compare(base, {"a": _rec("a", 80.0, "img/s"),
-                                      "b": _rec("b", 50.0, "ms")})
-    assert not ok
-    assert [e for e in rep if e["metric"] == "a"][0]["status"] == "REGRESSION"
-    # latency increase beyond tolerance fails
-    ok, rep = gate_mod.compare(base, {"a": _rec("a", 100.0, "img/s"),
-                                      "b": _rec("b", 70.0, "ms")})
-    assert not ok
-    # improvements pass
-    ok, rep = gate_mod.compare(base, {"a": _rec("a", 150.0, "img/s"),
-                                      "b": _rec("b", 30.0, "ms")})
-    assert ok
-
-
-def test_gate_missing_metric_fails_and_new_is_informational():
-    base = {"a": _rec("a", 100.0, "img/s")}
-    cur = {"b": _rec("b", 1.0, "x")}
-    ok, rep = gate_mod.compare(base, cur)
-    assert not ok
-    statuses = {e["metric"]: e["status"] for e in rep}
-    assert statuses["a"] == "MISSING"
-    assert statuses["b"] == "NEW"
-    # errored current record also fails
-    ok, _ = gate_mod.compare(base, {"a": {"metric": "a", "error": "boom"}})
-    assert not ok
-    # errored baseline entry is skipped, not gated
-    ok, rep = gate_mod.compare({"a": {"metric": "a", "error": "boom"}}, cur)
-    assert ok
-    assert rep[0]["status"] == "SKIP"
-
-
-def test_gate_backend_mismatch_checks_presence_only():
-    """A TPU-pinned baseline gated on a CPU smoke host: values are not
-    comparable, so the gate demands metric PRESENCE (a usable record)
-    and nothing else."""
-    base = {"a": dict(_rec("a", 5000.0, "img/s"), backend="tpu")}
-    # wildly lower CPU value still passes — PRESENT, not REGRESSION
-    ok, rep = gate_mod.compare(
-        base, {"a": dict(_rec("a", 3.0, "img/s"), backend="cpu")})
-    assert ok
-    assert rep[0]["status"] == "PRESENT"
-    # but an errored/absent record still fails: presence means PRESENT
-    ok, rep = gate_mod.compare(base, {"a": {"metric": "a", "error": "x"}})
-    assert not ok and rep[0]["status"] == "MISSING"
-    # same backend -> real value gating
-    ok, rep = gate_mod.compare(
-        base, {"a": dict(_rec("a", 3.0, "img/s"), backend="tpu")})
-    assert not ok and rep[0]["status"] == "REGRESSION"
-
-
-def test_gate_presence_pin_skips_value_compare():
-    base = {"n": dict(_rec("n", 3.0, "x"), backend="cpu",
-                      gate="presence")}
-    cur = {"n": dict(_rec("n", 0.5, "x"), backend="cpu")}
-    ok, rep = gate_mod.compare(base, cur)  # 6x "regression" — ignored
-    assert ok and rep[0]["status"] == "PRESENT"
-    assert "PRESENT" in gate_mod.format_report(rep)
-
-
-def test_write_baseline_drops_errored_records(tmp_path, capsys):
-    recs = [_rec("good", 1.0, "x"), {"metric": "bad", "error": "boom"}]
-    p = str(tmp_path / "base.json")
-    n = gate_mod.write_baseline(recs, p)
-    assert n == 1
-    assert set(gate_mod.load_results(p)) == {"good"}
-    assert "bad" in capsys.readouterr().err  # dropped LOUDLY, not silently
-
-
-def test_gate_load_results_formats(tmp_path):
-    recs = [_rec("m1", 1.0, "x"), _rec("m2", 2.0, "ms")]
-    p1 = tmp_path / "obj.json"
-    p1.write_text(json.dumps({"results": recs}))
-    p2 = tmp_path / "arr.json"
-    p2.write_text(json.dumps(recs))
-    p3 = tmp_path / "lines.json"
-    p3.write_text("\n".join(json.dumps(r) for r in recs))
-    for p in (p1, p2, p3):
-        loaded = gate_mod.load_results(str(p))
-        assert set(loaded) == {"m1", "m2"}
-
-
-def test_run_all_gate_exits_nonzero_on_regression(tmp_path):
-    """Acceptance: `benchmarks/run_all.py --gate` exits non-zero against a
-    synthetically regressed baseline (current results fed from a file so
-    no benches run)."""
-    cur = [_rec("resnet50_train_img_per_s_per_chip", 100.0, "img/s")]
-    good = [_rec("resnet50_train_img_per_s_per_chip", 95.0, "img/s")]
-    bad = [_rec("resnet50_train_img_per_s_per_chip", 200.0, "img/s")]
-    (tmp_path / "cur.json").write_text(json.dumps({"results": cur}))
-    (tmp_path / "good.json").write_text(json.dumps({"results": good}))
-    (tmp_path / "bad.json").write_text(json.dumps({"results": bad}))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-
-    def run(baseline):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "benchmarks", "run_all.py"),
-             "--results", str(tmp_path / "cur.json"), "--gate",
-             str(tmp_path / baseline)],
-            capture_output=True, text=True, cwd=REPO, timeout=300, env=env)
-
-    r = run("bad.json")
-    assert r.returncode == 2, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout
-    r = run("good.json")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "PERF GATE: PASS" in r.stdout
-
-
-def test_perf_gate_tool_roundtrip(tmp_path):
-    cur = [_rec("m", 10.0, "tokens/s")]
-    (tmp_path / "cur.json").write_text(json.dumps({"results": cur}))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    # pin a baseline from the current file, then gate against it: PASS
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--current", str(tmp_path / "cur.json"),
-         "--write-baseline", str(tmp_path / "base.json")],
-        capture_output=True, text=True, cwd=REPO, timeout=300, env=env)
-    assert r.returncode == 0, r.stdout + r.stderr
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--baseline", str(tmp_path / "base.json"),
-         "--current", str(tmp_path / "cur.json")],
-        capture_output=True, text=True, cwd=REPO, timeout=300, env=env)
-    assert r.returncode == 0, r.stdout + r.stderr
+@pytest.mark.parametrize("peak", ["bf16_flops_per_s", "hbm_bytes_per_s"])
+def test_package_peaks_agree_with_the_benchmarks(peak):
+    """The package holds each chip peak once; the benchmark's own table
+    (`chipbench/peaks.json`, read only) says the same of the same chip."""
+    from paddle_tpu.observability import overlap, step
+    kind = "TPU v5 lite"
+    with open(os.path.join(REPO, "chipbench", "peaks.json")) as f:
+        theirs = json.load(f)["devices"][kind][peak]
+    ours = {"bf16_flops_per_s": {step.PEAK_BF16_FLOPS[kind],
+                                 overlap.DEFAULT_PEAK_FLOPS},
+            "hbm_bytes_per_s": {overlap.DEFAULT_HBM_GBPS * 1e9}}[peak]
+    assert ours == {theirs}
 
 
 # -- end-to-end acceptance -------------------------------------------------

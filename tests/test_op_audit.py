@@ -1,6 +1,6 @@
 """The op-coverage audit must stay clean: every operator type the
 reference registers maps to a verified symbol, a delegation, or a
-documented deferral (tools/op_audit.py; VERDICT r3 item #5)."""
+documented deferral (tools/op_audit.py)."""
 import os
 import subprocess
 import sys

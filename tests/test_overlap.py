@@ -1,7 +1,7 @@
 """Collective overlap observability (ISSUE 16): the HLO schedule
 analyzer (``observability.overlap``), the async ``-start``/``-done``
 billing contract in ``hlo_bytes``, the per-program XLA flag surface
-(``jit.xla_flags``), gate direction pins, and ``tools/overlap_view``.
+(``jit.xla_flags``), and ``tools/overlap_view``.
 
 The seeded async-HLO fixtures pin the pairing/interleave math
 backend-independently: XLA:CPU never emits async collective pairs, so
@@ -23,7 +23,6 @@ from paddle_tpu import nn
 from paddle_tpu.distributed import parallel_env
 from paddle_tpu.jit import xla_flags
 from paddle_tpu.observability import export as obs_export
-from paddle_tpu.observability import gate as gate_mod
 from paddle_tpu.observability import hlo_bytes, overlap
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -490,51 +489,6 @@ def test_scan_default_latency_hiding_preset(_mesh, monkeypatch):
     step4 = paddle.jit.to_static(lambda v: v, scan_steps=2)
     assert step4._xla_flags_default_pending is False
     assert step4._xla_flags == {"xla_x": 1}
-
-
-# -- gate direction pins ---------------------------------------------------
-
-def test_gate_direction_pins():
-    assert gate_mod.higher_is_better(
-        {"metric": "mlp_zero3_overlap_efficiency", "unit": "frac"}) is True
-    assert gate_mod.higher_is_better(
-        {"metric": "mlp_zero3_exposed_collective_frac",
-         "unit": "frac"}) is False
-    # an explicit per-record pin still outranks the suffix
-    assert gate_mod.higher_is_better(
-        {"metric": "x_overlap_efficiency", "direction": "lower"}) is False
-
-
-def test_gate_exposed_frac_regresses_upward():
-    base = {"m_exposed_collective_frac":
-            {"metric": "m_exposed_collective_frac", "value": 0.5,
-             "unit": "frac", "backend": "cpu"}}
-    worse = {"m_exposed_collective_frac":
-             {"metric": "m_exposed_collective_frac", "value": 0.9,
-              "unit": "frac", "backend": "cpu"}}
-    ok, report = gate_mod.compare(base, worse)
-    assert not ok and report[0]["status"] == "REGRESSION"
-    better = {"m_exposed_collective_frac":
-              {"metric": "m_exposed_collective_frac", "value": 0.2,
-               "unit": "frac", "backend": "cpu"}}
-    ok2, report2 = gate_mod.compare(base, better)
-    assert ok2 and report2[0]["status"] == "IMPROVED"
-
-
-def test_baseline_presence_pins_overlap_rows():
-    baseline = gate_mod.load_results(
-        os.path.join(REPO, "BASELINE_PERF.json"))
-    for metric in ("mlp_zero3_overlap_efficiency",
-                   "mlp_zero3_exposed_collective_frac"):
-        assert metric in baseline
-        assert baseline[metric]["gate"] == "presence"
-    current = {m: dict(baseline[m]) for m in
-               ("mlp_zero3_overlap_efficiency",
-                "mlp_zero3_exposed_collective_frac")}
-    ok, report = gate_mod.compare(
-        {m: baseline[m] for m in current}, current)
-    assert ok
-    assert all(e["status"] == "PRESENT" for e in report)
 
 
 # -- tools/overlap_view ----------------------------------------------------

@@ -243,7 +243,7 @@ def test_output_scales_and_sidecar(tmp_path):
 @pytest.mark.slow  # ~20 s resnet PTQ + artifact round-trip; quant op
 # semantics stay tier-1-covered by the per-op cases in this file
 def test_ptq_resnet_serving_accuracy_delta(tmp_path):
-    """The VERDICT bar: PTQ a ResNet, serve the saved artifact through
+    """The bar: PTQ a ResNet, serve the saved artifact through
     the Predictor in-process, assert the quantized predictions track the
     float model (top-1 agreement)."""
     from paddle_tpu.inference import Config, create_predictor

@@ -71,7 +71,7 @@ def test_scan_matches_unrolled_linear():
 # equivalence itself is tier-1-covered at toy scale in this file
 def test_scan_matches_unrolled_bert_cpu_small():
     """Acceptance: scan-vs-unrolled loss equivalence on the CPU-small
-    BERT config (k=2, same seed, allclose) — the bench.py program
+    BERT config (k=2, same seed, allclose) — chip_smoke.py's program
     structure A/B in miniature."""
     import jax.lax as lax
     from paddle_tpu.models import (BertConfig, BertForPretraining,

@@ -69,16 +69,23 @@ class TestCoordinator:
     def test_join_is_a_uniqueid_exchange(self):
         coord, ep = start_coordinator(expected=2, lease_ttl=5.0)
         try:
-            got = {}
+            got, failed = {}, {}
 
             def run(r):
-                pod = self._pod(ep, 2, r).init()
-                got[r] = (pod.uid, pod.gen, pod.rank, pod.world_size)
-                pod.shutdown()
+                try:
+                    pod = self._pod(ep, 2, r).init()
+                    got[r] = (pod.uid, pod.gen, pod.rank, pod.world_size)
+                    pod.shutdown()
+                except BaseException as e:  # carried to the main thread
+                    failed[r] = e
 
             ts = [threading.Thread(target=run, args=(r,)) for r in (0, 1)]
             [t.start() for t in ts]
             [t.join(30) for t in ts]
+            if failed:
+                r = min(failed)
+                raise AssertionError(f"rank {r} failed") from failed[r]
+            assert not any(t.is_alive() for t in ts), "a join outran 30 s"
             # every rank got the SAME minted uid (the NCCL-uniqueId
             # analog) and a consistent roster
             assert got[0][0] == got[1][0] == coord.uid

@@ -840,8 +840,7 @@ def test_collective_cadence_mismatch_flagged():
 
 def test_zero3_ladder_twin_verifies_clean():
     """The zero3 analysis ladder twin (ag->fwd + window-gated rs, both
-    ranks cadence-stamped) passes the full analyzer — the programs
-    run_all's --write-baseline gate insists on."""
+    ranks cadence-stamped) passes the full analyzer."""
     from paddle_tpu.analysis import ladder
     findings, summary = ladder.verify_ladder(["zero3"])
     assert findings == []

@@ -8,8 +8,8 @@ over the collapsed trace->XLA pipeline.
     python tools/lint_program.py               # --ladder, --source and
                                                # --concurrency (the default
                                                # sweep)
-    python tools/lint_program.py --ladder      # verify the benchmark
-                                               # ladder's program miniatures
+    python tools/lint_program.py --ladder      # verify the analyzers'
+                                               # ladder of tiny programs
     python tools/lint_program.py --source      # AST lint (nondeterminism in
                                                # traced fns, eager jnp in
                                                # dispatch hot paths)
@@ -21,8 +21,7 @@ over the collapsed trace->XLA pipeline.
 
 Exit codes: 0 clean, 1 any error-severity finding (warnings print but do
 not fail the gate; --strict promotes them). Wired into the verify-skill
-recipe and `benchmarks/run_all.py --write-baseline` (a perf baseline must
-not be pinned from a program the verifier rejects).
+recipe.
 """
 import argparse
 import os
