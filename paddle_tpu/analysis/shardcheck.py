@@ -56,7 +56,15 @@ stage      reduce-scatter         all-gather
                                   window micro steps)
 =========  =====================  ==========================
 
-and diff it against the trip-weighted compiled multiset from
+A bucket whose gradients are 16-bit floats is not reduce-scattered: the
+ZeRO step exchanges it in that type (one ``all-to-all`` in the
+reduce-scatter's place, summed in float32 where it lands), so of the
+``nb`` buckets the ``exchanged_buckets`` the build's trace counted
+(``jit_zero_exchanged_buckets``, kept in the partition) are budgeted as
+``all-to-all`` at the reduce-scatter's cadence and only the rest as
+``reduce-scatter``.
+
+Diff it against the trip-weighted compiled multiset from
 ``StaticFunction.collective_stats(per_execution=True)``
 (``observability.hlo_bytes``), emitting ``collective-budget-mismatch``
 (ERROR) findings that name the op, axis, and count delta. All-reduce is
@@ -109,7 +117,7 @@ MATERIALIZATION_BUDGET = 1
 _ZERO_STORE_RE = re.compile(r"^zero_([A-Za-z0-9]+)_b(\d+)$")
 
 # shard-producing jaxpr primitives: the output is a 1/axis shard
-_SHARD_PRODUCING_PRIMS = ("psum_scatter", "reduce_scatter")
+_SHARD_PRODUCING_PRIMS = ("psum_scatter", "reduce_scatter", "all_to_all")
 
 # record-level stamped op name -> collective kind (the ladder twins'
 # identity stand-ins; distributed.collective stamps the real lowerings
@@ -132,14 +140,17 @@ _OP_ABBREV = {"all-gather": "ag", "reduce-scatter": "rs",
 
 def predict_collective_budget(stage, scan_steps=1, accumulate_steps=None,
                               n_buckets=1, prefetch=False, axis="dp",
-                              mesh_axes=("dp",)):
+                              mesh_axes=("dp",), exchanged_buckets=0):
     """The per-execution collective multiset a ZeRO layout budgets:
     ``{(op, axis): count}`` for the gather/scatter schedule (all-reduce
     is unconstrained — see the module docstring's table and the
     intra-window elision the prefetch slot buys under stage 3 with
     accumulation). ``mesh_axes`` names the axes the checker constrains;
     an ``axis`` outside it returns an empty budget (a tp axis becomes
-    checkable by widening the tuple, not by new code)."""
+    checkable by widening the tuple, not by new code).
+    ``exchanged_buckets`` of the ``n_buckets`` move their 16-bit
+    gradients by ``all-to-all`` where the others reduce-scatter; the
+    ``all-to-all`` key appears only when there are any."""
     if axis not in tuple(mesh_axes or ()):
         return {}
     stage = int(stage)
@@ -159,7 +170,13 @@ def predict_collective_budget(stage, scan_steps=1, accumulate_steps=None,
     else:
         rs = nb * k
         ag = nb * k - ((k - windows) if prefetch else 0)
-    return {("all-gather", axis): ag, ("reduce-scatter", axis): rs}
+    budget = {("all-gather", axis): ag, ("reduce-scatter", axis): rs}
+    nx = min(max(0, int(exchanged_buckets or 0)), nb)
+    if nx:
+        per_bucket = rs // nb
+        budget[("reduce-scatter", axis)] = per_bucket * (nb - nx)
+        budget[("all-to-all", axis)] = per_bucket * nx
+    return budget
 
 
 def infer_zero_layout(sfn):
@@ -206,6 +223,7 @@ def infer_zero_layout(sfn):
         "prefetch": prefetch,
         "scan_steps": part.get("scan_steps") or 1,
         "accumulate_steps": part.get("accumulate_steps") or 1,
+        "exchanged_buckets": part.get("zero_exchanged_buckets") or 0,
         "source": "partition",
     }
 
@@ -236,7 +254,8 @@ def check_collective_budget(sfn, layout=None, mesh_axes=None):
         layout["stage"], scan_steps=k, accumulate_steps=a,
         n_buckets=layout.get("n_buckets", 1),
         prefetch=layout.get("prefetch", False),
-        axis=axis, mesh_axes=mesh_axes)
+        axis=axis, mesh_axes=mesh_axes,
+        exchanged_buckets=layout.get("exchanged_buckets", 0))
     if not budget:
         return []
     floor = dict(budget)
@@ -612,23 +631,27 @@ def format_shard_stats(stats):
 
 def check_program_sharding(prog, mesh_axes=("dp",)):
     """Record-level collective budget of a program twin: on every
-    checked axis, gradient shards that are reduce-scattered must be
-    matched by at least one all-gather republishing the updated params
-    (the ZeRO contract the stamped schedules encode) — a scatter-only
-    axis is a ``collective-budget-mismatch`` ERROR. Rank-order and
-    cadence divergence stay with ``check_collective_order``."""
+    checked axis, gradient shards that are reduce-scattered — or, in
+    the twin of a step with 16-bit gradients, exchanged by all-to-all
+    and summed on arrival — must be matched by at least one all-gather
+    republishing the updated params (the ZeRO contract the stamped
+    schedules encode) — a scatter-only axis is a
+    ``collective-budget-mismatch`` ERROR. Rank-order and cadence
+    divergence stay with ``check_collective_order``."""
     stats = program_shard_stats(prog, mesh_axes=mesh_axes)
     findings = []
     for axis, ops in sorted(stats["axes"].items()):
         rs = ops.get("reduce-scatter", 0)
+        a2a = ops.get("all-to-all", 0)
         ag = ops.get("all-gather", 0)
-        if rs and not ag:
+        if (rs or a2a) and not ag:
             findings.append(Finding(
                 "collective-budget-mismatch", ERROR,
-                f"axis {axis!r}: {rs} reduce-scatter(s) but no "
-                "all-gather — gradient shards are reduced but the "
-                "updated params are never republished (expected >= 1 "
-                "all-gather per update window, got 0)", slot=axis))
+                f"axis {axis!r}: {rs} reduce-scatter(s) and {a2a} "
+                "all-to-all(s) but no all-gather — gradient shards are "
+                "reduced but the updated params are never republished "
+                "(expected >= 1 all-gather per update window, got 0)",
+                slot=axis))
     return findings
 
 

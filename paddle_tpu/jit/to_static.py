@@ -130,13 +130,13 @@ def in_tracing():
 
 
 # what the newest trace of a step body staged, by kind: rolled regions'
-# trip counts, remat segments and activation factors saved for the
-# backward (`F.gelu`'s erfc). Every trace of a build stages the same
-# structure, so the tally restarts with each and the build publishes the
-# last one (`jit_rolled_loop_trips`, `jit_recompute_segments`,
-# `jit_saved_activation_factors`).
+# trip counts, remat segments, activation factors saved for the backward
+# (`F.gelu`'s erfc), ZeRO buckets exchanged in their gradients' 16-bit
+# type. Every trace of a build's boundary step stages the same structure,
+# so the tally restarts with each and the build publishes the last one
+# (`jit_<kind>`: `jit_rolled_loop_trips`, `jit_zero_exchanged_buckets`, ...).
 _STRUCTURE = {"rolled_loop_trips": 0, "recompute_segments": 0,
-              "saved_activation_factors": 0}
+              "saved_activation_factors": 0, "zero_exchanged_buckets": 0}
 
 
 def note_structure(kind, count=1):
@@ -1098,8 +1098,8 @@ class StaticFunction:
         per-rank shards of any PartitionSpec-sharded carry state (the
         ZeRO optimizer stores), gradient reduction happens through the
         explicit collectives the optimizer issues (per-param psum for the
-        replicated control, bucketed psum_scatter + all_gather under
-        ZeRO), and the grad-presence fixpoint runs over LOCAL (per-shard)
+        replicated control, bucketed psum_scatter, or all_to_all of 16-bit
+        gradients, + all_gather under ZeRO); the fixpoint runs over LOCAL
         shapes so the analysis trace matches the shard_map body exactly.
         """
         import jax.numpy as jnp
@@ -1224,9 +1224,9 @@ class StaticFunction:
                              np.dtype(g.dtype))
                          if g is not None and _is_sharded_spec(spec) else g
                          for g, spec in zip(grad_tmpl, state_specs)]
-        modes = [("fire", fire_fn)]
-        if accum_fn is not None:
-            modes.append(("accum", accum_fn))
+        # the boundary body is traced last: the structure counters are its
+        modes = [("accum", accum_fn)] if accum_fn is not None else []
+        modes.append(("fire", fire_fn))
         mode_res = {}
         for _ in range(2 * (n + 1)):
             grew = False
@@ -1382,9 +1382,9 @@ class StaticFunction:
             "carry_optional": [uids[i] for i in range(n)
                                if getattr(state_items[i][1],
                                           "_carry_optional", False)],
-            "dp_axis": dp_axis,
-            "scan_steps": k,
-            "accumulate_steps": a,
+            "dp_axis": dp_axis, "scan_steps": k, "accumulate_steps": a,
+            # of the boundary step's analysis trace, the last one above
+            "zero_exchanged_buckets": _STRUCTURE["zero_exchanged_buckets"],
             "donate": bool(self._donate),
             "state_meta": {uids[i]: {
                 "name": getattr(state_items[i][1], "name", None),
@@ -1486,7 +1486,7 @@ def to_static(function=None, input_spec=None, build_strategy=None,
     axis manual: the microbatch is split 1/dp per rank, gradient
     reduction goes through the explicit collectives the optimizer
     issues — per-param psum for a replicated optimizer, bucketed
-    ``psum_scatter`` + param ``all_gather`` after
+    ``psum_scatter`` (16-bit gradients: ``all_to_all``) + ``all_gather`` after
     ``optimizer._zero_enable()`` (ZeRO; stage 3 adds per-bucket param
     ``all_gather`` before the forward instead, with params riding the
     carry as 1/dp shards) — and PartitionSpec-sharded optimizer state

@@ -47,6 +47,10 @@ class _LRValue:
 
 _FLAT_LANES = 1024  # row width: multiple of the (8,128) f32 tile
 
+# gradient dtypes a ZeRO bucket exchanges as they are (summed in float32
+# where they land) instead of reducing in float32 on the wire
+_NARROW_FLOATS = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16))
+
 
 class _FlatSlot:
     """Per-param view into a coalesced accumulator buffer: reads slice the
@@ -148,8 +152,10 @@ class _ZeroBucket:
     params during the update) share this [rows, 1024] layout: per-param
     row-aligned segments, total rows padded to a multiple of the shard
     degree so ``lax.psum_scatter(..., scatter_dimension=0, tiled=True)``
-    hands each rank a contiguous [rows/degree, 1024] shard that lines up
-    exactly with its shard of the bucket's moment/master stores."""
+    — or the ``all_to_all`` of the bucket viewed [degree, rows/degree,
+    1024] — hands each rank a contiguous [rows/degree, 1024] shard that
+    lines up exactly with its shard of the bucket's moment/master
+    stores."""
 
     __slots__ = ("index", "params", "sizes", "shapes", "n_rows", "row_offs",
                  "rows", "pad_rows", "degree", "has_master", "param_dtype",
@@ -188,7 +194,8 @@ class _ZeroBucket:
 
     def flatten(self, vals, dtype=jnp.float32):
         """Per-param arrays -> the [rows, 1024] bucket layout in ``dtype``
-        (f32 for gradients/moments, the param dtype for stage-3 stores)."""
+        (f32 for moments and float32 gradients, the gradients' own type
+        for a 16-bit bucket, the param dtype for stage-3 stores)."""
         segs = []
         with scope("zero.bucket_copy"):
             for v, n_rows, size in zip(vals, self.n_rows, self.sizes):
@@ -230,6 +237,22 @@ class _ZeroBucket:
         if self.pad_rows:
             parts.append(np.zeros((self.pad_rows, 1), bool))
         return np.concatenate(parts)
+
+
+def _zero_sum_pieces(raw):
+    """The float32 tail of one bucket's narrow exchange: ``raw`` is the
+    ``[degree, rows/degree, 1024]`` stack ``all_to_all`` delivered
+    (piece j = rank j's 16-bit gradient rows for this rank); each piece
+    is converted to float32 — exact — and the pieces are added in rank
+    order by explicit adds, so the order of summation is the program's
+    and every rank's, not the backend's. A float32 ``psum_scatter``
+    result (2-D) is already that sum and passes through."""
+    if raw.ndim == 2:
+        return raw
+    total = raw[0].astype(jnp.float32)
+    for j in range(1, raw.shape[0]):
+        total = total + raw[j].astype(jnp.float32)
+    return total
 
 
 class _ZeroView:
@@ -467,16 +490,25 @@ class Optimizer:
         one mesh axis: moments (and fp32 masters under multi_precision)
         move into per-bucket flat [rows, 1024] stores sharded 1/degree per
         rank (PartitionSpec(axis, None)); ``step()`` switches to the
-        sharded update — bucketed psum_scatter gradient reduction,
-        shard-local update math (global-norm/value grad clipping, decay
-        and per-param lr scales applied on the flat shard views),
-        all_gather of refreshed params. Buckets are sized from
+        sharded update — bucketed gradient reduction to a float32 shard
+        per rank, shard-local update math (global-norm/value grad
+        clipping, decay and per-param lr scales applied on the flat
+        shard views), all_gather of refreshed params. What a bucket
+        puts on the wire follows its gradients' dtype, observed at trace
+        time (no argument selects it): 16-bit float gradients (bf16 /
+        fp16 parameters) cross as they are — one ``all_to_all`` of the
+        16-bit bucket, each rank receiving every rank's rows of its
+        shard — and are converted and summed in float32 where they
+        land; float32 gradients are reduced by a float32
+        ``psum_scatter``. Either way the shard is the float32 sum of the
+        gradients backward produced: nothing is rounded below float32
+        before it is summed (``_zero_reduced_shard``). Buckets are sized from
         ``comm_buffer_mb`` (the DataParallel ``comm_buffer_size`` knob) so
         the reduction of bucket i can overlap the backward compute of
         bucket i+1.
 
-        Stages: 1 and 2 differ only in gradient lifetime — both reduce via
-        psum_scatter, but stage 2 frees (clears) each param's full
+        Stages: 1 and 2 differ only in gradient lifetime — both reduce
+        the same way, but stage 2 frees (clears) each param's full
         gradient the moment its bucket shard is consumed, so no full
         gradient outlives the update. Stage 3 additionally moves the
         PARAMETERS into per-bucket flat stores sharded 1/degree (their own
@@ -491,7 +523,10 @@ class Optimizer:
 
         ``prefetch`` (default on) selects the latency-hiding step
         schedule: the sharded update software-pipelines each bucket's
-        ``psum_scatter`` ahead of the previous bucket's update math, and
+        reduction collective (``psum_scatter`` or the 16-bit
+        ``all_to_all``; its float32 convert-and-add stays with the
+        deferred mean divide) ahead of the previous bucket's update
+        math, and
         stage 3 double-buffers the parameter gathers — bucket i+1's
         ``all_gather`` issues while bucket i computes, with bucket 0
         arriving through a full-bucket prefetch carry slot that the
@@ -902,47 +937,78 @@ class Optimizer:
                             constrain=None, defer_mean=False):
         """One bucket's gradient reduction, shared by the boundary step
         and the accumulation fold (they MUST agree on these semantics):
-        flatten the current per-param grads (f32; zeros for absent) into
-        the bucket layout and hand back this rank's mean-reduced
+        flatten the current per-param grads (zeros for absent) into the
+        bucket layout and hand back this rank's mean-reduced float32
         [rows/degree, 1024] shard plus the per-param presence flags.
 
-        ``defer_mean=True`` returns the raw scatter SUM instead (the
-        manual-axis branches only — GSPMD grads arrive pre-reduced):
-        the pipelined step divides by ``degree`` later, so the
-        collective's first consumer is not emitted adjacent to it."""
+        What crosses the wire follows the gradients' dtype, read off the
+        trace and nothing else. A bucket whose gradients all arrive in
+        one 16-bit float type (bf16 parameters: backward produces bf16
+        gradients) is flattened in that type, and each rank sends every
+        other rank that rank's rows of it — ONE ``all_to_all`` of the
+        narrow bucket, (degree-1)/degree of its bytes a rank on the
+        links; the receiver converts the ``degree`` pieces to float32
+        and adds them in rank order (:func:`_zero_sum_pieces`). The
+        conversion is exact and the adds are float32, so this is the
+        float32 sum of the same gradients a float32 ``psum_scatter``
+        computes, to float32's order of summation, at a quarter of the
+        all-reduce's wire bytes XLA:TPU lowers that reduce-scatter to;
+        no partial sum is ever rounded to 16 bits. Buckets with float32
+        (or mixed) gradients keep the float32 ``psum_scatter``: the
+        exchange would save them no bytes against a true
+        reduce-scatter.
+
+        ``defer_mean=True`` returns the collective's RAW result instead
+        (the manual-axis branches only — GSPMD grads arrive
+        pre-reduced): the float32 scatter SUM, or the received narrow
+        pieces ``[degree, rows/degree, 1024]``. The pipelined step runs
+        :func:`_zero_sum_pieces` and the divide by ``degree`` later, so
+        the collective's first consumer is not emitted adjacent to
+        it."""
         from ..core.selected_rows import SelectedRows
-        vals, present = [], []
-        for p, shape in zip(zb.params, zb.shapes):
+        from ..jit.to_static import note_structure
+        grads = []
+        for p in zb.params:
             g = p._grad
             if isinstance(g, SelectedRows):
                 raise NotImplementedError(
                     "ZeRO sharded step does not support sparse "
                     "(SelectedRows) gradients (out of scope of ISSUE 5: "
                     "ZeRO-3 parameter sharding)")
-            present.append(g is not None)
-            if g is None:
-                g = jnp.zeros(shape, jnp.float32)
-            elif g.dtype != jnp.float32:
-                g = g.astype(jnp.float32)
-            vals.append(g)
-        gfull = zb.flatten(vals)
-        if bound:
-            with scope("zero.reduce_scatter"):
-                gred = jax.lax.psum_scatter(
-                    gfull, axis, scatter_dimension=0, tiled=True)
-            if not defer_mean:
-                gred = gred / degree
-        elif dp_mode:
-            # abstract analysis trace: rank-0-shaped stand-in
-            gred = zb.shard_of(gfull, axis, bound=False)
-            if not defer_mean:
-                gred = gred / degree
-        else:
+            grads.append(g)
+        present = [g is not None for g in grads]
+        dtypes = {jnp.dtype(g.dtype) for g in grads if g is not None}
+        narrow = (dp_mode and len(dtypes) == 1
+                  and next(iter(dtypes)) in _NARROW_FLOATS)
+        wire = dtypes.pop() if narrow else jnp.dtype(jnp.float32)
+        gfull = zb.flatten(
+            [jnp.zeros(shape, wire) if g is None else g
+             for g, shape in zip(grads, zb.shapes)], dtype=wire)
+        if not dp_mode:
             # GSPMD/eager world: gradients are already globally reduced;
             # the constraint shards the update compute (and lets the
             # partitioner fold the grad all-reduce into a reduce-scatter
             # on backends that support it)
-            gred = constrain(gfull)
+            return constrain(gfull), present
+        if narrow:
+            note_structure("zero_exchanged_buckets")
+            with scope("zero.bucket_copy"):  # one relayout with flatten's
+                gred = gfull.reshape(degree, zb.shard_rows, _FLAT_LANES)
+            if bound:
+                # piece j of the result is rank j's rows for this rank
+                with scope("zero.reduce_scatter"):
+                    gred = jax.lax.all_to_all(gred, axis, 0, 0)
+            # (unbound: the abstract analysis trace — the local pieces
+            # stand in, shape and dtype are all that matter there)
+        elif bound:
+            with scope("zero.reduce_scatter"):
+                gred = jax.lax.psum_scatter(
+                    gfull, axis, scatter_dimension=0, tiled=True)
+        else:
+            # abstract analysis trace: rank-0-shaped stand-in
+            gred = zb.shard_of(gfull, axis, bound=False)
+        if not defer_mean:
+            gred = _zero_sum_pieces(gred) / degree
         return gred, present
 
     def _zero_accum_fold(self):
@@ -951,7 +1017,8 @@ class Optimizer:
         accumulating on the params through the scan carry and the single
         bucketed reduction fires at the window boundary (collective bytes
         per optimizer step drop ~a×). Stages 2/3 instead reduce the micro
-        gradient now (one psum_scatter per bucket) and fold the mean shard
+        gradient now (one collective per bucket, as the boundary step
+        does it: ``_zero_reduced_shard``) and fold the mean shard
         into the sharded ``gacc`` window accumulator, so no full gradient
         outlives its micro step — the DeepSpeed-style trade of per-micro
         reduction traffic for 1/degree accumulation memory."""
@@ -975,9 +1042,13 @@ class Optimizer:
                 p._grad = None
 
     def _zero_step(self):
-        """The sharded update: per bucket, psum_scatter the flat gradient
-        (each rank keeps the mean-reduced [rows/degree, 1024] shard),
-        clip/decay/scale it on the shard, run the optimizer's elementwise
+        """The sharded update: per bucket, reduce the flat gradient so
+        that each rank keeps the float32 mean-reduced [rows/degree,
+        1024] shard (``_zero_reduced_shard``: 16-bit gradients are
+        exchanged in their own dtype by ``all_to_all`` and summed in
+        float32 on arrival, float32 ones go through a float32
+        ``psum_scatter``), clip/decay/scale it on the shard, run the
+        optimizer's elementwise
         update against the sharded moment/master stores, and publish the
         refreshed parameters — stage 1/2 ``all_gather`` them back into
         every rank's full params, stage 3 writes only the local rows of
@@ -1029,26 +1100,31 @@ class Optimizer:
 
         def _rs_bucket(zb, sdict):
             """Just the collective half of one bucket's reduction: the
-            psum_scatter that produces this rank's raw reduced shard.
-            Kept free of any elementwise follow-up (the mean divide
-            included, via ``defer_mean``) so the pipelined schedule can
-            issue it early — every op that would consume the result
-            immediately lives in :func:`_norm_bucket`."""
+            psum_scatter that produces this rank's raw reduced shard,
+            or the all_to_all that delivers its 16-bit pieces. Kept
+            free of any elementwise follow-up (the pieces' float32
+            convert-and-add and the mean divide included, via
+            ``defer_mean``) so the pipelined schedule can issue it
+            early — every op that would consume the result immediately
+            lives in :func:`_norm_bucket`."""
             return self._zero_reduced_shard(
                 zb, axis, degree, bound, dp_mode,
                 constrain=lambda v: _constrain(v, shard_spec),
                 defer_mean=True)
 
         def _norm_bucket(sdict, gred):
-            """Mean divide + accumulation-window fold + pending-scaler/
-            window scaling of one reduced shard — the elementwise tail
+            """Float32 sum of an exchanged bucket's pieces + mean divide
+            + accumulation-window fold + pending-scaler/window scaling
+            of one reduced shard — the elementwise tail
             of the bucket's gradient production, deferred to just
             before the update in the pipelined schedule (same
             per-bucket op order either way, so values are untouched)."""
             if dp_mode:
-                # the deferred half of the scatter-mean (the GSPMD
-                # branch returns grads already reduced, nothing to do)
-                gred = gred / degree
+                # the deferred half of the scatter-mean: the narrow
+                # exchange's float32 convert-and-add, then the divide
+                # (the GSPMD branch returns grads already reduced,
+                # nothing to do)
+                gred = _zero_sum_pieces(gred) / degree
             if use_gacc:
                 gacc = sdict["gacc"].tensor._value
                 if not dp_mode:
@@ -1070,7 +1146,7 @@ class Optimizer:
 
         # A cross-bucket reduction over the reduced shards (global-norm
         # clip, or shard-derived overflow detection) is a barrier: every
-        # bucket's psum_scatter must land before any update math can
+        # bucket's reduction must land before any update math can
         # start, so those configs keep the two-pass schedule. Without
         # one, the reduce/update loop software-pipelines: bucket i+1's
         # reduction issues BEFORE bucket i's update math, giving the
@@ -1302,7 +1378,8 @@ class Optimizer:
         """One update. In a compiled step its device time goes under the
         scope `optimizer`: beneath it `update` (the elementwise rule and
         the master-to-parameter cast), `clip`, and for ZeRO
-        `zero.reduce_scatter`, `zero.gather`, `zero.bucket_copy`."""
+        `zero.reduce_scatter` (the reduction's collective, `psum_scatter`
+        or `all_to_all`), `zero.gather`, `zero.bucket_copy`."""
         with scope("optimizer"):
             return self._step()
 

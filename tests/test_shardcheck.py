@@ -61,11 +61,14 @@ def _mlp():
     return nn.Sequential(nn.Linear(16, 32), nn.ReLU(), nn.Linear(32, 8))
 
 
-def _build(stage, k, acc=None, prefetch=None, donate=True, seed=11):
+def _build(stage, k, acc=None, prefetch=None, donate=True, seed=11,
+           bf16=False):
     paddle.seed(seed)
     m = _mlp()
+    if bf16:
+        m.to("bfloat16")
     opt = paddle.optimizer.AdamW(parameters=m.parameters(),
-                                 learning_rate=0.05)
+                                 learning_rate=0.05, multi_precision=bf16)
     if stage:
         opt._zero_enable(axis="dp", stage=stage, comm_buffer_mb=COMM_MB,
                          prefetch=prefetch)
@@ -119,6 +122,17 @@ def test_predict_budget_table():
     # window boundary)
     assert P(3, scan_steps=4, n_buckets=2, prefetch=True) == \
         {ag: 8, rs: 8}
+    # buckets whose 16-bit gradients are exchanged narrow: an all-to-all
+    # at the reduce-scatter's cadence in its place, bucket by bucket
+    a2a = ("all-to-all", "dp")
+    assert P(3, scan_steps=4, n_buckets=2, exchanged_buckets=2) == \
+        {ag: 8, rs: 0, a2a: 8}
+    assert P(1, scan_steps=4, accumulate_steps=2, n_buckets=2,
+             exchanged_buckets=2) == {ag: 4, rs: 0, a2a: 4}
+    assert P(2, scan_steps=4, accumulate_steps=2, n_buckets=3,
+             exchanged_buckets=1) == {ag: 6, rs: 8, a2a: 4}
+    assert P(3, scan_steps=4, n_buckets=2, exchanged_buckets=0) == \
+        {ag: 8, rs: 8}
 
 
 def test_predict_budget_mesh_axes_gating():
@@ -171,6 +185,35 @@ def test_clean_grid_no_shardcheck_findings(stage, k, acc, pf):
         assert zl["n_buckets"] == layout["n_buckets"]
         assert shardcheck.check_collective_budget(s) == []
         assert shardcheck.check_zero_residency(opt) == []
+
+
+BF16_GRID = [(1, 4, None, None), (2, 4, 2, None), (3, 4, None, False),
+             (3, 4, 2, True)]
+
+
+@pytest.mark.parametrize("stage,k,acc,pf", BF16_GRID,
+                         ids=[f"z{s}_k{k}_a{a or 1}_pf{int(bool(p))}"
+                              for s, k, a, p in BF16_GRID])
+def test_clean_grid_bf16_expects_the_all_to_all(stage, k, acc, pf):
+    """bf16 parameters: every bucket's gradients are exchanged narrow,
+    the partition says so, the budget holds the compiled step to an
+    all-to-all where the float32 step reduce-scatters — and a layout
+    that claims the float32 schedule is short of its reduce-scatters."""
+    s, _m, opt = _build(stage, k, acc=acc, prefetch=pf, bf16=True)
+    x, y = _batches(k)
+    s(x, y)
+    findings = s.verify()
+    assert errors(findings) == []
+    assert [f for f in findings if f.rule in SHARD_RULES] == []
+    layout = shardcheck.infer_zero_layout(s)
+    assert layout["stage"] == stage
+    assert layout["exchanged_buckets"] == layout["n_buckets"] == 2
+    assert shardcheck.check_collective_budget(s) == []
+    assert shardcheck.check_zero_residency(opt) == []
+    lie = dict(layout, exchanged_buckets=0)
+    fs = shardcheck.check_collective_budget(s, layout=lie)
+    assert {f.op_name for f in fs} == {"reduce-scatter"}
+    assert all(f.rule == "collective-budget-mismatch" for f in fs)
 
 
 # -- seeded defects: one per rule -------------------------------------------
@@ -309,6 +352,15 @@ def test_record_level_rs_without_ag():
     assert stats["collectives"] == 2
     assert stats["axes"]["dp"] == {"reduce-scatter": 1, "all-gather": 1}
     assert shardcheck.format_shard_stats(stats) == "dp:ag1+rs1"
+    # the twin of a bf16 step: the all-to-all stands for the scatter
+    bad16 = prog_with(["c_alltoall"])
+    fs = shardcheck.check_program_sharding(bad16)
+    assert fs and fs[0].rule == "collective-budget-mismatch"
+    assert "1 all-to-all" in fs[0].message
+    good16 = prog_with(["c_alltoall", "c_allgather"])
+    assert shardcheck.check_program_sharding(good16) == []
+    assert shardcheck.format_shard_stats(
+        shardcheck.program_shard_stats(good16)) == "dp:ag1+a2a1"
     assert shardcheck.format_shard_stats(
         shardcheck.program_shard_stats(prog_with([]))) == "-"
 

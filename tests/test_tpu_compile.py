@@ -28,8 +28,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described v5e device; the persistent compile cache is off
+def v5e_topo():
+    """A described v5e 2x2 host; the persistent compile cache is off
     around these compiles (an entry written for an unattached chip can
     never be read back, and warns on every later attempt)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -43,9 +43,15 @@ def v5e_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_topo):
+    """One of its devices."""
+    return jax.sharding.SingleDeviceSharding(v5e_topo.devices[0])
 
 
 # (batch, seq, heads, head_dim), dtype, causal
@@ -144,6 +150,64 @@ def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
         r"= \w+\[[\d,]*8192\]\S* %s\(" % op, text))
         for op in ("exponential", "divide")}
     assert ffn_wide == {"exponential": 2, "divide": 2}
+
+
+@pytest.mark.parametrize("grad_dtype", ["bfloat16", "float32"])
+def test_zero_bucket_crosses_the_v5e_links_in_its_gradients_dtype(
+        v5e_topo, grad_dtype):
+    """One ZeRO bucket's reduction (`Optimizer._zero_reduced_shard`, a
+    2048 x 1024 bf16 weight and its bias over dp=4) compiled for the
+    four described chips. bf16 gradients: ONE all-to-all whose operand
+    is the bf16 bucket, split four ways, and neither an all-reduce nor a
+    reduce-scatter — the float32 sum is four converts and three adds
+    after it. float32 gradients: the float32 reduction XLA:TPU makes of
+    `psum_scatter` (an all-reduce inside a `kCustom` fusion), and no
+    all-to-all."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as paddle
+    from paddle_tpu import nn
+    from paddle_tpu.distributed import parallel_env
+
+    dp = 4
+    tpu_mesh = Mesh(np.array(v5e_topo.devices).reshape(dp), ("dp",))
+    # the stores are placed on attached devices; only the lowering sees
+    # the described ones
+    host_mesh = parallel_env.make_mesh({"dp": dp})
+    layer = nn.Linear(2048, 1024)
+    layer.to("bfloat16")
+    opt = paddle.optimizer.AdamW(parameters=layer.parameters(),
+                                 learning_rate=1e-4, multi_precision=True)
+    opt._zero_enable(axis="dp", stage=1, mesh=host_mesh)
+    (zb,) = opt._zero["buckets"]
+
+    def body(*grads):
+        with parallel_env.dp_axis_ctx("dp"):
+            for p, g in zip(zb.params, grads):
+                p._grad = g
+            shard, _present = opt._zero_reduced_shard(zb, "dp", dp, True,
+                                                      True)
+        for p in zb.params:
+            p._grad = None
+        return shard
+
+    fn = jax.shard_map(body, mesh=tpu_mesh, in_specs=P(),
+                       out_specs=P("dp"), check_vma=False)
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.dtype(grad_dtype),
+                                   sharding=NamedSharding(tpu_mesh, P()))
+              for shape in zb.shapes]
+    text = jax.jit(fn).lower(*shapes).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    pieces = r"= bf16\[%d,%d,1024\]\S* all-to-all\(" % (dp, zb.shard_rows)
+    if grad_dtype == "bfloat16":
+        assert len(re.findall(pieces, text)) == 1
+        assert "all-reduce" not in text and "reduce-scatter" not in text
+    else:
+        assert "all-to-all" not in text
+        assert re.search(r"= f32\[\d+,1024\]\S* all-reduce\(", text)
 
 
 _PLACEMENT_PROBE = """

@@ -175,6 +175,30 @@ def test_schedulable_overlap_pipelined_vs_serial():
     assert spliced["assumptions"]["schedulable_source"] == "traced-jaxpr"
 
 
+def test_schedulable_overlap_prices_the_narrow_exchange():
+    """bf16 gradients: the all-to-all stands where the reduce-scatter
+    stood in the pipeline, its float32 convert-and-add deferred with the
+    mean divide — serial scores exactly 0.0, pipelined > 0 with an
+    all-to-all given a real compute window, and the cost model prices
+    the op."""
+    k = 4
+    x, y = _batches(k)
+    s_off, _, _ = _build(3, k, bf16=True, prefetch=False)
+    s_off(x, y)
+    s_on, _, _ = _build(3, k, bf16=True, prefetch=True)
+    s_on(x, y)
+    off = s_off.schedulable_stats()
+    on = s_on.schedulable_stats()
+    assert off["schedulable_overlap"] == 0.0
+    assert on["schedulable_overlap"] > 0.0
+    ops = {p["op"] for p in on["pairs"]}
+    assert "all-to-all" in ops and "reduce-scatter" not in ops
+    assert any(p["op"] == "all-to-all" and p["available_ns"] > 0
+               for p in on["pairs"])
+    assert all(p["collective_ns"] > 0 for p in on["pairs"]
+               if p["op"] == "all-to-all")
+
+
 def test_schedulable_overlap_accumulation_window():
     """The pipeline composes with accumulation windows: boundary-step
     reduce/update pipelining still scores with accumulate_steps=2."""

@@ -909,3 +909,206 @@ def test_reduce_op_validation():
         dist.reduce_scatter(t, [t], op="bogus")
     with pytest.raises(NotImplementedError, match="not supported"):
         dist.reduce_scatter(t, [t], op=dist.ReduceOp.MAX)
+
+
+# -- the narrow gradient exchange -------------------------------------------
+# A bucket whose gradients all arrive in one 16-bit float type crosses
+# the wire in that type (one all_to_all in the reduce-scatter's place)
+# and is summed in float32 where it lands; float32 gradients keep the
+# float32 psum_scatter.
+
+COUNTER = "jit_zero_exchanged_buckets"
+
+
+def _collectives_in(jaxpr):
+    """{primitive name: [operand dtype, ...]} of the gradient-reduction
+    collectives in a jaxpr, nested regions included (``reduce_scatter``
+    is jax's name for ``psum_scatter``)."""
+    from paddle_tpu.observability.jaxpr_walk import sub_jaxprs
+    found = {}
+
+    def walk(jx):
+        for eqn in getattr(jx, "jaxpr", jx).eqns:
+            if eqn.primitive.name in ("all_to_all", "reduce_scatter"):
+                found.setdefault(eqn.primitive.name, []).append(
+                    str(eqn.invars[0].aval.dtype))
+            for sub in sub_jaxprs(eqn):
+                walk(sub)
+    walk(jaxpr)
+    return found
+
+
+def _collectives_traced(step):
+    """The same over a step's traced program: XLA:CPU widens a bf16
+    all-to-all's operands to float32 in its compiled text, so the dtype
+    the PROGRAM puts on the wire is read from the jaxpr
+    (tests/test_tpu_compile.py holds the chip's compiler to it)."""
+    return _collectives_in(step._last_aux["traced_jaxpr"]())
+
+
+NARROW_GRID = [(s, a, p) for s in (1, 2, 3) for a in (None, 2)
+               for p in (False, True)]
+
+
+@pytest.mark.parametrize(
+    "stage,acc,pf", NARROW_GRID,
+    ids=[f"z{s}_a{a or 1}_pf{int(p)}" for s, a, p in NARROW_GRID])
+def test_bf16_gradients_are_exchanged_narrow(stage, acc, pf):
+    """bf16 parameters give bf16 gradients: the compiled step holds one
+    all-to-all a bucket a reduction, its operand bf16, and no
+    reduce-scatter at all; `jit_zero_exchanged_buckets` reads the bucket
+    count, the partition carries it, and shardcheck's budget — which
+    expects the all-to-all in the reduce-scatter's place — is met."""
+    from paddle_tpu.analysis import (check_collective_budget,
+                                     infer_zero_layout)
+    k = 2
+    x, y = _batches(k)
+    before = monitor.stat_get(COUNTER)
+    s, _m, opt = _build(stage, k, bf16=True, comm_buffer_mb=0.003,
+                        accumulate=acc, prefetch=pf)
+    s(x, y)
+    nb = len(opt._zero["buckets"])
+    assert nb == 2
+    assert monitor.stat_get(COUNTER) - before == nb
+    assert infer_zero_layout(s)["exchanged_buckets"] == nb
+    # stage 1 reduces once a window, stages 2/3 every micro step
+    reductions = nb * (k // (acc or 1) if stage == 1 else k)
+    stats = {c["op"]: c for c in s.collective_stats(per_execution=True)}
+    assert "reduce-scatter" not in stats
+    assert stats["all-to-all"]["count"] == reductions
+    assert stats["all-to-all"]["axis"] == "dp"
+    assert check_collective_budget(s) == []
+    traced = _collectives_traced(s)
+    assert "reduce_scatter" not in traced  # jax's name for psum_scatter
+    assert set(traced["all_to_all"]) == {"bfloat16"}
+
+
+@pytest.mark.parametrize(
+    "stage,acc,pf", NARROW_GRID,
+    ids=[f"z{s}_a{a or 1}_pf{int(p)}" for s, a, p in NARROW_GRID])
+def test_float32_gradients_keep_the_reduce_scatter(stage, acc, pf):
+    """float32 gradients: no bucket is exchanged — the float32
+    psum_scatter per bucket per reduction of before, no all-to-all, the
+    counter does not move."""
+    k = 2
+    x, y = _batches(k)
+    before = monitor.stat_get(COUNTER)
+    s, _m, opt = _build(stage, k, bf16=False, comm_buffer_mb=0.003,
+                        accumulate=acc, prefetch=pf)
+    s(x, y)
+    nb = len(opt._zero["buckets"])
+    assert monitor.stat_get(COUNTER) == before
+    assert s._last_partition["zero_exchanged_buckets"] == 0
+    reductions = nb * (k // (acc or 1) if stage == 1 else k)
+    stats = {c["op"]: c for c in s.collective_stats(per_execution=True)}
+    assert "all-to-all" not in stats
+    assert stats["reduce-scatter"]["count"] == reductions
+    traced = _collectives_traced(s)
+    assert "all_to_all" not in traced
+    assert set(traced["reduce_scatter"]) == {"float32"}
+
+
+def test_replicated_step_counts_no_exchanged_bucket():
+    """ZeRO off: the counter stays where it was."""
+    k = 1
+    x, y = _batches(k)
+    before = monitor.stat_get(COUNTER)
+    s0, _m, _o = _build(0, k, bf16=True)
+    s0(x, y)
+    assert monitor.stat_get(COUNTER) == before
+    assert s0._last_partition["zero_exchanged_buckets"] == 0
+
+
+def _one_bucket_reduction(dp, param_dtype="bfloat16"):
+    """A dp-way mesh, one ZeRO bucket over the MLP's parameters, and a
+    jitted `reduce(widen, *per_rank_grads)`: `_zero_reduced_shard` of
+    that bucket inside the bound dp axis, the gradients as given or (for
+    each `widen` flag) first converted to float32; one shard a flag."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    mesh = parallel_env.make_mesh({"dp": dp})
+    parallel_env.set_mesh(mesh)
+    paddle.seed(3)
+    m = _mlp(bf16=True)
+    m.to(param_dtype)
+    opt = paddle.optimizer.AdamW(parameters=m.parameters(),
+                                 learning_rate=0.05, multi_precision=True)
+    opt._zero_enable(axis="dp", stage=1, mesh=mesh)
+    (zb,) = opt._zero["buckets"]
+
+    def reduce(widen, *grads):
+        def body(*gs):
+            out = []
+            with parallel_env.dp_axis_ctx("dp"):
+                for w in widen:
+                    for p, g in zip(zb.params, gs):
+                        p._grad = g[0].astype(jnp.float32) if w else g[0]
+                    shard, present = opt._zero_reduced_shard(
+                        zb, "dp", dp, True, True)
+                    assert all(present) and shard.dtype == jnp.float32
+                    out.append(shard)
+            for p in zb.params:
+                p._grad = None
+            return tuple(out)
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+            check_vma=False))
+
+    return zb, reduce
+
+
+@pytest.mark.parametrize("wire", ["bfloat16", "float16"])
+@pytest.mark.parametrize("dp", [2, 4, 8])
+def test_exchanged_shard_is_the_float32_sum(dp, wire):
+    """What `_zero_reduced_shard` hands back for 16-bit gradients is the
+    float32 mean of the same gradients: bitwise the rank-ordered float32
+    sum (the order is the program's), bitwise the float32 psum_scatter
+    of the widened gradients at dp=2, and within one float32 ulp of the
+    summed magnitudes above (a backend may order its all-reduce's adds
+    differently). No partial sum is rounded to 16 bits: per-rank values
+    that differ by less than a 16-bit ulp of their sum survive."""
+    import jax.numpy as jnp
+    zb, reduce = _one_bucket_reduction(dp, wire)
+    drng = np.random.RandomState(100 + dp)
+    # magnitudes spread over six binades, so that low bits of the small
+    # ranks' values fall below a 16-bit ulp of the large ones
+    grads = [(drng.randn(dp, *shape) * 2.0 ** drng.randint(
+                  -6, 1, (dp,) + (1,) * len(shape))).astype(wire)
+             for shape in zb.shapes]
+    got, want = (np.asarray(a) for a in reduce((False, True))(*grads))
+    flat = np.stack([np.asarray(zb.flatten(
+        [jnp.asarray(g[r]) for g in grads], dtype=jnp.dtype(wire)))
+        for r in range(dp)]).astype(np.float32)
+    ordered = flat[0]
+    for r in range(1, dp):
+        ordered = ordered + flat[r]
+    assert (ordered / np.float32(dp)).tobytes() == got.tobytes()
+    if dp == 2:
+        assert want.tobytes() == got.tobytes()
+    else:
+        ulp = np.spacing(np.abs(flat).sum(0) / np.float32(dp))
+        assert np.all(np.abs(got - want) <= ulp)
+    # a 16-bit partial sum would have lost what float32 keeps
+    narrow = flat[0].astype(wire)
+    for r in range(1, dp):
+        narrow = (narrow + flat[r].astype(wire)).astype(wire)
+    assert np.any(narrow.astype(np.float32) != ordered)
+
+
+def test_mixed_gradient_dtypes_keep_the_float32_reduction():
+    """The decision is per bucket and from the gradients alone: a bucket
+    holding one float32 gradient among bf16 ones is reduced in float32
+    (nothing narrow to gain without a second collective)."""
+    import jax
+    dp = 4
+    zb, reduce = _one_bucket_reduction(dp)
+    dtypes = ["float32"] + ["bfloat16"] * (len(zb.shapes) - 1)
+    grads = [np.ones((dp,) + shape, dt)
+             for shape, dt in zip(zb.shapes, dtypes)]
+    fn = reduce((False,))
+    assert _collectives_in(jax.make_jaxpr(fn)(*grads)) == {
+        "reduce_scatter": ["float32"]}
+    (got,) = fn(*grads)
+    assert np.all(np.asarray(got)[:sum(zb.n_rows)].reshape(-1)
+                  [:zb.sizes[0]] == 1.0)
