@@ -1,6 +1,10 @@
-"""Dygraph MoE layer over parallel.moe (name-compatible with the later
-reference releases' paddle.incubate.distributed.models.moe.MoELayer; this
-snapshot has no MoE)."""
+"""Dygraph MoE layers over parallel.moe: `MoELayer` (name-compatible with
+the later reference releases' paddle.incubate.distributed.models.moe.
+MoELayer; this snapshot has no MoE), Switch top-1 routing with a capacity;
+and `HeldExpertsLayer`, sigmoid-scored top-k routing over a whole expert
+set of which this device holds a contiguous share, no pair dropped, with
+`routing_stats()` for what its layers counted on the device."""
+import weakref
 import zlib
 
 import jax
@@ -9,7 +13,7 @@ import numpy as np
 
 from ..core.dispatch import call_op, unwrap, wrap
 from ..nn.layer.layers import Layer
-from ..parallel.moe import moe_ffn
+from ..parallel.moe import held_experts_ffn, moe_ffn
 
 
 class MoELayer(Layer):
@@ -78,3 +82,105 @@ class MoELayer(Layer):
                            self.w2, self.b2, op_name="moe_ffn")
         self.aux_loss = aux
         return out
+
+
+# every live HeldExpertsLayer, for `routing_stats()` (as
+# `observability.memory.program_scopes()` keeps the newest program)
+_held_layers = weakref.WeakSet()
+# a layer's counting buffers and the `monitor` stats they are summed into
+_COUNTERS = {"routed_pairs": "moe_routed_pairs", "steps": "moe_steps",
+             "load_max": "moe_expert_load_max"}
+
+
+class HeldExpertsLayer(Layer):
+    """One device's share of a routed expert layer (DeepSeek-V3's
+    `noaux_tc` routing): a router over all `num_experts`, `top_k` of them
+    chosen a token by sigmoid score plus a selection bias, gates
+    normalised over the chosen and scaled, and of the sum over the
+    chosen experts the part that experts `ep_rank * held ..` give, with
+    `held = num_experts // ep_size` gated SiLU units of width `d_hidden`
+    stored here. What the experts held elsewhere would add is left out
+    (their devices add it); no pair routed here is ever dropped.
+
+    `e_score_correction_bias` is a persistable buffer: it enters the
+    selection and nothing else, and no rule moves it here (`Layer.to`
+    casts it with the rest; the scores it joins are float32). Three
+    non-persistable int32 buffers count inside a compiled step, with no
+    host sync: `routed_pairs`, `steps` (applications of the layer) and
+    `load_max` (the busiest held expert's pairs, summed over them);
+    `routing_stats()` fetches them."""
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k, ep_size=1,
+                 ep_rank=0, routed_scaling_factor=1.0):
+        super().__init__()
+        if num_experts % ep_size or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"{num_experts} experts do not split over ep_size "
+                f"{ep_size} with ep_rank {ep_rank}")
+        held = num_experts // ep_size
+        self.num_experts, self.top_k, self.held = num_experts, top_k, held
+        self.first_expert = ep_rank * held
+        self.scale = float(routed_scaling_factor)
+        from ..nn.initializer import Normal
+
+        normal = Normal(0.0, 0.02)
+        self.router_weight = self.create_parameter(
+            [d_model, num_experts], default_initializer=normal)
+        self.w_gate = self.create_parameter(
+            [held, d_model, d_hidden], default_initializer=normal)
+        self.w_up = self.create_parameter(
+            [held, d_model, d_hidden], default_initializer=normal)
+        self.w_down = self.create_parameter(
+            [held, d_hidden, d_model], default_initializer=normal)
+        self.register_buffer("e_score_correction_bias",
+                             wrap(jnp.zeros((num_experts,), jnp.float32)))
+        for name in _COUNTERS:
+            self.register_buffer(name, wrap(jnp.zeros((), jnp.int32)),
+                                 persistable=False)
+        _held_layers.add(self)
+
+    def forward(self, x):
+        from ..jit.to_static import note_structure
+
+        shape = tuple(unwrap(x).shape)
+
+        def _moe(v, rw, wg, wu, wd, bias):
+            y, pairs, load = held_experts_ffn(
+                v.reshape(-1, shape[-1]), rw, bias, wg, wu, wd,
+                top_k=self.top_k, first_expert=self.first_expert,
+                scale=self.scale)
+            # the counts leave the op as float32 (an op's outputs are
+            # floating point); a layer application routes < 2**24 pairs
+            return (y.reshape(shape), pairs.astype(jnp.float32),
+                    load.astype(jnp.float32))
+
+        out, pairs, load = call_op(
+            _moe, x, self.router_weight, self.w_gate, self.w_up,
+            self.w_down, self.e_score_correction_bias,
+            op_name="held_experts_ffn")
+        for name, more in (("routed_pairs", unwrap(pairs)),
+                           ("load_max", unwrap(load)), ("steps", 1)):
+            setattr(self, name, wrap(unwrap(getattr(self, name))
+                                     + jnp.asarray(more, jnp.int32)))
+        note_structure("moe_layers")
+        note_structure("moe_experts_held", self.held)
+        return out
+
+
+def routing_stats():
+    """What the live `HeldExpertsLayer`s have counted so far, fetched in
+    one `device_get`: {"moe_routed_pairs", "moe_steps",
+    "moe_expert_load_max"} as python integers, also written to
+    `paddle_tpu.monitor` under those names. Call it between steps, never
+    inside one: it waits for the device."""
+    from .. import monitor
+
+    layers = list(_held_layers)
+    fetched = jax.device_get([[unwrap(getattr(layer, name))
+                               for name in _COUNTERS] for layer in layers])
+    totals = {stat: sum(int(row[i]) for row in fetched)
+              for i, stat in enumerate(_COUNTERS.values())}
+    for name, value in totals.items():
+        monitor.stat_reset(name)
+        monitor.stat_add(name, value)
+    return totals

@@ -131,12 +131,12 @@ def in_tracing():
 
 # what the newest trace of a step body staged, by kind: rolled regions'
 # trip counts, remat segments, activation factors saved for the backward
-# (`F.gelu`'s erfc), ZeRO buckets exchanged in their gradients' 16-bit
-# type. Every trace of a build's boundary step stages the same structure,
-# so the tally restarts with each and the build publishes the last one
-# (`jit_<kind>`: `jit_rolled_loop_trips`, `jit_zero_exchanged_buckets`, ...).
-_STRUCTURE = {"rolled_loop_trips": 0, "recompute_segments": 0,
-              "saved_activation_factors": 0, "zero_exchanged_buckets": 0}
+# (`F.gelu`'s erfc), ZeRO buckets exchanged in their gradients' 16-bit type,
+# held-expert layers and their experts. Every trace of a build's boundary
+# step stages the same, so each restarts the tally; published: `jit_<kind>`.
+_STRUCTURE = dict.fromkeys((
+    "rolled_loop_trips", "recompute_segments", "saved_activation_factors",
+    "zero_exchanged_buckets", "moe_layers", "moe_experts_held"), 0)
 
 
 def note_structure(kind, count=1):

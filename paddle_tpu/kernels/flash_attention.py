@@ -5,6 +5,10 @@ The analog of the reference's hand-written fused CUDA attention
 the S×S score matrix out of HBM entirely. Forward saves only the logsumexp
 row stats; backward recomputes scores blockwise (dq kernel + dkv kernel).
 Layout [B, S, H, D] outside (framework attention layout), [B*H, S, D] inside.
+q and k share one width `d_qk` and v, the output and their gradients another,
+`d_v` (latent attention: 192-wide keys, 128-wide values); the scores contract
+over `d_qk` in one product, `p v`, `do v^T` and `dv` run over `d_v`. With
+`d_v == d_qk` the kernels are what they were before the widths could differ.
 
 What is multiplied in which dtype: every `dot_general` takes its operands in
 the dtype the call's inputs arrive in and accumulates in float32. bf16 inputs
@@ -74,7 +78,7 @@ def _keep(q_pos, k_pos, causal, padded_pos, true_len):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
                 causal, scale, block_kv):
     qi = pl.program_id(1)
-    bq, d = q_ref.shape[1:]
+    bq, d_v = o_ref.shape[1:]
     q = q_ref[0]
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
@@ -104,7 +108,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    acc0 = jnp.zeros((bq, d_v), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_kv, body, (m0, l0, acc0))
 
     l_safe = jnp.maximum(l, 1e-30)
@@ -113,10 +117,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
 
 
 def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
-    """q/k/v: [BH, S, D] (seq padded to 128 multiples); kv_len = true
-    unpadded key length for masking."""
+    """q/k: [BH, S, D_qk], v: [BH, S, D_v] (seq padded to 128 multiples);
+    kv_len = true unpadded key length for masking."""
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = v.shape[1:]
     block_q, block_kv = _block(s_q), _block(s_k)
     kernel = functools.partial(
         _fwd_kernel, kv_len=kv_len, causal=causal, scale=scale,
@@ -127,14 +131,14 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s_k, d_v), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s_q, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -205,8 +209,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_new = dk + _dot(ds.astype(q.dtype), q, _NN)
         return dk_new, dv_new
 
-    zero = jnp.zeros((bkv, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, s_q // block_q, body, (zero, zero))
+    dk0 = jnp.zeros((bkv, d), jnp.float32)
+    dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
+    dk, dv = jax.lax.fori_loop(start_q, s_q // block_q, body, (dk0, dv0))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -214,7 +219,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
                interpret):
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = v.shape[1:]
     block_q, block_kv = _block(s_q), _block(s_k)
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [BH, S, 1]
@@ -226,8 +231,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, s_k, d_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
@@ -245,17 +250,17 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
         in_specs=[
             pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_kv, d_v), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, s_q, d_v), lambda b, i: (b, 0, 0)),
             stat_spec, stat_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, d_v), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype),
         ],
         interpret=interpret,
     )(q, k, v, do, lse.reshape(bh, n_q, 1, block_q),
@@ -294,9 +299,19 @@ def _pad_seq(x, block):
 
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=False):
-    """q/k/v: [B, S, H, D] -> [B, S, H, D]."""
+    """q/k: [B, S, H, D_qk], v: [B, S, H, D_v] -> [B, S, H, D_v]; the
+    default scale is 1 / sqrt(D_qk)."""
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
+    if k.shape[2] != h or v.shape[2] != h:
+        raise NotImplementedError(
+            f"flash attention needs one key and one value head a query "
+            f"head, got {h} query, {k.shape[2]} key and {v.shape[2]} value "
+            f"heads; grouped-query heads are not implemented")
+    if k.shape[3] != d or v.shape[1] != s_k:
+        raise ValueError(
+            f"flash attention: q {q.shape} and k {k.shape} must share "
+            f"their last axis, k and v {v.shape} their sequence")
     if causal and s_q != s_k:
         raise NotImplementedError(
             "causal flash attention requires s_q == s_k (top-left aligned "
@@ -304,11 +319,11 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=False):
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
     def to_bhsd(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], x.shape[3])
 
     qf, _ = _pad_seq(to_bhsd(q), BLOCKS[-1])
     kf, _ = _pad_seq(to_bhsd(k), BLOCKS[-1])
     vf, _ = _pad_seq(to_bhsd(v), BLOCKS[-1])
     out = _flash(qf, kf, vf, causal, float(scale), s_q, s_k, interpret)
     out = out[:, :s_q]
-    return jnp.swapaxes(out.reshape(b, h, s_q, d), 1, 2)
+    return jnp.swapaxes(out.reshape(b, h, s_q, v.shape[3]), 1, 2)

@@ -11,6 +11,9 @@ from .gpt import (  # noqa: F401
 from .ouro import (  # noqa: F401
     OuroConfig, OuroModel, OuroForCausalLM,
 )
+from .joyai import (  # noqa: F401
+    JoyAIFlashConfig, JoyAIFlashModel, JoyAIFlashForCausalLM,
+)
 from .ctr import (  # noqa: F401
     WideAndDeep, synthetic_ctr_batches, build_ctr_scan_step,
     train_ctr_windows,

@@ -1,5 +1,5 @@
 """The causal decoder block the language models share, built from what
-its configuration says of five things:
+its configuration says of six things:
 
     norm        "layer_norm" (weight and bias) or "rms_norm" (weight)
     sandwich    False: a norm before each sublayer (pre-LN, GPT-2/3);
@@ -12,7 +12,20 @@ its configuration says of five things:
                 `k_proj`, `v_proj`. `linear_bias` gives all of the
                 block's projections a bias, or none
     ffn         "gelu": fc2(gelu(fc1(x))); "swiglu": a gated unit,
-                down_proj(silu(gate_proj(x)) * up_proj(x))
+                down_proj(silu(gate_proj(x)) * up_proj(x)); "moe": routed
+                experts of which this device holds a share
+                (`incubate.moe.HeldExpertsLayer`, registered `moe`) plus
+                a gated unit every token meets (`shared_expert`). A
+                model may give one block another `ffn` than its
+                configuration's (a leading dense layer)
+    attention   "mha": heads of one width from `qkv` or `q_proj`,
+                `k_proj`, `v_proj`; "mla": latent attention (DeepSeek-V2)
+                    c_q = q_a_norm(x q_a_proj),  q = c_q q_b_proj
+                    (c_kv | k_r) = x kv_a_proj,  (k_n | v) =
+                    kv_a_norm(c_kv) kv_b_proj
+                q and k are `qk_nope_head_dim` wide without position and
+                `qk_rope_head_dim` with it (one rotary key for all
+                heads), v and the output `v_head_dim`; `o_proj` closes
 
 Under `GPTConfig` the block registers `ln1 qkv proj ln2 fc1 fc2` and
 stages the operations `GPTBlock` always staged, in their order.
@@ -35,44 +48,92 @@ class DecoderConfig:
     fused_qkv = True
     linear_bias = True
     ffn = "gelu"
+    attention = "mha"
+    rope_interleaved = False
     hidden_dropout = 0.0
     attention_dropout = 0.0
     use_mp = False
 
 
-def make_norm(cfg):
+def make_norm(cfg, width=None):
+    width = width or cfg.hidden_size
     if cfg.norm == "layer_norm":
-        return nn.LayerNorm(cfg.hidden_size, epsilon=cfg.norm_eps)
+        return nn.LayerNorm(width, epsilon=cfg.norm_eps)
     if cfg.norm == "rms_norm":
-        return nn.RMSNorm(cfg.hidden_size, epsilon=cfg.norm_eps)
+        return nn.RMSNorm(width, epsilon=cfg.norm_eps)
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
+class GatedFFN(nn.Layer):
+    """down_proj(silu(gate_proj(x)) * up_proj(x)), no biases."""
+
+    def __init__(self, hidden_size, intermediate_size):
+        super().__init__()
+        self.gate_proj = nn.Linear(hidden_size, intermediate_size,
+                                   bias_attr=False)
+        self.up_proj = nn.Linear(hidden_size, intermediate_size,
+                                 bias_attr=False)
+        self.down_proj = nn.Linear(intermediate_size, hidden_size,
+                                   bias_attr=False)
+
+    def forward(self, x):
+        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+
+
 class DecoderBlock(nn.Layer):
-    def __init__(self, cfg):
+    def __init__(self, cfg, ffn=None):
         super().__init__()
         h = cfg.hidden_size
         bias = None if cfg.linear_bias else False
+        ffn = ffn or cfg.ffn
         self.num_heads = cfg.num_heads
         self.head_dim = h // cfg.num_heads
         self.rope_theta = cfg.rope_theta
+        self.rope_interleaved = cfg.rope_interleaved
         self.attn_dropout_p = cfg.attention_dropout
-        if cfg.ffn not in ("gelu", "swiglu"):
-            raise ValueError(f"unknown ffn {cfg.ffn!r}")
+        if ffn not in ("gelu", "swiglu", "moe"):
+            raise ValueError(f"unknown ffn {ffn!r}")
+        if cfg.attention not in ("mha", "mla"):
+            raise ValueError(f"unknown attention {cfg.attention!r}")
         self.ln1 = make_norm(cfg)
-        if cfg.fused_qkv:
-            self.qkv = nn.Linear(h, 3 * h, bias_attr=bias)
+        if cfg.attention == "mla":
+            self.widths = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                           cfg.v_head_dim)
+            nope, rope, v = self.widths
+            self.q_a_proj = nn.Linear(h, cfg.q_lora_rank, bias_attr=bias)
+            self.q_a_norm = make_norm(cfg, cfg.q_lora_rank)
+            self.q_b_proj = nn.Linear(
+                cfg.q_lora_rank, cfg.num_heads * (nope + rope),
+                bias_attr=bias)
+            self.kv_a_proj = nn.Linear(h, cfg.kv_lora_rank + rope,
+                                       bias_attr=bias)
+            self.kv_a_norm = make_norm(cfg, cfg.kv_lora_rank)
+            self.kv_b_proj = nn.Linear(
+                cfg.kv_lora_rank, cfg.num_heads * (nope + v), bias_attr=bias)
+            self.o_proj = nn.Linear(cfg.num_heads * v, h, bias_attr=bias)
         else:
-            self.q_proj = nn.Linear(h, h, bias_attr=bias)
-            self.k_proj = nn.Linear(h, h, bias_attr=bias)
-            self.v_proj = nn.Linear(h, h, bias_attr=bias)
-        self.proj = nn.Linear(h, h, bias_attr=bias)
+            if cfg.fused_qkv:
+                self.qkv = nn.Linear(h, 3 * h, bias_attr=bias)
+            else:
+                self.q_proj = nn.Linear(h, h, bias_attr=bias)
+                self.k_proj = nn.Linear(h, h, bias_attr=bias)
+                self.v_proj = nn.Linear(h, h, bias_attr=bias)
+            self.proj = nn.Linear(h, h, bias_attr=bias)
         if cfg.sandwich:
             self.ln1_post = make_norm(cfg)
         self.ln2 = make_norm(cfg)
-        if cfg.ffn == "gelu":
+        if ffn == "gelu":
             self.fc1 = nn.Linear(h, cfg.intermediate_size, bias_attr=bias)
             self.fc2 = nn.Linear(cfg.intermediate_size, h, bias_attr=bias)
+        elif ffn == "moe":
+            from ..incubate.moe import HeldExpertsLayer
+            self.moe = HeldExpertsLayer(
+                h, cfg.moe_intermediate_size, cfg.n_routed_experts,
+                cfg.num_experts_per_tok, ep_size=cfg.ep_size,
+                ep_rank=cfg.ep_rank,
+                routed_scaling_factor=cfg.routed_scaling_factor)
+            self.shared_expert = GatedFFN(
+                h, cfg.n_shared_experts * cfg.moe_intermediate_size)
         else:
             self.gate_proj = nn.Linear(h, cfg.intermediate_size,
                                        bias_attr=bias)
@@ -84,8 +145,8 @@ class DecoderBlock(nn.Layer):
             self.ln2_post = make_norm(cfg)
         self.dropout = nn.Dropout(cfg.hidden_dropout)
         if cfg.use_mp:
-            if not (cfg.fused_qkv and cfg.ffn == "gelu"
-                    and cfg.linear_bias):
+            if not (cfg.fused_qkv and ffn == "gelu" and cfg.linear_bias
+                    and cfg.attention == "mha"):
                 raise NotImplementedError(
                     "use_mp shards the fused, biased GPT block only")
             self.qkv.weight.pspec = P(None, "mp")
@@ -108,19 +169,50 @@ class DecoderBlock(nn.Layer):
     def _ffn(self, h):
         if "fc1" in self._sub_layers:
             return self.fc2(F.gelu(self.fc1(h)))
+        if "moe" in self._sub_layers:
+            return self.moe(h) + self.shared_expert(h)
         return self.down_proj(F.silu(self.gate_proj(h)) * self.up_proj(h))
+
+    def _rope(self, x):
+        return F.rotary_embedding(x, theta=self.rope_theta,
+                                  interleaved=self.rope_interleaved)
+
+    def _latent_attention(self, h, b, s):
+        nope, rope, v_dim = self.widths
+        heads = self.num_heads
+        q = ops.reshape(self.q_b_proj(self.q_a_norm(self.q_a_proj(h))),
+                        [b, s, heads, nope + rope])
+        q_n, q_r = ops.split(q, [nope, rope], axis=-1)
+        c_kv, k_r = ops.split(self.kv_a_proj(h),
+                              [self.kv_a_norm.weight.shape[0], rope], axis=-1)
+        kv = ops.reshape(self.kv_b_proj(self.kv_a_norm(c_kv)),
+                         [b, s, heads, nope + v_dim])
+        k_n, v = ops.split(kv, [nope, v_dim], axis=-1)
+        k_r = self._rope(ops.reshape(k_r, [b, s, 1, rope]))
+        q = ops.concat([q_n, self._rope(q_r)], axis=-1)
+        # the one rotary key, copied to every head
+        k = ops.concat([k_n, ops.expand(k_r, [b, s, heads, rope])], axis=-1)
+        ctx = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, dropout_p=self.attn_dropout_p,
+            training=self.training)
+        return self.o_proj(ops.reshape(ctx, [b, s, heads * v_dim]))
+
+    def _attention(self, h, b, s):
+        if "q_a_proj" in self._sub_layers:
+            return self._latent_attention(h, b, s)
+        q, k, v = self._qkv(h, b, s)
+        if self.rope_theta is not None:
+            q, k = self._rope(q), self._rope(k)
+        ctx = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, dropout_p=self.attn_dropout_p,
+            training=self.training)
+        return self.proj(ops.reshape(
+            ctx, [b, s, self.num_heads * self.head_dim]))
 
     def forward(self, x):
         b, s = x.shape[0], x.shape[1]
         sandwich = "ln1_post" in self._sub_layers
-        q, k, v = self._qkv(self.ln1(x), b, s)
-        if self.rope_theta is not None:
-            q = F.rotary_embedding(q, theta=self.rope_theta)
-            k = F.rotary_embedding(k, theta=self.rope_theta)
-        ctx = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True, dropout_p=self.attn_dropout_p,
-            training=self.training)
-        a = self.proj(ops.reshape(ctx, [b, s, self.num_heads * self.head_dim]))
+        a = self._attention(self.ln1(x), b, s)
         if sandwich:
             a = self.ln1_post(a)
         x = x + self.dropout(a)

@@ -10,13 +10,13 @@ import jax.numpy as jnp
 from ...core.dispatch import call_op
 from ...observability.scopes import scope
 
-# Crossover measured in rounds 2-4 on the shared v5e of that time, with the
-# kernel of that time (128x128 blocks, head_dim 64): XLA's fused attention
-# won up to ~1k tokens; the pallas flash kernel won beyond (1.1-1.3x at
-# 2-4k) and keeps memory O(S) instead of O(S^2). The kernel under the gate
-# changed in PR 26 (512x512 blocks, 2.4-2.9x faster at seq 1024-2048 on
-# today's chip); the gate is still the old chip's and has not been
-# re-measured (ROADMAP S5, "the flash gate").
+# The kernel under the gate: three Mosaic kernels (forward, dq, dkv) on
+# 512x512 score tiles (PR 26), q and k of one width and v and the output of
+# another (PR 33: latent attention's 192 and 128; equal widths lower as
+# before), O(S) memory. The gate itself is older than that kernel: the
+# crossover at ~1k tokens was measured in rounds 2-4 on another chip with a
+# 128x128-tile kernel 2.4-2.9x slower, and has not been re-measured
+# (ROADMAP S5, "the flash gate").
 _FLASH_MIN_SEQ = 1024
 
 

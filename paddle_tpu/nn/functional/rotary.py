@@ -1,4 +1,5 @@
-"""Rotary position embedding (Su et al. 2021, RoFormer), rotate-half form.
+"""Rotary position embedding (Su et al. 2021, RoFormer): the rotate-half
+form, and behind `interleaved` the paper's own pairing of neighbours.
 
 Not in the reference snapshot; the position scheme of today's decoder
 models. Applied to q and k before `scaled_dot_product_attention`.
@@ -9,13 +10,16 @@ from ...core.dispatch import call_op
 from ...observability.scopes import scope
 
 
-def rotary_embedding(x, positions=None, theta=10000.0):
+def rotary_embedding(x, positions=None, theta=10000.0, interleaved=False):
     """Rotate x [batch, seq, heads, head_dim] by position: with the two
     halves of the last axis x1, x2 and the angle a[s, i] = positions[s]
     * theta ** (-2 i / head_dim),
 
         out = (x1 cos a - x2 sin a, x2 cos a + x1 sin a)
 
+    With `interleaved` pair i is the neighbours (x[2i], x[2i+1]) and not
+    (x[i], x[i + head_dim/2]): the same rotation, the result in x's own
+    order (a `rope_interleave: true` checkpoint's layout).
     `positions` ([seq] or [batch, seq], any numeric type) defaults to
     0..seq-1. The angles and the rotation are float32 whatever x is; the
     result has x's dtype. In a compiled step its device time goes under
@@ -32,6 +36,12 @@ def rotary_embedding(x, positions=None, theta=10000.0):
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         angle = p[..., None, None] * inv  # [(batch,) seq, 1, d/2]
         cos, sin = jnp.cos(angle), jnp.sin(angle)
+        if interleaved:
+            pairs = v.astype(jnp.float32).reshape(v.shape[:-1] + (d // 2, 2))
+            v1, v2 = pairs[..., 0], pairs[..., 1]
+            out = jnp.stack([v1 * cos - v2 * sin, v2 * cos + v1 * sin],
+                            axis=-1).reshape(v.shape)
+            return out.astype(v.dtype)
         v1, v2 = jnp.split(v.astype(jnp.float32), 2, axis=-1)
         out = jnp.concatenate([v1 * cos - v2 * sin, v2 * cos + v1 * sin],
                               axis=-1)

@@ -18,6 +18,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.models.joyai import JoyAIFlashConfig, JoyAIFlashForCausalLM
 from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -49,10 +50,10 @@ def _metric_files():
 
 METRICS = _metric_files()
 COUNTERS = sorted({a[key] for a, _f in METRICS.values()
-                   for key in ("counter", "per") if key in a})
+                   for key in ("counter", "per", "times") if key in a})
 
 
-def _compiled(model, forward_loss):
+def _compiled(model, forward_loss, seq=SEQ):
     """The step a user's loop calls, called twice: autocast, backward,
     AdamW with fp32 masters, `to_static(scan_steps=2)`."""
     opt = paddle.optimizer.AdamW(parameters=model.parameters(),
@@ -68,7 +69,7 @@ def _compiled(model, forward_loss):
 
     step = paddle.jit.to_static(one_step, scan_steps=K)
     ids = paddle.to_tensor(np.random.RandomState(0).randint(
-        0, VOCAB, (K, 2, SEQ)).astype("int32"))
+        0, VOCAB, (K, 2, seq)).astype("int32"))
     before = {c: monitor.stat_get(c) for c in COUNTERS}
     for _call in range(2):  # the build, then one call the counters keep
         assert np.isfinite(step(ids, ids).numpy()).all()
@@ -101,6 +102,32 @@ def ouro():
     return _compiled(model, lambda ids, labels: model(ids, labels))
 
 
+@pytest.fixture(scope="module")
+def joyai():
+    """At the flash gate's sequence, the kernels interpreted: the chip's
+    branch of the gate is the one the cell's metrics read."""
+    import functools
+
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.nn.functional import attention
+
+    paddle.seed(7)
+    model = JoyAIFlashForCausalLM(JoyAIFlashConfig(
+        vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=24, num_hidden_layers=2,
+        num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        n_routed_experts=8, num_experts_per_tok=2, ep_size=2, ep_rank=1,
+        max_position_embeddings=128)).enable_layer_recompute("kernels")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "is_available", lambda: True)
+        patch.setattr(fa, "flash_attention_bshd", functools.partial(
+            fa.flash_attention_bshd, interpret=True))
+        patch.setattr(attention, "_FLASH_MIN_SEQ", 128)
+        return _compiled(model, lambda ids, labels: model(ids, labels),
+                         seq=128)
+
+
 @pytest.mark.parametrize("metric", sorted(METRICS))
 def test_the_program_still_carries_what_the_metric_reads(metric, request):
     args, families = METRICS[metric]
@@ -108,7 +135,7 @@ def test_the_program_still_carries_what_the_metric_reads(metric, request):
         program = request.getfixturevalue(family)
         for name in set(args.get("scopes", ())) - MAY_ROOT_NONE:
             assert name in program["components"], (family, name)
-        for key in ("counter", "per"):
+        for key in ("counter", "per", "times"):
             if key in args:
                 assert program["counted"][args[key]] > 0, (family, args[key])
         for mark in args.get("marks", ()):

@@ -92,6 +92,53 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, shape, dtype, causal,
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kernel_with_narrower_values_compiles_for_v5e(v5e_chip,
+                                                            direction):
+    """Latent attention's call shape at the new cell's size: 32 heads,
+    q and k 192 wide (one contraction), v and the output 128, s 4,096
+    (k and v whole in VMEM beside 512 x 512 score tiles)."""
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def fwd(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    qk = jax.ShapeDtypeStruct((1, 4096, 32, 192), jnp.bfloat16,
+                              sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
+                             sharding=v5e_chip)
+    text = jax.jit(fn).lower(qk, qk, v).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+
+
+def test_held_experts_layer_compiles_for_v5e(v5e_chip):
+    """The routed experts at the new cell's widths (h 2,048, experts of
+    768, 16 held of 256, 8 a token), forward and backward: the grouped
+    products stay the chip's own grouped-matmul calls inside the chunk
+    loop's conditional, three forward and their transposes."""
+    from paddle_tpu.parallel.moe import held_experts_ffn
+
+    def loss(x, router, bias, gate, up, down):
+        y, _pairs, _load = held_experts_ffn(
+            x, router, bias, gate, up, down, top_k=8, first_expert=0,
+            scale=2.5)
+        return y.astype(jnp.float32).sum()
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape(4096, 2048), shape(2048, 256), shape(256, dtype=jnp.float32),
+        shape(16, 2048, 768), shape(16, 2048, 768),
+        shape(16, 768, 2048)).compile().as_text()
+    assert text.count("ragged-dot") >= 9 and "conditional(" in text
+
+
 def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
                                                          monkeypatch):
     """A GPT block at the cells' widths (h 2048, FFN 8192), forward and
