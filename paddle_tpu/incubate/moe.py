@@ -13,7 +13,7 @@ import numpy as np
 
 from ..core.dispatch import call_op, unwrap, wrap
 from ..nn.layer.layers import Layer
-from ..parallel.moe import held_experts_ffn, moe_ffn
+from ..parallel.moe import held_experts_ffn, moe_ffn, rows_worked
 
 
 class MoELayer(Layer):
@@ -89,7 +89,8 @@ class MoELayer(Layer):
 _held_layers = weakref.WeakSet()
 # a layer's counting buffers and the `monitor` stats they are summed into
 _COUNTERS = {"routed_pairs": "moe_routed_pairs", "steps": "moe_steps",
-             "load_max": "moe_expert_load_max"}
+             "load_max": "moe_expert_load_max",
+             "rows_worked": "moe_rows_worked"}
 
 
 class HeldExpertsLayer(Layer):
@@ -104,11 +105,17 @@ class HeldExpertsLayer(Layer):
 
     `e_score_correction_bias` is a persistable buffer: it enters the
     selection and nothing else, and no rule moves it here (`Layer.to`
-    casts it with the rest; the scores it joins are float32). Three
-    non-persistable int32 buffers count inside a compiled step, with no
-    host sync: `routed_pairs`, `steps` (applications of the layer) and
-    `load_max` (the busiest held expert's pairs, summed over them);
-    `routing_stats()` fetches them."""
+    casts it with the rest; the scores it joins are float32).
+
+    The routed pairs are worked through by a loop over blocks of sorted
+    pair slots whose trip count is read on the device (`parallel.moe.
+    held_experts_ffn`): a pass costs what the pairs routed here cost,
+    rounded up to a block. Four non-persistable int32 buffers count
+    inside a compiled step, with no host sync: `routed_pairs`, `steps`
+    (applications of the layer), `load_max` (the busiest held expert's
+    pairs, summed over them) and `rows_worked` (the rows of the blocks
+    the loop ran, summed over them: over `routed_pairs` it is the
+    padding the loop pays); `routing_stats()` fetches them."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_size=1,
                  ep_rank=0, routed_scaling_factor=1.0):
@@ -158,8 +165,11 @@ class HeldExpertsLayer(Layer):
             _moe, x, self.router_weight, self.w_gate, self.w_up,
             self.w_down, self.e_score_correction_bias,
             op_name="held_experts_ffn")
-        for name, more in (("routed_pairs", unwrap(pairs)),
-                           ("load_max", unwrap(load)), ("steps", 1)):
+        routed = unwrap(pairs).astype(jnp.int32)
+        slots = int(np.prod(shape[:-1])) * self.top_k
+        for name, more in (("routed_pairs", routed),
+                           ("load_max", unwrap(load)), ("steps", 1),
+                           ("rows_worked", rows_worked(routed, slots))):
             setattr(self, name, wrap(unwrap(getattr(self, name))
                                      + jnp.asarray(more, jnp.int32)))
         note_structure("moe_layers")
@@ -170,9 +180,9 @@ class HeldExpertsLayer(Layer):
 def routing_stats():
     """What the live `HeldExpertsLayer`s have counted so far, fetched in
     one `device_get`: {"moe_routed_pairs", "moe_steps",
-    "moe_expert_load_max"} as python integers, also written to
-    `paddle_tpu.monitor` under those names. Call it between steps, never
-    inside one: it waits for the device."""
+    "moe_expert_load_max", "moe_rows_worked"} as python integers, also
+    written to `paddle_tpu.monitor` under those names. Call it between
+    steps, never inside one: it waits for the device."""
     from .. import monitor
 
     layers = list(_held_layers)

@@ -12,6 +12,8 @@ standard capacity-factor semantics). Everything is differentiable jnp, so
 the same code runs single-device (no mesh) or inside shard_map with the
 'ep' axis bound.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -100,10 +102,182 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, axis_name=None, capacity_factor=1.25,
     return out * prob[:, None].astype(out.dtype), aux
 
 
-# the sorted pair slots are worked through in this many equal chunks, a
-# chunk that holds no routed pair skipped at run time: what a step costs
-# follows the pairs routed here, and every slot has a place
-_CHUNKS = 4
+# the sorted pair slots are worked through in blocks of this many rows,
+# as many blocks as hold a routed pair: what a layer costs, in every
+# pass, follows the pairs routed here, and every slot has a place
+_BLOCK = 1024
+# the backward keeps this many blocks' rows side by side and forms the
+# weights' gradients over them in one grouped product a weight: such a
+# product writes every held expert's [D, F] result whatever rows it was
+# given, 100 MB in float32 at 16 x 2048 x 768, so it runs once a layer
+# where the routing is the usual one and not once a block
+_STAGED = 16
+
+# the grouped product of a weight's gradient, as `ragged_dot`'s own
+# transpose forms it: [B, k] rows against [B, n] rows over the ragged B,
+# a [G, k, n] result. (A rows' gradient is `ragged_dot` against the
+# weight transposed: other dimension numbers leave the chip's grouped
+# matmul for a dense product over every group.)
+_ROWS_BY_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def _block_rows(slots):
+    return _BLOCK if slots % _BLOCK == 0 else slots
+
+
+def _live_blocks(block, routed):
+    return (routed + block - 1) // block
+
+
+def rows_worked(routed, slots):
+    """The rows `held_experts_ffn` works in a pass over `slots` pair
+    slots of which `routed` hold a pair routed here: its live blocks'."""
+    block = _block_rows(slots)
+    return block * _live_blocks(block, routed)
+
+
+def _dispatch(first, block, x, gates, token, expert, starts, ends):
+    """The block of sorted slots from `first` on: its tokens, which of
+    its rows hold a routed pair, those tokens' rows of x, where a row's
+    expert lies among all experts, its gate, and how many of the block's
+    rows each held expert has."""
+    tok = jax.lax.dynamic_slice_in_dim(token, first, block)
+    exp = jax.lax.dynamic_slice_in_dim(expert, first, block)
+    alive = (first + jnp.arange(block) < ends[-1])[:, None]
+    rows = jnp.where(alive, x[tok], 0)
+    # a slot's gate: its token's row of gates, at its expert
+    chosen = exp[:, None] == jnp.arange(gates.shape[1])
+    g = jnp.sum(jnp.where(chosen, gates[tok], 0), axis=-1, keepdims=True)
+    here = jnp.clip(jnp.minimum(ends, first + block)
+                    - jnp.maximum(starts, first), 0, block)
+    return tok, alive, rows, chosen, g, here
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed_blocks(block, staged, x, gates, w_gate, w_up, w_down, token,
+                   expert, starts, ends):
+    """sum over the routed pairs of gate x expert(row), added by token:
+    [T, D] in x's dtype. One loop over the live blocks forward, one
+    backward; nothing of a block is kept between them."""
+    from ..observability.scopes import scope
+
+    def one_block(i, acc):
+        with scope("dispatch"):
+            tok, alive, rows, _chosen, g, here = _dispatch(
+                i * block, block, x, gates, token, expert, starts, ends)
+        with scope("experts"):
+            h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, here))
+                 * jax.lax.ragged_dot(rows, w_up, here))
+            out = jax.lax.ragged_dot(h, w_down, here)
+        with scope("combine"):
+            out = jnp.where(alive, out, 0).astype(jnp.float32)
+            return acc.at[tok].add(out * g)
+
+    y = jax.lax.fori_loop(0, _live_blocks(block, ends[-1]), one_block,
+                          jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _routed_blocks_fwd(block, staged, *operands):
+    return _routed_blocks(block, staged, *operands), operands
+
+
+def _routed_blocks_bwd(block, staged, operands, dy):
+    from ..observability.scopes import scope
+
+    x, gates, w_gate, w_up, w_down, token, expert, starts, ends = operands
+    f32 = jnp.float32
+    live = _live_blocks(block, ends[-1])
+    span = staged * block
+    with scope("experts"):
+        gate_t, up_t, down_t = (jnp.swapaxes(w, 1, 2)
+                                for w in (w_gate, w_up, w_down))
+
+    def one_block(i, carry, base):
+        """Block i's part of dx and of the gates' gradient, and its rows
+        and the factors of the weights' gradients put beside the other
+        blocks' from block `base` on."""
+        dx, dgates, (s_rows, s_dy, s_da, s_du, s_h) = carry
+        with scope("dispatch"):
+            tok, alive, rows, chosen, g, here = _dispatch(
+                i * block, block, x, gates, token, expert, starts, ends)
+            dy_rows = jnp.where(alive, dy[tok], 0)
+        with scope("experts"):
+            # the forward's first two products and activation again: the
+            # one replay a block costs. The chip's grouped product
+            # writes the rows of its groups and no others: what it
+            # leaves in a row past the last routed pair is not a number
+            # to multiply by 0
+            a, u, dh = (
+                jnp.where(alive, product, 0) for product in (
+                    jax.lax.ragged_dot(rows, w_gate, here).astype(f32),
+                    jax.lax.ragged_dot(rows, w_up, here).astype(f32),
+                    # d(out) = g dy: the gate joins on the narrow side
+                    jax.lax.ragged_dot(dy_rows, down_t, here,
+                                       preferred_element_type=f32)))
+            gate = jax.nn.sigmoid(a)
+            act = a * gate
+            dg = jnp.sum(dh * (act * u), axis=-1, keepdims=True)
+            dh = dh * g
+            da = (dh * u * gate * (1 + a * (1 - gate))).astype(x.dtype)
+            du = (dh * act).astype(x.dtype)
+            hg = (act * u * g).astype(x.dtype)
+            d_rows = (jax.lax.ragged_dot(da, gate_t, here).astype(f32)
+                      + jax.lax.ragged_dot(du, up_t, here))
+        with scope("dispatch"):
+            dgates = dgates.at[tok].add(jnp.where(chosen, dg, 0))
+        with scope("combine"):
+            dx = dx.at[tok].add(jnp.where(alive, d_rows, 0))
+            at = (i - base) * block
+            put = jax.lax.dynamic_update_slice_in_dim
+            staging = (put(s_rows, rows, at, 0), put(s_dy, dy_rows, at, 0),
+                       put(s_da, da, at, 0), put(s_du, du, at, 0),
+                       put(s_h, hg, at, 0))
+        return dx, dgates, staging
+
+    def staged_blocks(j, carry):
+        """Blocks j * staged .. of the live ones, and the weights'
+        gradients over their rows."""
+        base = j * staged
+        dx, dgates, staging = jax.lax.fori_loop(
+            base, jnp.minimum(base + staged, live),
+            functools.partial(one_block, base=base), carry)
+        s_rows, s_dy, s_da, s_du, s_h = staging
+        with scope("experts"):
+            first = base * block
+            here = jnp.clip(jnp.minimum(ends, first + span)
+                            - jnp.maximum(starts, first), 0, span)
+            grads = tuple(
+                jax.lax.ragged_dot_general(
+                    lhs, rhs, here, _ROWS_BY_ROWS, preferred_element_type=f32)
+                for lhs, rhs in ((s_rows, s_da), (s_rows, s_du),
+                                 (s_h, s_dy)))
+        return (dx, dgates, staging), grads
+
+    width, hidden = w_gate.shape[1:]
+    carry = (jnp.zeros(x.shape, f32), jnp.zeros(gates.shape, f32),
+             tuple(jnp.zeros((span, n), x.dtype)
+                   for n in (width, width, hidden, hidden, hidden)))
+    # the first `staged` blocks' weight gradients start the sums; where
+    # more blocks are live the others' are added in float32
+    carry, grads = staged_blocks(0, carry)
+
+    def more(j, state):
+        carry, grads = state
+        carry, new = staged_blocks(j, carry)
+        with scope("experts"):
+            return carry, tuple(a + b for a, b in zip(grads, new))
+
+    (dx, dgates, _staging), grads = jax.lax.fori_loop(
+        1, (live + staged - 1) // staged, more, (carry, grads))
+    return (dx.astype(x.dtype), dgates.astype(gates.dtype)) + tuple(
+        g.astype(w.dtype) for g, w in zip(grads, (w_gate, w_up, w_down))
+    ) + (None,) * 4
+
+
+_routed_blocks.defvjp(_routed_blocks_fwd, _routed_blocks_bwd)
 
 
 def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
@@ -121,25 +295,28 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
         y   = sum_{i in sel, i held here} g_i E_i(x)
 
     The token-expert pairs routed to a held expert are sorted expert by
-    expert, their rows gathered, the three products run as grouped
-    products (`jax.lax.ragged_dot`) and the results are added back
-    weighted by g. All T * top_k pair slots exist, so the result is the
-    same whether every token chooses held experts or none does; the
-    slots are worked through in `_CHUNKS` chunks under `lax.cond`, and a
-    chunk past the last routed pair does nothing.
+    expert and worked through in blocks of `_BLOCK` sorted slots by a
+    loop whose trip count, ceil(routed / `_BLOCK`), is read on the device
+    at run time: a block's rows are gathered, the three products run as
+    grouped products (`jax.lax.ragged_dot`) and the results are added
+    back by token, weighted by g, into a float32 sum. All T * top_k pair
+    slots have a place, so the result is the same whether every token
+    chooses held experts (every block runs) or none does (none runs).
+    The backward is a second loop over the same blocks
+    (`jax.custom_vjp`): it gathers a block's rows again, repeats its
+    first two products and forms the gradients, which meet in float32;
+    no pass keeps anything of a block for another. Where `_BLOCK` does
+    not divide the slots they are one block.
 
     Returns (y [T, D] in x's dtype, routed pairs, the busiest held
     expert's pairs), the two counts int32 scalars. In a compiled step
     the device time goes under the scopes `router`, `dispatch`,
     `experts` and `combine`."""
     from ..observability.scopes import scope
-    from ..recompute import checkpoint_arrays
 
     tokens, _width = x.shape
     held = w_gate.shape[0]
     slots = tokens * top_k
-    chunks = _CHUNKS if slots % _CHUNKS == 0 else 1
-    size = slots // chunks
 
     with scope("router"):
         s = jax.nn.sigmoid(jnp.dot(
@@ -167,37 +344,9 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
                         dtype=jnp.int32)
         ends = jnp.cumsum(sizes)
         starts, routed = ends - sizes, ends[-1]
-        token = (order // top_k).reshape(chunks, size)
-        expert = (key + first_expert).reshape(chunks, size)
 
-    def chunk(acc, xs):
-        tok, exp, first = xs
-
-        def live(acc):
-            with scope("dispatch"):
-                alive = (first + jnp.arange(size) < routed)[:, None]
-                rows = jnp.where(alive, x[tok], 0)
-                # a slot's gate: its token's row of gates, at its expert
-                g = jnp.sum(jnp.where(exp[:, None] == jnp.arange(experts),
-                                      gates[tok], 0), axis=-1)
-                here = jnp.clip(jnp.minimum(ends, first + size)
-                                - jnp.maximum(starts, first), 0, size)
-            with scope("experts"):
-                h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, here))
-                     * jax.lax.ragged_dot(rows, w_up, here))
-                out = jax.lax.ragged_dot(h, w_down, here)
-            with scope("combine"):
-                out = jnp.where(alive, out, 0).astype(jnp.float32)
-                return acc.at[tok].add(out * g[:, None])
-
-        return jax.lax.cond(first < routed, live, lambda acc: acc, acc), None
-
-    # a chunk keeps nothing for its backward but its indices: kept, every
-    # chunk's rows, products and masks (and a copy of the weights a
-    # chunk, which `cond` cannot tell from a chunk's own) outlive the
-    # loop, 1.4 GiB more at 4 x 4,096 tokens of width 2,048, which the
-    # chip has not got beside a layer's other replayed activations
-    y, _ = jax.lax.scan(checkpoint_arrays(chunk),
-                        jnp.zeros(x.shape, jnp.float32),
-                        (token, expert, jnp.arange(chunks) * size))
-    return y.astype(x.dtype), routed, jnp.max(sizes)
+    block = _block_rows(slots)
+    y = _routed_blocks(block, min(_STAGED, slots // block), x, gates, w_gate,
+                       w_up, w_down, order // top_k, key + first_expert,
+                       starts, ends)
+    return y, routed, jnp.max(sizes)

@@ -169,15 +169,6 @@ def resolve_policy(policy, strict=False):
     return cp.dots_with_no_batch_dims_saveable, "selective"
 
 
-def checkpoint_arrays(fn):
-    """``fn`` over jax arrays — no Tensors, no framework state — as a
-    remat region that keeps nothing: for a loop body inside one op
-    (``parallel.moe.held_experts_ffn``'s chunk loop), which must not
-    call ``jax.checkpoint`` itself any more than a model may."""
-    return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.nothing_saveable)
-
-
 # -- remat replay marker (static-graph remat structure) ---------------------
 
 def remat_replay(fn):

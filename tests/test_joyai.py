@@ -27,6 +27,7 @@ from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
 from paddle_tpu.models.joyai import (JoyAIFlashConfig,  # noqa: E402
                                      JoyAIFlashForCausalLM)
 from paddle_tpu.nn import functional as F  # noqa: E402
+from paddle_tpu.parallel import moe as pmoe  # noqa: E402
 from paddle_tpu.parallel.moe import held_experts_ffn  # noqa: E402
 
 from chipbench.models import _common, joyai as bench  # noqa: E402
@@ -181,17 +182,17 @@ def _layer_inputs(tokens=48, width=32, experts=16, hidden=24, seed=3):
             "down": f32(experts, hidden, width, scale=0.3)}
 
 
-def _whole_layer(t, bias, top_k, scale):
+def _whole_layer(t, bias, top_k, scale, experts=range(16)):
     """The uncut routed sum, written from the equations: every chosen
-    expert of every token."""
+    expert of every token (of `experts`: a share, written densely)."""
     s = jax.nn.sigmoid(jnp.dot(t["x"], t["router"],
                                precision=jax.lax.Precision.HIGHEST))
     _, sel = jax.lax.top_k(s + bias, top_k)
     chosen = jax.nn.one_hot(sel, s.shape[1]).sum(1)
     g = scale * s * chosen / ((s * chosen).sum(-1, keepdims=True) + 1e-20)
-    units = [(jax.nn.silu(t["x"] @ t["gate"][e]) * (t["x"] @ t["up"][e]))
-             @ t["down"][e] for e in range(s.shape[1])]
-    return sum(g[:, e:e + 1] * units[e] for e in range(s.shape[1]))
+    return sum(g[:, e:e + 1] * (
+        (jax.nn.silu(t["x"] @ t["gate"][e]) * (t["x"] @ t["up"][e]))
+        @ t["down"][e]) for e in experts)
 
 
 def _share(t, bias, first, held, top_k=4, scale=2.5):
@@ -232,7 +233,88 @@ def test_no_pair_is_dropped_whatever_the_routing(kind):
         assert int(load) == 48  # every token chose each of the four
 
 
-def test_the_layer_counts_on_the_device_and_routing_stats_fetches():
+def _routing_bias(kind):
+    """A selection bias for experts 8..11 held, and the pairs it routes
+    there out of 48 tokens x 4 (None: whatever the scores give)."""
+    mine = (np.arange(16) >= 8) & (np.arange(16) < 12)
+    bias, pairs = {
+        "all_held": (np.where(mine, 10.0, 0.0), 192),
+        "none_held": (np.where(mine, -10.0, 0.0), 0),
+        "spread": (np.asarray(SPREAD), None),
+        # every token chooses expert 8 and three experts held elsewhere
+        "one_block": (np.where(np.arange(16) == 8, 10.0,
+                               np.where(mine, -10.0, 0.0)), 48),
+        # ... experts 8 and 9: 96 pairs, not a multiple of 36
+        "ragged_tail": (np.where((np.arange(16) == 8) | (np.arange(16) == 9),
+                                 10.0, np.where(mine, -10.0, 0.0)), 96),
+    }[kind]
+    return jnp.asarray(bias, jnp.float32), pairs
+
+
+# (block, staged blocks): the 192 slots as 12 blocks of which the
+# backward stages two at a time; as 4 blocks of 48 (the `one_block`
+# routing fills exactly one) all staged at once; as blocks of 36, which
+# do not divide them: one block, as at the module's own size
+BLOCKINGS = {"b16x2": (16, 2), "b48x16": (48, 16), "b36_one": (36, 16)}
+
+
+@pytest.mark.parametrize("blocking", list(BLOCKINGS))
+@pytest.mark.parametrize("kind", ["all_held", "none_held", "spread",
+                                  "one_block", "ragged_tail"])
+def test_the_loops_gradients_are_the_dense_formulations(kind, blocking,
+                                                        monkeypatch):
+    """x, the router and the three weights: the hand-written backward
+    over a run-time number of blocks against `jax.grad` of every token
+    through every held expert."""
+    block, staged = BLOCKINGS[blocking]
+    monkeypatch.setattr(pmoe, "_BLOCK", block)
+    monkeypatch.setattr(pmoe, "_STAGED", staged)
+    # the chip's grouped product leaves the rows past its groups
+    # unwritten (XLA:CPU zeroes them): here they come back as NaN, so a
+    # pass that let one into a sum would show it
+    plain = jax.lax.ragged_dot
+
+    def unwritten_past_the_groups(lhs, rhs, group_sizes, **kw):
+        out = plain(lhs, rhs, group_sizes, **kw)
+        past = jnp.arange(out.shape[0]) >= jnp.sum(group_sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", unwritten_past_the_groups)
+    t = _layer_inputs()
+    bias, pairs = _routing_bias(kind)
+    weight = jnp.asarray(np.random.default_rng(9).normal(size=t["x"].shape),
+                         jnp.float32)
+    keys = ("x", "router", "gate", "up", "down")
+
+    def loop(*leaves):
+        y, routed, _load = _share(dict(zip(keys, leaves)), bias, 8, 4)
+        return jnp.sum(y * weight), routed
+
+    def dense(*leaves):
+        return jnp.sum(_whole_layer(dict(zip(keys, leaves)), bias, 4, 2.5,
+                                    experts=range(8, 12)) * weight)
+
+    leaves = [t[k] for k in keys]
+    (value, routed), got = jax.jit(jax.value_and_grad(
+        loop, argnums=range(5), has_aux=True))(*leaves)
+    want_value, want = jax.value_and_grad(dense, argnums=range(5))(*leaves)
+    if pairs is not None:
+        assert int(routed) == pairs
+    one = 192 if 192 % block else block
+    assert int(pmoe.rows_worked(routed, 192)) == one * -(-int(routed) // one)
+    np.testing.assert_allclose(value, want_value, rtol=2e-5, atol=2e-5)
+    for key, g, w in zip(keys, got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * float(jnp.abs(w).max() + 1e-30),
+            err_msg=key)
+    if kind == "none_held":
+        assert all(float(jnp.abs(g).max()) == 0 for g in got)
+
+
+@pytest.mark.parametrize("block", [16, 1024], ids=["b16", "one_block"])
+def test_the_layer_counts_on_the_device_and_routing_stats_fetches(
+        block, monkeypatch):
+    monkeypatch.setattr(pmoe, "_BLOCK", block)
     paddle.seed(5)
     layer = moe_mod.HeldExpertsLayer(32, 24, 16, 4, ep_size=4, ep_rank=2,
                                      routed_scaling_factor=2.5)
@@ -248,6 +330,14 @@ def test_the_layer_counts_on_the_device_and_routing_stats_fetches():
     assert monitor.stat_get("moe_routed_pairs") == after["moe_routed_pairs"]
     assert (after["moe_expert_load_max"] - before["moe_expert_load_max"]
             >= pairs / 4)
+    # the same input three times: the live blocks' rows of one
+    # application, 192 slots in blocks of 16 or (1,024 does not divide
+    # them) in one
+    one = 16 if block == 16 else 192
+    worked = after["moe_rows_worked"] - before["moe_rows_worked"]
+    assert worked == 3 * one * -(-(pairs // 3) // one)
+    assert worked == int(layer.rows_worked.numpy())
+    assert monitor.stat_get("moe_rows_worked") == after["moe_rows_worked"]
     with pytest.raises(ValueError, match="ep_size"):
         moe_mod.HeldExpertsLayer(32, 24, 16, 4, ep_size=3)
 
@@ -360,7 +450,11 @@ def compiled():
         bench.make_batch, cfg, cell, SEED, 0, 2)]
     before = {c: monitor.stat_get(c) for c in BUILD_COUNTERS}
     stats = moe_mod.routing_stats()
-    losses = [step(*arrays).numpy().ravel() for _call in range(2)]
+    # 64 tokens x 4: four blocks of 64 slots, staged two at a time
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pmoe, "_BLOCK", 64)
+        patch.setattr(pmoe, "_STAGED", 2)
+        losses = [step(*arrays).numpy().ravel() for _call in range(2)]
     return {"step": step, "losses": np.concatenate(losses),
             "built": {c: monitor.stat_get(c) - v for c, v in before.items()},
             "routed": {k: v - stats[k]
@@ -395,6 +489,11 @@ def test_the_compiled_step_counts_once_a_step_whatever_is_replayed(compiled):
     mean = routed["moe_routed_pairs"] / routed["moe_steps"]
     assert 0.5 * tokens < mean < 1.6 * tokens  # expectation: 4 * 4 / 16 a token
     assert routed["moe_expert_load_max"] * 4 >= routed["moe_routed_pairs"]
+    # the live blocks' rows (blocks of 64), once an application: replay
+    # and backward run the loop again and count nothing
+    worked = routed["moe_rows_worked"]
+    assert worked % 64 == 0
+    assert 0 <= worked - routed["moe_routed_pairs"] < 64 * routed["moe_steps"]
 
 
 # ------------------------------------------------- the configuration file

@@ -116,27 +116,137 @@ def test_flash_kernel_with_narrower_values_compiles_for_v5e(v5e_chip,
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
 
 
-def test_held_experts_layer_compiles_for_v5e(v5e_chip):
-    """The routed experts at the new cell's widths (h 2,048, experts of
-    768, 16 held of 256, 8 a token), forward and backward: the grouped
-    products stay the chip's own grouped-matmul calls inside the chunk
-    loop's conditional, three forward and their transposes."""
-    from paddle_tpu.parallel.moe import held_experts_ffn
+def _stage_for_v5e(fwd_bwd, args, chip, monkeypatch):
+    """`to_static`'s own program of `fwd_bwd(*args)`, handed to the
+    chip's compiler in place of the attached backend's: keep the
+    function it would jit and the arguments of its first call, and
+    compile that for the described chip as the chip compiles it."""
+    import paddle_tpu as paddle
 
-    def loss(x, router, bias, gate, up, down):
-        y, _pairs, _load = held_experts_ffn(
-            x, router, bias, gate, up, down, top_k=8, first_expert=0,
-            scale=2.5)
-        return y.astype(jnp.float32).sum()
+    held = {}
 
-    def shape(*dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+    class Staged(Exception):
+        pass
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
-        shape(4096, 2048), shape(2048, 256), shape(256, dtype=jnp.float32),
-        shape(16, 2048, 768), shape(16, 2048, 768),
-        shape(16, 768, 2048)).compile().as_text()
-    assert text.count("ragged-dot") >= 9 and "conditional(" in text
+    def keep(self, fun, **kwargs):
+        def call(*args):
+            held.update(fun=fun, kwargs=kwargs, args=args)
+            raise Staged
+        return call
+
+    step = paddle.jit.to_static(fwd_bwd)
+    monkeypatch.setattr(type(step), "_jit", keep)
+    with pytest.raises(Staged):
+        step(*args)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype, sharding=chip),
+        held["args"])
+    return jax.jit(held["fun"], **held["kwargs"]).lower(*shapes).compile(
+        compiler_options={"xla_backend_optimization_level": 3})
+
+
+@pytest.fixture(scope="module")
+def held_experts_program(v5e_chip):
+    """One routed-expert layer at the JoyAI cell's size (16,384 tokens,
+    2,048 wide, 16 experts of 768 held of 256, 8 a token), forward and
+    backward in bf16 through `to_static`, so that the scopes are
+    entered as in a step: (compiled text, its temporaries' bytes)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.moe import HeldExpertsLayer
+
+    class Probe(paddle.nn.Layer):
+        """The layer over rows that are a parameter, so that the rows'
+        gradient is state the step keeps and not dead code."""
+
+        def __init__(self):
+            super().__init__()
+            self.rows = self.create_parameter([4, 4096, 2048])
+            self.moe = HeldExpertsLayer(2048, 768, 256, 8, ep_size=16,
+                                        ep_rank=0, routed_scaling_factor=2.5)
+
+        def forward(self):
+            return self.moe(self.rows)
+
+    probe = Probe()
+    probe.to("bfloat16")
+
+    def fwd_bwd():
+        loss = probe().astype("float32").sum()
+        loss.backward()
+        return loss
+
+    with pytest.MonkeyPatch.context() as patch:
+        compiled = _stage_for_v5e(fwd_bwd, (), v5e_chip, patch)
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def _grouped_products(text):
+    """(instruction name, op_name) of every grouped-matmul call in the
+    compiled text: the chip's own kernel, known by its tiling
+    attribute whatever the compiler names it."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        if "ragged_dot_tiling=" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            found.append((line.split("=")[0].strip().lstrip("%"),
+                          op_name.group(1) if op_name else ""))
+    return found
+
+
+def test_held_experts_layer_compiles_for_v5e(held_experts_program):
+    """The grouped products stay the chip's own grouped-matmul calls,
+    inside loops with a run-time trip count and under no conditional:
+    three a block forward; backward two again, one for the hidden
+    gradient, two for the rows' and three a weight over the staged
+    rows, written once and once more inside the loop over further
+    staged blocks."""
+    text, _temp = held_experts_program
+    assert len(_grouped_products(text)) >= 9
+    # forward, the backward's blocks, the further staged blocks and
+    # their blocks
+    assert text.count(" while(") >= 4 and "conditional(" not in text
+    # no grouped product fell back to a dense product over every group
+    assert "activations_broadcast_fusion" not in text
+
+
+def test_held_experts_device_time_keeps_its_names_for_v5e(
+        held_experts_program):
+    """What keeps `moe.experts_roofline` and `moe.route_share` honest:
+    every grouped product, forward, replayed and backward, is either
+    called `ragged-dot-none*` (the reader takes those by name) or
+    carries the scope `experts`; the row gathers carry `dispatch` and
+    the scatter-adds `combine` or, the gates' gradient, `dispatch`,
+    in the backward (`transpose(` in the name) as in the forward."""
+    import re
+
+    text, _temp = held_experts_program
+    products = _grouped_products(text)
+    assert all(name.startswith("ragged-dot-none") or "pt.experts" in op
+               for name, op in products), products
+    ops = re.findall(r'op_name="([^"]*/(?:gather|scatter-add))"', text)
+    loops = [op for op in ops if "/while/body/" in op]
+    assert loops and all("pt.held_experts_ffn" in op for op in loops)
+    for backward in (False, True):
+        mine = [op for op in loops if ("transpose(" in op) == backward]
+        gathers = [op for op in mine if op.endswith("/gather")]
+        scatters = [op for op in mine if op.endswith("/scatter-add")]
+        assert gathers and all("pt.dispatch/" in op for op in gathers), mine
+        assert any("pt.combine/" in op for op in scatters), mine
+        assert all("pt.combine/" in op or "pt.dispatch/" in op
+                   for op in scatters), mine
+
+
+# `held_experts_program`'s temporaries at the parent commit (00c0e96:
+# four chunks of 32,768 slots under `lax.cond`, each a remat region)
+PARENT_HELD_EXPERTS_TEMP_BYTES = 2_130_259_456  # the loop: 1,222,008,832
+
+
+def test_held_experts_layer_keeps_less_than_the_chunked_loop_for_v5e(
+        held_experts_program):
+    _text, temp = held_experts_program
+    assert temp < PARENT_HELD_EXPERTS_TEMP_BYTES
 
 
 def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
@@ -168,31 +278,9 @@ def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
         loss.backward()
         return loss
 
-    # to_static's own program, handed to the chip's compiler in place of
-    # the attached backend's: keep the function it would jit and the
-    # arguments of its first call
-    held = {}
-
-    class Staged(Exception):
-        pass
-
-    def keep(self, fun, **kwargs):
-        def call(*args):
-            held.update(fun=fun, kwargs=kwargs, args=args)
-            raise Staged
-        return call
-
-    step = paddle.jit.to_static(fwd_bwd)
-    monkeypatch.setattr(type(step), "_jit", keep)
     x = paddle.to_tensor(jnp.zeros((2, 512, 2048), jnp.bfloat16),
                          stop_gradient=False)
-    with pytest.raises(Staged):
-        step(x)
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
-                                       sharding=v5e_chip), held["args"])
-    text = jax.jit(held["fun"], **held["kwargs"]).lower(*shapes).compile(
-        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    text = _stage_for_v5e(fwd_bwd, (x,), v5e_chip, monkeypatch).as_text()
     ffn_wide = {op: len(re.findall(
         r"= \w+\[[\d,]*8192\]\S* %s\(" % op, text))
         for op in ("exponential", "divide")}
