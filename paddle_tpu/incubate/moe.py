@@ -97,11 +97,13 @@ class HeldExpertsLayer(Layer):
     """One device's share of a routed expert layer (DeepSeek-V3's
     `noaux_tc` routing): a router over all `num_experts`, `top_k` of them
     chosen a token by sigmoid score plus a selection bias, gates
-    normalised over the chosen and scaled, and of the sum over the
-    chosen experts the part that experts `ep_rank * held ..` give, with
-    `held = num_experts // ep_size` gated SiLU units of width `d_hidden`
-    stored here. What the experts held elsewhere would add is left out
-    (their devices add it); no pair routed here is ever dropped.
+    normalised over the chosen (their sum plus `norm_eps`, an argument:
+    DeepSeek-V3's 1e-20 by default, LFM2 passes 1e-6) and scaled, and of
+    the sum over the chosen experts the part that experts
+    `ep_rank * held ..` give, with `held = num_experts // ep_size` gated
+    SiLU units of width `d_hidden` stored here. What the experts held
+    elsewhere would add is left out (their devices add it); no pair
+    routed here is ever dropped.
 
     `e_score_correction_bias` is a persistable buffer: it enters the
     selection and nothing else, and no rule moves it here (`Layer.to`
@@ -118,7 +120,7 @@ class HeldExpertsLayer(Layer):
     padding the loop pays); `routing_stats()` fetches them."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_size=1,
-                 ep_rank=0, routed_scaling_factor=1.0):
+                 ep_rank=0, routed_scaling_factor=1.0, norm_eps=1e-20):
         super().__init__()
         if num_experts % ep_size or not 0 <= ep_rank < ep_size:
             raise ValueError(
@@ -128,6 +130,7 @@ class HeldExpertsLayer(Layer):
         self.num_experts, self.top_k, self.held = num_experts, top_k, held
         self.first_expert = ep_rank * held
         self.scale = float(routed_scaling_factor)
+        self.norm_eps = float(norm_eps)
         from ..nn.initializer import Normal
 
         normal = Normal(0.0, 0.02)
@@ -155,7 +158,7 @@ class HeldExpertsLayer(Layer):
             y, pairs, load = held_experts_ffn(
                 v.reshape(-1, shape[-1]), rw, bias, wg, wu, wd,
                 top_k=self.top_k, first_expert=self.first_expert,
-                scale=self.scale)
+                scale=self.scale, norm_eps=self.norm_eps)
             # the counts leave the op as float32 (an op's outputs are
             # floating point); a layer application routes < 2**24 pairs
             return (y.reshape(shape), pairs.astype(jnp.float32),

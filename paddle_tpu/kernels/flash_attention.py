@@ -10,6 +10,14 @@ q and k share one width `d_qk` and v, the output and their gradients another,
 over `d_qk` in one product, `p v`, `do v^T` and `dv` run over `d_v`. With
 `d_v == d_qk` the kernels are what they were before the widths could differ.
 
+Grouped-query heads: k and v may have fewer heads than q, `group` query heads
+to each (query head i reads key/value head i // group). K and V stay at their
+own head count in HBM: the forward and dq kernels find a query head's K and V
+through the block index map, and the dkv kernel's grid has a third, innermost
+axis over the group's query heads, whose dk and dv meet in the float32 output
+block that stays in VMEM across it. With one head each the three calls are
+what they were before the counts could differ.
+
 What is multiplied in which dtype: every `dot_general` takes its operands in
 the dtype the call's inputs arrive in and accumulates in float32. bf16 inputs
 go to the MXU as they are, and `p` (forward PV, backward dV) and `ds` (dQ, dK)
@@ -73,6 +81,14 @@ def _keep(q_pos, k_pos, causal, padded_pos, true_len):
     return keep
 
 
+def _whole_kv(group):
+    """The index map of the whole [1, S, D] K or V of a query head's
+    grid step: its own head's, or with grouped heads its group's."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
+
+
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, kv_len,
@@ -122,6 +138,7 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
     bh, s_q, d = q.shape
     s_k, d_v = v.shape[1:]
     block_q, block_kv = _block(s_q), _block(s_k)
+    whole_kv = _whole_kv(bh // k.shape[0])
     kernel = functools.partial(
         _fwd_kernel, kv_len=kv_len, causal=causal, scale=scale,
         block_kv=block_kv)
@@ -130,8 +147,8 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
         grid=(bh, s_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s_k, d), whole_kv),
+            pl.BlockSpec((1, s_k, d_v), whole_kv),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
@@ -180,10 +197,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, q_len, causal, scale, block_q):
-    """Scores, `p` and `ds` are held transposed, [BKV, BQ]; `lse_ref` and
-    `delta_ref` are [1, S_Q / BQ, 1, BQ], one row a query block."""
+def _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, q_len,
+              causal, scale, block_q):
+    """One key/value block's float32 (dk / scale, dv) over every block of
+    one query head. Scores, `p` and `ds` are held transposed, [BKV, BQ];
+    `lse_ref` and `delta_ref` are [1, S_Q / BQ, 1, BQ], one row a query
+    block."""
     ki = pl.program_id(1)
     bkv, d = k_ref.shape[1:]
     k = k_ref[0]
@@ -211,9 +230,69 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     dk0 = jnp.zeros((bkv, d), jnp.float32)
     dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, s_q // block_q, body, (dk0, dv0))
+    return jax.lax.fori_loop(start_q, s_q // block_q, body, (dk0, dv0))
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, *, scale, **static):
+    dk, dv = _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       scale=scale, **static)
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _bwd_dkv_grouped_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dk_ref, dv_ref, *, scale, **static):
+    """Grid axis 2 runs over the query heads of this key/value head: the
+    float32 output blocks stay where they are across it and take each
+    query head's sums."""
+    dk, dv = _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       scale=scale, **static)
+    first = pl.program_id(2) == 0
+
+    @pl.when(first)
+    def _():
+        dk_ref[0] = dk * scale
+        dv_ref[0] = dv
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        dk_ref[0] += dk * scale
+        dv_ref[0] += dv
+
+
+def _grouped_dkv(q, k, v, do, stats, group, static, interpret):
+    """dk and dv of `group` query heads to a key/value head: grid
+    (key/value heads, key blocks, the group), the sums float32 until the
+    last query head has added its own."""
+    (bh, s_q, d), (bkvh, s_k, d_v) = q.shape, v.shape
+    block_kv = _block(s_k)
+    n_q = s_q // static["block_q"]
+
+    def of_query_head(*block):
+        return pl.BlockSpec(block, lambda b, i, g: (b * group + g,)
+                            + (0,) * (len(block) - 1))
+
+    def of_kv_block(width):
+        return pl.BlockSpec((1, block_kv, width), lambda b, i, g: (b, i, 0))
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_grouped_kernel, **static),
+        grid=(bkvh, s_k // block_kv, group),
+        in_specs=[
+            of_query_head(1, s_q, d), of_kv_block(d), of_kv_block(d_v),
+            of_query_head(1, s_q, d_v),
+            of_query_head(1, n_q, 1, static["block_q"]),
+            of_query_head(1, n_q, 1, static["block_q"]),
+        ],
+        out_specs=[of_kv_block(d), of_kv_block(d_v)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bkvh, s_k, d), jnp.float32),
+            jax.ShapeDtypeStruct((bkvh, s_k, d_v), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, v, do, *stats)
+    return dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
@@ -221,6 +300,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
     bh, s_q, d = q.shape
     s_k, d_v = v.shape[1:]
     block_q, block_kv = _block(s_q), _block(s_k)
+    group = bh // k.shape[0]
+    whole_kv = _whole_kv(group)
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [BH, S, 1]
 
@@ -230,8 +311,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
         grid=(bh, s_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s_k, d), whole_kv),
+            pl.BlockSpec((1, s_k, d_v), whole_kv),
             pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
@@ -242,10 +323,15 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
     )(q, k, v, do, lse, delta)
 
     n_q = s_q // block_q
+    static = dict(q_len=q_len, causal=causal, scale=scale, block_q=block_q)
+    stats = (lse.reshape(bh, n_q, 1, block_q),
+             delta.reshape(bh, n_q, 1, block_q))
+    if group > 1:
+        dk, dv = _grouped_dkv(q, k, v, do, stats, group, static, interpret)
+        return dq, dk, dv
     stat_spec = pl.BlockSpec((1, n_q, 1, block_q), lambda b, i: (b, 0, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, q_len=q_len, causal=causal,
-                          scale=scale, block_q=block_q),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(bh, s_k // block_kv),
         in_specs=[
             pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
@@ -263,8 +349,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
             jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse.reshape(bh, n_q, 1, block_q),
-      delta.reshape(bh, n_q, 1, block_q))
+    )(q, k, v, do, *stats)
     return dq, dk, dv
 
 
@@ -299,15 +384,16 @@ def _pad_seq(x, block):
 
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=False):
-    """q/k: [B, S, H, D_qk], v: [B, S, H, D_v] -> [B, S, H, D_v]; the
-    default scale is 1 / sqrt(D_qk)."""
+    """q: [B, S, H, D_qk], k: [B, S, H_kv, D_qk], v: [B, S, H_kv, D_v] ->
+    [B, S, H, D_v], query head i reading key/value head i // (H / H_kv);
+    the default scale is 1 / sqrt(D_qk)."""
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    if k.shape[2] != h or v.shape[2] != h:
+    if v.shape[2] != k.shape[2] or h % k.shape[2]:
         raise NotImplementedError(
-            f"flash attention needs one key and one value head a query "
-            f"head, got {h} query, {k.shape[2]} key and {v.shape[2]} value "
-            f"heads; grouped-query heads are not implemented")
+            f"flash attention needs as many value heads as key heads and "
+            f"the query heads a multiple of them (grouped-query heads), "
+            f"got {h} query, {k.shape[2]} key and {v.shape[2]} value heads")
     if k.shape[3] != d or v.shape[1] != s_k:
         raise ValueError(
             f"flash attention: q {q.shape} and k {k.shape} must share "
@@ -319,7 +405,8 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=False):
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
     def to_bhsd(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], x.shape[3])
+        return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], x.shape[1],
+                                             x.shape[3])
 
     qf, _ = _pad_seq(to_bhsd(q), BLOCKS[-1])
     kf, _ = _pad_seq(to_bhsd(k), BLOCKS[-1])

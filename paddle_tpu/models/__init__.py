@@ -14,6 +14,9 @@ from .ouro import (  # noqa: F401
 from .joyai import (  # noqa: F401
     JoyAIFlashConfig, JoyAIFlashModel, JoyAIFlashForCausalLM,
 )
+from .lfm2 import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeModel, Lfm2MoeForCausalLM,
+)
 from .ctr import (  # noqa: F401
     WideAndDeep, synthetic_ctr_batches, build_ctr_scan_step,
     train_ctr_windows,

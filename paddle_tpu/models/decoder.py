@@ -1,5 +1,8 @@
 """The causal decoder block the language models share, built from what
-its configuration says of six things:
+its configuration says of six things (two of them, `ffn` and
+`attention`, a model may give one block otherwise than its
+configuration: a leading dense layer, a stack whose `layer_types`
+alternate):
 
     norm        "layer_norm" (weight and bias) or "rms_norm" (weight)
     sandwich    False: a norm before each sublayer (pre-LN, GPT-2/3);
@@ -18,8 +21,19 @@ its configuration says of six things:
                 a gated unit every token meets (`shared_expert`). A
                 model may give one block another `ffn` than its
                 configuration's (a leading dense layer)
-    attention   "mha": heads of one width from `qkv` or `q_proj`,
-                `k_proj`, `v_proj`; "mla": latent attention (DeepSeek-V2)
+    attention   the block's sequence mixer. "mha": heads of one width
+                from `qkv` or `q_proj`, `k_proj`, `v_proj`; with
+                `num_key_value_heads` fewer than `num_heads` (separate
+                projections only) the heads are grouped-query, query
+                head i reading key/value head i // group, and with
+                `qk_norm` q and k pass a norm of their own over the
+                head's width (`q_norm`, `k_norm`) before the rotation.
+                "conv": no attention but LFM2's gated short convolution,
+                    (B | C | z) = x conv_in,  out = (C * conv(B * z))
+                    conv_out
+                a causal depthwise convolution of `conv_L_cache` taps
+                (`conv_taps` [h, taps], `F.gated_short_conv`), no bias.
+                "mla": latent attention (DeepSeek-V2)
                     c_q = q_a_norm(x q_a_proj),  q = c_q q_b_proj
                     (c_kv | k_r) = x kv_a_proj,  (k_n | v) =
                     kv_a_norm(c_kv) kv_b_proj
@@ -53,6 +67,11 @@ class DecoderConfig:
     hidden_dropout = 0.0
     attention_dropout = 0.0
     use_mp = False
+    num_key_value_heads = None  # as many as `num_heads`
+    qk_norm = False
+    conv_L_cache = 3
+    n_shared_experts = 1
+    router_norm_eps = 1e-20
 
 
 def make_norm(cfg, width=None):
@@ -81,22 +100,37 @@ class GatedFFN(nn.Layer):
 
 
 class DecoderBlock(nn.Layer):
-    def __init__(self, cfg, ffn=None):
+    def __init__(self, cfg, ffn=None, attention=None):
         super().__init__()
         h = cfg.hidden_size
         bias = None if cfg.linear_bias else False
         ffn = ffn or cfg.ffn
+        attention = attention or cfg.attention
         self.num_heads = cfg.num_heads
+        self.num_kv_heads = cfg.num_key_value_heads or cfg.num_heads
         self.head_dim = h // cfg.num_heads
         self.rope_theta = cfg.rope_theta
         self.rope_interleaved = cfg.rope_interleaved
         self.attn_dropout_p = cfg.attention_dropout
         if ffn not in ("gelu", "swiglu", "moe"):
             raise ValueError(f"unknown ffn {ffn!r}")
-        if cfg.attention not in ("mha", "mla"):
-            raise ValueError(f"unknown attention {cfg.attention!r}")
+        if attention not in ("mha", "mla", "conv"):
+            raise ValueError(f"unknown attention {attention!r}")
+        grouped = self.num_kv_heads != self.num_heads
+        if grouped and (cfg.fused_qkv or attention == "mla"
+                        or self.num_heads % self.num_kv_heads):
+            raise NotImplementedError(
+                f"{self.num_kv_heads} key/value heads for {self.num_heads}: "
+                f"grouped-query heads divide the query heads and come from "
+                f"separate q_proj, k_proj, v_proj")
         self.ln1 = make_norm(cfg)
-        if cfg.attention == "mla":
+        if attention == "conv":
+            from ..nn.initializer import Normal
+            self.conv_in = nn.Linear(h, 3 * h, bias_attr=bias)
+            self.conv_taps = self.create_parameter(
+                [h, cfg.conv_L_cache], default_initializer=Normal(0.0, 0.02))
+            self.conv_out = nn.Linear(h, h, bias_attr=bias)
+        elif attention == "mla":
             self.widths = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                            cfg.v_head_dim)
             nope, rope, v = self.widths
@@ -115,9 +149,13 @@ class DecoderBlock(nn.Layer):
             if cfg.fused_qkv:
                 self.qkv = nn.Linear(h, 3 * h, bias_attr=bias)
             else:
+                kv = self.num_kv_heads * self.head_dim
                 self.q_proj = nn.Linear(h, h, bias_attr=bias)
-                self.k_proj = nn.Linear(h, h, bias_attr=bias)
-                self.v_proj = nn.Linear(h, h, bias_attr=bias)
+                self.k_proj = nn.Linear(h, kv, bias_attr=bias)
+                self.v_proj = nn.Linear(h, kv, bias_attr=bias)
+            if cfg.qk_norm:
+                self.q_norm = make_norm(cfg, self.head_dim)
+                self.k_norm = make_norm(cfg, self.head_dim)
             self.proj = nn.Linear(h, h, bias_attr=bias)
         if cfg.sandwich:
             self.ln1_post = make_norm(cfg)
@@ -131,9 +169,11 @@ class DecoderBlock(nn.Layer):
                 h, cfg.moe_intermediate_size, cfg.n_routed_experts,
                 cfg.num_experts_per_tok, ep_size=cfg.ep_size,
                 ep_rank=cfg.ep_rank,
-                routed_scaling_factor=cfg.routed_scaling_factor)
-            self.shared_expert = GatedFFN(
-                h, cfg.n_shared_experts * cfg.moe_intermediate_size)
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                norm_eps=cfg.router_norm_eps)
+            if cfg.n_shared_experts:
+                self.shared_expert = GatedFFN(
+                    h, cfg.n_shared_experts * cfg.moe_intermediate_size)
         else:
             self.gate_proj = nn.Linear(h, cfg.intermediate_size,
                                        bias_attr=bias)
@@ -146,7 +186,7 @@ class DecoderBlock(nn.Layer):
         self.dropout = nn.Dropout(cfg.hidden_dropout)
         if cfg.use_mp:
             if not (cfg.fused_qkv and ffn == "gelu" and cfg.linear_bias
-                    and cfg.attention == "mha"):
+                    and attention == "mha"):
                 raise NotImplementedError(
                     "use_mp shards the fused, biased GPT block only")
             self.qkv.weight.pspec = P(None, "mp")
@@ -162,15 +202,18 @@ class DecoderBlock(nn.Layer):
                               [b, s, 3, self.num_heads, self.head_dim])
             return ops.unstack(qkv, axis=2)
         shape = [b, s, self.num_heads, self.head_dim]
+        kv_shape = [b, s, self.num_kv_heads, self.head_dim]
         return (ops.reshape(self.q_proj(h), shape),
-                ops.reshape(self.k_proj(h), shape),
-                ops.reshape(self.v_proj(h), shape))
+                ops.reshape(self.k_proj(h), kv_shape),
+                ops.reshape(self.v_proj(h), kv_shape))
 
     def _ffn(self, h):
         if "fc1" in self._sub_layers:
             return self.fc2(F.gelu(self.fc1(h)))
-        if "moe" in self._sub_layers:
+        if "shared_expert" in self._sub_layers:
             return self.moe(h) + self.shared_expert(h)
+        if "moe" in self._sub_layers:
+            return self.moe(h)
         return self.down_proj(F.silu(self.gate_proj(h)) * self.up_proj(h))
 
     def _rope(self, x):
@@ -198,9 +241,19 @@ class DecoderBlock(nn.Layer):
         return self.o_proj(ops.reshape(ctx, [b, s, heads * v_dim]))
 
     def _attention(self, h, b, s):
+        from ..jit.to_static import note_structure
+
+        if "conv_in" in self._sub_layers:
+            note_structure("short_conv_layers")
+            return self.conv_out(F.gated_short_conv(self.conv_in(h),
+                                                    self.conv_taps))
         if "q_a_proj" in self._sub_layers:
             return self._latent_attention(h, b, s)
         q, k, v = self._qkv(h, b, s)
+        if "q_norm" in self._sub_layers:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if self.num_kv_heads != self.num_heads:
+            note_structure("gqa_attention_layers")
         if self.rope_theta is not None:
             q, k = self._rope(q), self._rope(k)
         ctx = F.scaled_dot_product_attention(

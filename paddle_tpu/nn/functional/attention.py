@@ -4,6 +4,9 @@ Not a single op in the reference (composed from matmul+softmax there; the
 fused path is `operators/fused/fused_attention_op.cu` in later snapshots).
 Here: one fused XLA computation by default, and the pallas flash-attention
 kernel (paddle_tpu.kernels.flash_attention) on TPU for long sequences.
+Both take grouped-query heads (key and value with fewer heads than the
+query, query head i reading key/value head i // group) and values
+narrower than keys; neither copies K or V out to the query's head count.
 """
 import jax.numpy as jnp
 
@@ -23,9 +26,10 @@ _FLASH_MIN_SEQ = 1024
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
                                  scale=None):
-    """q/k/v: [batch, seq, heads, head_dim] (paddle layout). In a
-    compiled step its device time goes under the scope `attention`, with
-    the path taken beneath it (`flash` / `xla`)."""
+    """q/k/v: [batch, seq, heads, head_dim] (paddle layout); k and v
+    may have fewer heads, a divisor of q's. In a compiled step its
+    device time goes under the scope `attention`, with the path taken
+    beneath it (`flash` / `xla`)."""
     with scope("attention"):
         return _sdpa_dispatch(query, key, value, attn_mask, dropout_p,
                               is_causal, training, scale)
@@ -66,7 +70,13 @@ def _sdpa_dispatch(query, key, value, attn_mask, dropout_p, is_causal,
         qt = jnp.swapaxes(q, 1, 2)
         kt = jnp.swapaxes(k, 1, 2)
         vt = jnp.swapaxes(v, 1, 2)
-        logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * s
+        group = qt.shape[1] // kt.shape[1]
+        if group == 1:
+            logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * s
+        else:  # query heads [kv head, its group] against their one K
+            grouped = (qt.shape[0], kt.shape[1], group) + qt.shape[2:]
+            logits = jnp.einsum("bhgqd,bhkd->bhgqk", qt.reshape(grouped),
+                                kt).reshape(qt.shape[:3] + kt.shape[2:3]) * s
         if is_causal:
             causal = jnp.tril(jnp.ones((logits.shape[-2], logits.shape[-1]),
                                        dtype=bool))
@@ -82,7 +92,13 @@ def _sdpa_dispatch(query, key, value, attn_mask, dropout_p, is_causal,
             import jax
             keep = jax.random.bernoulli(drop_key, 1.0 - dropout_p, probs.shape)
             probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vt.dtype), vt)
+        if group == 1:
+            out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vt.dtype), vt)
+        else:
+            out = jnp.einsum(
+                "bhgqk,bhkd->bhgqd",
+                probs.astype(vt.dtype).reshape(grouped[:4] + probs.shape[3:]),
+                vt).reshape(probs.shape[:3] + vt.shape[3:])
         return jnp.swapaxes(out, 1, 2)  # back to [B, S, H, D]
 
     args = (query, key, value) + ((attn_mask,) if attn_mask is not None else ())

@@ -110,7 +110,9 @@ _BLOCK = 1024
 # weights' gradients over them in one grouped product a weight: such a
 # product writes every held expert's [D, F] result whatever rows it was
 # given, 100 MB in float32 at 16 x 2048 x 768, so it runs once a layer
-# where the routing is the usual one and not once a block
+# where the routing is the usual one and not once a block. A share that
+# uniform routing sends more than half as many blocks stages twice its
+# usual blocks (`_staged_blocks`)
 _STAGED = 16
 
 # the grouped product of a weight's gradient, as `ragged_dot`'s own
@@ -129,6 +131,17 @@ def _block_rows(slots):
 
 def _live_blocks(block, routed):
     return (routed + block - 1) // block
+
+
+def _staged_blocks(slots, block, held, experts):
+    """How many blocks' rows the backward stages for a share of `held`
+    of `experts`: `_STAGED`, or twice the blocks uniform routing sends
+    here where that is more. At exactly `_STAGED` usual blocks (top-4 of
+    64 with 8 held over 32,768 tokens) every other layer application
+    would spill one block into a second pass over the weights'
+    gradients, and which ones do turns on the drawn router."""
+    usual = -(-slots * held // (experts * block))
+    return min(max(_STAGED, 2 * usual), slots // block)
 
 
 def rows_worked(routed, slots):
@@ -281,7 +294,7 @@ _routed_blocks.defvjp(_routed_blocks_fwd, _routed_blocks_bwd)
 
 
 def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
-                     top_k, first_expert, scale):
+                     top_k, first_expert, scale, norm_eps=1e-20):
     """Sigmoid-scored top-k routing over ALL experts and the part of the
     result that the experts held here give, with no pair dropped.
 
@@ -291,7 +304,7 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
     gated SiLU units. With s = sigmoid(x router_w) in float32 and `sel`
     the top_k of s + select_bias (ties to the lower index),
 
-        g_i = scale * s_i / (sum_{j in sel} s_j + 1e-20)      i in sel
+        g_i = scale * s_i / (sum_{j in sel} s_j + norm_eps)   i in sel
         y   = sum_{i in sel, i held here} g_i E_i(x)
 
     The token-expert pairs routed to a held expert are sorted expert by
@@ -331,7 +344,7 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
         chosen = jnp.sum(sel[:, :, None] == jnp.arange(experts), axis=1)
         picked = s * chosen
         gates = scale * picked / (
-            jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+            jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
 
     with scope("dispatch"):
         local = (sel - first_expert).reshape(-1)
@@ -346,7 +359,7 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
         starts, routed = ends - sizes, ends[-1]
 
     block = _block_rows(slots)
-    y = _routed_blocks(block, min(_STAGED, slots // block), x, gates, w_gate,
-                       w_up, w_down, order // top_k, key + first_expert,
-                       starts, ends)
+    y = _routed_blocks(block, _staged_blocks(slots, block, held, experts), x,
+                       gates, w_gate, w_up, w_down, order // top_k,
+                       key + first_expert, starts, ends)
     return y, routed, jnp.max(sizes)

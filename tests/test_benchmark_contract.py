@@ -19,6 +19,7 @@ import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.joyai import JoyAIFlashConfig, JoyAIFlashForCausalLM
+from paddle_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
 from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -102,8 +103,7 @@ def ouro():
     return _compiled(model, lambda ids, labels: model(ids, labels))
 
 
-@pytest.fixture(scope="module")
-def joyai():
+def _compiled_at_the_flash_gate(model):
     """At the flash gate's sequence, the kernels interpreted: the chip's
     branch of the gate is the one the cell's metrics read."""
     import functools
@@ -111,14 +111,6 @@ def joyai():
     from paddle_tpu.kernels import flash_attention as fa
     from paddle_tpu.nn.functional import attention
 
-    paddle.seed(7)
-    model = JoyAIFlashForCausalLM(JoyAIFlashConfig(
-        vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
-        moe_intermediate_size=24, num_hidden_layers=2,
-        num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
-        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-        n_routed_experts=8, num_experts_per_tok=2, ep_size=2, ep_rank=1,
-        max_position_embeddings=128)).enable_layer_recompute("kernels")
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(fa, "is_available", lambda: True)
         patch.setattr(fa, "flash_attention_bshd", functools.partial(
@@ -126,6 +118,30 @@ def joyai():
         patch.setattr(attention, "_FLASH_MIN_SEQ", 128)
         return _compiled(model, lambda ids, labels: model(ids, labels),
                          seq=128)
+
+
+@pytest.fixture(scope="module")
+def joyai():
+    paddle.seed(7)
+    return _compiled_at_the_flash_gate(JoyAIFlashForCausalLM(JoyAIFlashConfig(
+        vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=24, num_hidden_layers=2,
+        num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        n_routed_experts=8, num_experts_per_tok=2, ep_size=2, ep_rank=1,
+        max_position_embeddings=128)).enable_layer_recompute("kernels"))
+
+
+@pytest.fixture(scope="module")
+def lfm2():
+    paddle.seed(7)
+    return _compiled_at_the_flash_gate(Lfm2MoeForCausalLM(Lfm2MoeConfig(
+        vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=24, num_hidden_layers=3,
+        layer_types=["conv", "full_attention", "conv"],
+        num_attention_heads=4, num_key_value_heads=2, num_dense_layers=1,
+        num_experts=8, num_experts_per_tok=2, ep_size=2, ep_rank=1,
+        max_position_embeddings=128)).enable_layer_recompute("kernels"))
 
 
 @pytest.mark.parametrize("metric", sorted(METRICS))

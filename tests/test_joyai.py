@@ -397,7 +397,7 @@ def test_equal_widths_lower_to_what_they_lowered_to():
 def test_the_entry_refuses_by_name_what_it_cannot_do():
     q = jnp.zeros((1, 128, 4, 16))
     with pytest.raises(NotImplementedError, match="grouped-query"):
-        fa.flash_attention_bshd(q, q[:, :, :2], q[:, :, :2], interpret=True)
+        fa.flash_attention_bshd(q, q[:, :, :3], q[:, :, :3], interpret=True)
     with pytest.raises(NotImplementedError, match="s_q == s_k"):
         fa.flash_attention_bshd(q, jnp.zeros((1, 256, 4, 16)),
                                 jnp.zeros((1, 256, 4, 16)), causal=True,

@@ -116,6 +116,60 @@ def test_flash_kernel_with_narrower_values_compiles_for_v5e(v5e_chip,
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kernel_with_grouped_heads_compiles_for_v5e(v5e_chip,
+                                                          direction):
+    """LFM2's call shape at its cell's size: 32 query heads over 8
+    key/value heads, 64 wide (half the lane axis), s 8,192 (a key/value
+    head whole in VMEM beside 512 x 512 score tiles; the dkv kernel's
+    grid over the group's four query heads). K and V enter the kernels
+    at their 8 heads: no operand of a Mosaic call is a 32-head K."""
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def fwd(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                              sharding=v5e_chip)
+    text = jax.jit(fn).lower(q, kv, kv).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "= " in line]
+    assert calls and all("bf16[32,8192,64]" in line
+                         and "bf16[8,8192,64]" in line for line in calls)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_short_conv_kernel_compiles_for_v5e(v5e_chip, direction):
+    """LFM2's operator at its cell's size: u [4, 8192, 3 x 2048] bf16,
+    3 taps; 128 positions and all 6,144 channels a grid step, the
+    backward with its two neighbour views and the taps' gradient block
+    resident: inside the VMEM a kernel may take."""
+    from paddle_tpu.kernels import short_conv as kernel
+
+    u = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16,
+                             sharding=v5e_chip)
+    taps = jax.ShapeDtypeStruct((2048, 3), jnp.bfloat16, sharding=v5e_chip)
+    dout = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16,
+                                sharding=v5e_chip)
+    assert kernel.supports(u.shape, taps.shape)
+    if direction == "fwd":
+        fn, args = kernel.short_conv, (u, taps)
+    else:
+        fn = lambda u, w, d: jax.vjp(kernel.short_conv, u, w)[1](d)  # noqa: E731
+        args = (u, taps, dout)
+    text = jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= 1
+
+
 def _stage_for_v5e(fwd_bwd, args, chip, monkeypatch):
     """`to_static`'s own program of `fwd_bwd(*args)`, handed to the
     chip's compiler in place of the attached backend's: keep the
