@@ -361,8 +361,8 @@ def phase_kernel():
         emit("kernel", shape=[b, s, h, d], causal=causal, dtype="bfloat16",
              tpu_custom_calls=custom_calls,
              max_err_over_max_ref=errs, tolerance=KERNEL_TOL)
-        # forward, dq and dkv kernels: the kernel was chosen, not XLA
-        if custom_calls < 3:
+        # the forward and the one backward kernel: chosen, not XLA
+        if custom_calls < 2:
             raise AssertionError(
                 f"flash kernel not in the compiled program "
                 f"({custom_calls} tpu_custom_call) at {(b, s, h, d)}")

@@ -2,21 +2,48 @@
 
 The analog of the reference's hand-written fused CUDA attention
 (`operators/fused/fused_attention_op.cu` family): online-softmax tiling keeps
-the S×S score matrix out of HBM entirely. Forward saves only the logsumexp
-row stats; backward recomputes scores blockwise (dq kernel + dkv kernel).
+the S×S score matrix out of HBM entirely. Two Mosaic kernels: the forward,
+which saves only the logsumexp row stats, and one backward, which recomputes
+the scores blockwise and feeds dq, dk and dv from them.
 Layout [B, S, H, D] outside (framework attention layout), [B*H, S, D] inside.
 q and k share one width `d_qk` and v, the output and their gradients another,
 `d_v` (latent attention: 192-wide keys, 128-wide values); the scores contract
-over `d_qk` in one product, `p v`, `do v^T` and `dv` run over `d_v`. With
-`d_v == d_qk` the kernels are what they were before the widths could differ.
+over `d_qk` in one product, `p v`, `do v^T` and `dv` run over `d_v`.
+
+The backward (PR 36): for each visited (key block, query block) pair the
+scores, `p`, the mask, `dp = do v^T` and `ds = p (dp - delta)` are formed
+once, and `dv += p^T do`, `dk += ds^T q`, `dq += ds k` all read them: five
+products and one `exp` a pair, where a dq kernel and a dkv kernel that each
+formed them took seven and two. The grid runs over key blocks with Q and dO
+of a query head whole in VMEM; dk and dv of the key block are the loop's
+float32 carries. The tile is held transposed (`k q^T`, the row statistics as
+rows), so four of the products are plain or transposed-weights matmuls; the
+fifth gives dq transposed, `k^T ds^T` ([D_qk, BQ]: it contracts the tile's
+rows, so `k`, the small operand, is what the matmul turns and no 512 x 512
+tile goes through the transpose unit), added into a float32 [D_qk, S_Q]
+scratch that stays in VMEM across the query head's key blocks, is zeroed at
+the first and is turned, scaled and rounded once into the head's dq block at
+the last. No float32 partial sum leaves VMEM. Measured on the v5e (PR 36,
+forward + backward of one call, the two backward kernels -> this one): b2 x
+2048 x 16 heads of 128 1.875 -> 1.587 ms; b4 x 4096 x 32 heads of 192 / 128
+34.34 -> 26.54 ms; b4 x 8192 x 32-over-8 heads of 64 75.85 -> 56.39 ms.
+`ds^T` turned on the transpose unit a tile pair instead reads 1.566, 27.55
+and 59.57 ms; writing dq's first visit and not zeroing loses 1.5 % everywhere.
 
 Grouped-query heads: k and v may have fewer heads than q, `group` query heads
 to each (query head i reads key/value head i // group). K and V stay at their
-own head count in HBM: the forward and dq kernels find a query head's K and V
-through the block index map, and the dkv kernel's grid has a third, innermost
-axis over the group's query heads, whose dk and dv meet in the float32 output
-block that stays in VMEM across it. With one head each the three calls are
-what they were before the counts could differ.
+own head count in HBM: the forward finds a query head's K and V through the
+block index map, and the backward's grid is (key/value heads, the group's
+query heads, key blocks): a key/value head's float32 dk and dv stay whole in
+VMEM scratch across the two inner axes, the group's first query head writes
+them, the others add, the last rounds them out. With one head each there is
+no such scratch and a key block's dk and dv leave as they are summed.
+
+VMEM: the backward states its limit from its operands' shapes (Q, dO and the
+dq block whole and double-buffered, the float32 sums, 8 MiB for the rest) and
+never under the 16 MiB a call gets unasked: 16 MiB at s 2,048, 21 MiB at
+4,096 x 192 / 128 (which does not fit 16 at the cell's 128 heads' rows), 30
+MiB at 8,192 x 32-over-8 x 64, where 64-wide blocks take 128 lanes.
 
 What is multiplied in which dtype: every `dot_general` takes its operands in
 the dtype the call's inputs arrive in and accumulates in float32. bf16 inputs
@@ -31,26 +58,27 @@ Blocks: a score tile is `block x block` with `block` the largest of 512, 256,
 128 that divides the sequence padded to a 128 multiple (measured on the v5e,
 PR 26: the kernels are bound by the latency of one loop iteration and by
 vector spills, not by the MXU, and 512 x 512 runs 2.4x faster than 128 x 128).
-The dkv kernel computes its scores transposed (`k q^T`, the row statistics as
-rows), so that each of its four products is a plain or a transposed-weights
-matmul and nothing is transposed on the vector units.
 
 Which blocks are masked: causal calls mask every block they visit by position
-(blocks wholly above the diagonal are not visited); padded key (forward, dq)
-or query (dkv) positions are masked with the true length where the wrapper
-padded, and a call with neither builds no mask at all.
+(blocks wholly above the diagonal are not visited); padded key (forward) or
+query (backward) positions are masked with the true length where the wrapper
+padded (the backward needs no key mask: a padded key's row of K is zero, so
+what it adds to dq is zero, and its dk and dv rows are sliced off), and a
+call with neither builds no mask at all.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCKS = (512, 256, 128)
 NEG_INF = -1e30
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def is_available():
@@ -165,52 +193,29 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, interpret):
 
 # --------------------------------------------------------------- backward
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, kv_len, causal, scale, block_kv):
-    qi = pl.program_id(1)
-    bq, d = q_ref.shape[1:]
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]      # [BQ, 1]
-    delta = delta_ref[0]  # [BQ, 1]
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-
-    s_k = k_ref.shape[1]
-    n_kv = s_k // block_kv
-    if causal:
-        n_kv = jnp.minimum(n_kv, pl.cdiv((qi + 1) * bq, block_kv))
-
-    def body(ki, dq):
-        k = _rows(k_ref, ki, block_kv)
-        v = _rows(v_ref, ki, block_kv)
-        p = jnp.exp(_dot(q, k, _NT) * scale - lse)
-        k_pos = ki * block_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_kv), 1)
-        keep = _keep(q_pos, k_pos, causal,
-                     k_pos if kv_len < s_k else None, kv_len)
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
-        ds = p * (_dot(do, v, _NT) - delta)
-        return dq + _dot(ds.astype(k.dtype), k, _NN)
-
-    dq = jax.lax.fori_loop(0, n_kv, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, q_len,
-              causal, scale, block_q):
-    """One key/value block's float32 (dk / scale, dv) over every block of
-    one query head. Scores, `p` and `ds` are held transposed, [BKV, BQ];
-    `lse_ref` and `delta_ref` are [1, S_Q / BQ, 1, BQ], one row a query
-    block."""
-    ki = pl.program_id(1)
-    bkv, d = k_ref.shape[1:]
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *dkv_acc,
+                q_len, causal, scale, block_q):
+    """One key/value block against every query block of one query head;
+    grid (key/value heads, the group's query heads, key blocks). Scores,
+    `p` and `ds` are formed once a tile pair and held transposed,
+    [BKV, BQ]; `lse_ref` and `delta_ref` are [1, S_Q / BQ, 1, BQ], one row
+    a query block. `dq_acc` is the query head's dq / scale, transposed
+    ([D_qk, S_Q] float32), across the head's key blocks; `dkv_acc`, with
+    grouped heads, the float32 (dk / scale, dv) of the whole key/value
+    head across the group's query heads."""
+    g, ki = pl.program_id(1), pl.program_id(2)
+    bkv = k_ref.shape[1]
     k = k_ref[0]
     v = v_ref[0]
     k_pos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bkv, 1), 0)
 
     s_q = q_ref.shape[1]
     start_q = (ki * bkv) // block_q if causal else 0  # earlier: all masked
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def body(qi, carry):
         dk, dv = carry
@@ -224,133 +229,101 @@ def _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, q_len,
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
         dv_new = dv + _dot(p.astype(do.dtype), do, _NN)
-        ds = p * (_dot(v, do, _NT) - delta_ref[0, qi])
-        dk_new = dk + _dot(ds.astype(q.dtype), q, _NN)
+        ds = (p * (_dot(v, do, _NT) - delta_ref[0, qi])).astype(q.dtype)
+        dk_new = dk + _dot(ds, q, _NN)
+        cols = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[:, cols] += _dot(k, ds, _TN)
         return dk_new, dv_new
 
-    dk0 = jnp.zeros((bkv, d), jnp.float32)
+    dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
-    return jax.lax.fori_loop(start_q, s_q // block_q, body, (dk0, dv0))
+    dk, dv = jax.lax.fori_loop(start_q, s_q // block_q, body, (dk0, dv0))
 
+    if dkv_acc:
+        dk_acc, dv_acc = dkv_acc
+        rows = pl.ds(pl.multiple_of(ki * bkv, bkv), bkv)
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, **static):
-    dk, dv = _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       scale=scale, **static)
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        @pl.when(g == 0)
+        def _():
+            dk_acc[rows, :] = dk
+            dv_acc[rows, :] = dv
 
+        @pl.when(g > 0)
+        def _():
+            dk_acc[rows, :] += dk
+            dv_acc[rows, :] += dv
 
-def _bwd_dkv_grouped_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            dk_ref, dv_ref, *, scale, **static):
-    """Grid axis 2 runs over the query heads of this key/value head: the
-    float32 output blocks stay where they are across it and take each
-    query head's sums."""
-    dk, dv = _dkv_sums(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       scale=scale, **static)
-    first = pl.program_id(2) == 0
+        @pl.when(g == pl.num_programs(1) - 1)
+        def _():
+            dk_ref[0] = (dk_acc[rows, :] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[rows, :].astype(dv_ref.dtype)
+    else:
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
-    @pl.when(first)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0] = dk * scale
-        dv_ref[0] = dv
-
-    @pl.when(jnp.logical_not(first))
-    def _():
-        dk_ref[0] += dk * scale
-        dv_ref[0] += dv
+        dq_ref[0] = (dq_acc[...].T * scale).astype(dq_ref.dtype)
 
 
-def _grouped_dkv(q, k, v, do, stats, group, static, interpret):
-    """dk and dv of `group` query heads to a key/value head: grid
-    (key/value heads, key blocks, the group), the sums float32 until the
-    last query head has added its own."""
-    (bh, s_q, d), (bkvh, s_k, d_v) = q.shape, v.shape
-    block_kv = _block(s_k)
-    n_q = s_q // static["block_q"]
-
-    def of_query_head(*block):
-        return pl.BlockSpec(block, lambda b, i, g: (b * group + g,)
-                            + (0,) * (len(block) - 1))
-
-    def of_kv_block(width):
-        return pl.BlockSpec((1, block_kv, width), lambda b, i, g: (b, i, 0))
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_grouped_kernel, **static),
-        grid=(bkvh, s_k // block_kv, group),
-        in_specs=[
-            of_query_head(1, s_q, d), of_kv_block(d), of_kv_block(d_v),
-            of_query_head(1, s_q, d_v),
-            of_query_head(1, n_q, 1, static["block_q"]),
-            of_query_head(1, n_q, 1, static["block_q"]),
-        ],
-        out_specs=[of_kv_block(d), of_kv_block(d_v)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bkvh, s_k, d), jnp.float32),
-            jax.ShapeDtypeStruct((bkvh, s_k, d_v), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, *stats)
-    return dk.astype(k.dtype), dv.astype(v.dtype)
+def _held_bytes(rows, cols, dtype):
+    """A [rows, cols] block as VMEM holds it: lanes padded to 128."""
+    return rows * -(-cols // 128) * 128 * jnp.dtype(dtype).itemsize
 
 
-def _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
-               interpret):
+def _flash_bwd(q, k, v, out, lse, do, causal, scale, q_len, interpret):
     bh, s_q, d = q.shape
-    s_k, d_v = v.shape[1:]
+    bkvh, s_k, d_v = v.shape
     block_q, block_kv = _block(s_q), _block(s_k)
-    group = bh // k.shape[0]
-    whole_kv = _whole_kv(group)
+    group, n_q = bh // bkvh, s_q // block_q
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [BH, S, 1]
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, kv_len=kv_len, causal=causal,
-                          scale=scale, block_kv=block_kv),
-        grid=(bh, s_q // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_k, d), whole_kv),
-            pl.BlockSpec((1, s_k, d_v), whole_kv),
-            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    def of_query_head(*block):
+        return pl.BlockSpec(block, lambda b, g, i: (b * group + g,)
+                            + (0,) * (len(block) - 1))
 
-    n_q = s_q // block_q
-    static = dict(q_len=q_len, causal=causal, scale=scale, block_q=block_q)
-    stats = (lse.reshape(bh, n_q, 1, block_q),
-             delta.reshape(bh, n_q, 1, block_q))
+    def of_kv_block(width):
+        return pl.BlockSpec((1, block_kv, width), lambda b, g, i: (b, i, 0))
+
+    def of_kv_sum(width):
+        """dk's or dv's output block: with grouped heads the group's last
+        query head writes it; the earlier ones stay on the key/value
+        head's first block, which nothing writes back before then."""
+        if group == 1:
+            return of_kv_block(width)
+        return pl.BlockSpec(
+            (1, block_kv, width),
+            lambda b, g, i: (b, jnp.where(g == group - 1, i, 0), 0))
+
+    scratch = [pltpu.VMEM((d, s_q), jnp.float32)]
     if group > 1:
-        dk, dv = _grouped_dkv(q, k, v, do, stats, group, static, interpret)
-        return dq, dk, dv
-    stat_spec = pl.BlockSpec((1, n_q, 1, block_q), lambda b, i: (b, 0, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **static),
-        grid=(bh, s_k // block_kv),
+        scratch += [pltpu.VMEM((s_k, d), jnp.float32),
+                    pltpu.VMEM((s_k, d_v), jnp.float32)]
+    # what stays in VMEM across grid steps: Q, dO and the dq block whole
+    # (each buffered twice), the float32 sums; 8 MiB more for the key and
+    # value blocks, the row statistics and a tile pair's temporaries. A
+    # call that fits the 16 MiB every Mosaic call gets asks for no more.
+    resident = sum(_held_bytes(*x.shape, x.dtype) for x in scratch) + 2 * (
+        2 * _held_bytes(s_q, d, q.dtype) + _held_bytes(s_q, d_v, q.dtype))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, q_len=q_len, causal=causal,
+                          scale=scale, block_q=block_q),
+        grid=(bkvh, group, s_k // block_kv),
         in_specs=[
-            pl.BlockSpec((1, s_q, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_q, d_v), lambda b, i: (b, 0, 0)),
-            stat_spec, stat_spec,
+            of_query_head(1, s_q, d), of_kv_block(d), of_kv_block(d_v),
+            of_query_head(1, s_q, d_v),
+            of_query_head(1, n_q, 1, block_q),
+            of_query_head(1, n_q, 1, block_q),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d_v), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype),
-        ],
+        out_specs=[of_query_head(1, s_q, d), of_kv_sum(d), of_kv_sum(d_v)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, resident + (8 << 20))),
         interpret=interpret,
-    )(q, k, v, do, *stats)
-    return dq, dk, dv
+    )(q, k, v, do, lse.reshape(bh, n_q, 1, block_q),
+      delta.reshape(bh, n_q, 1, block_q))
 
 
 # ------------------------------------------------------------- public API
@@ -367,9 +340,11 @@ def _flash_vjp_fwd(q, k, v, causal, scale, q_len, kv_len, interpret):
 
 
 def _flash_vjp_bwd(causal, scale, q_len, kv_len, interpret, res, do):
+    from ..jit.to_static import note_structure
+
     q, k, v, out, lse = res
-    return _flash_bwd(q, k, v, out, lse, do, causal, scale, kv_len, q_len,
-                      interpret)
+    note_structure("flash_fused_backwards")
+    return _flash_bwd(q, k, v, out, lse, do, causal, scale, q_len, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

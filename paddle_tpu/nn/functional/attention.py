@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from ...core.dispatch import call_op
 from ...observability.scopes import scope
 
-# The kernel under the gate: three Mosaic kernels (forward, dq, dkv) on
+# The kernel under the gate: two Mosaic kernels (forward; one backward) on
 # 512x512 score tiles (PR 26), q and k of one width and v and the output of
 # another (PR 33: latent attention's 192 and 128; equal widths lower as
 # before), O(S) memory. The gate itself is older than that kernel: the

@@ -379,9 +379,10 @@ def test_flash_kernels_with_values_narrower_than_keys(seq, causal):
 
 def test_equal_widths_lower_to_what_they_lowered_to():
     """The GPT cells' call shape, forward and the three gradients: the
-    traced kernels (grid, block shapes, every operation of the three
-    bodies) are the text recorded before the widths could differ,
-    source lines aside."""
+    traced kernels (grid, block shapes, every operation of the two
+    bodies) are the recorded text, source lines aside: the forward as
+    before the widths could differ, the backward the one kernel PR 36
+    recorded at equal widths and head counts."""
     def loss(q, k, v):
         return fa.flash_attention_bshd(q, k, v, causal=True).astype(
             jnp.float32).sum()

@@ -355,18 +355,21 @@ def test_flash_kernels_with_grouped_query_heads(seq, heads, kv_heads, causal):
 
 
 def test_the_kernels_never_see_k_and_v_at_the_query_heads():
-    """Every operand of the three `pallas_call`s that is a K, a V or one
-    of their gradients has 2 heads' rows, not 8's."""
+    """Every operand of the two `pallas_call`s (the forward, the one
+    backward kernel) that is a K, a V or one of their gradients has 2
+    heads' rows, not 8's."""
     q, k, v, _w = _qkv(256, 8, 2, 64)
     text = str(jax.make_jaxpr(jax.grad(
         lambda *a: fa.flash_attention_bshd(*a, causal=True).sum(),
         argnums=(0, 1, 2)))(q, k, v))
     calls = [line for line in text.splitlines() if "pallas_call[" in line]
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert "repeat" not in text and "broadcast_in_dim[shape=(1, 256, 8" \
         not in text
-    # dkv: float32 sums at 2 heads, its grid's third axis the group
+    # the backward: grid (key/value heads, the group, key blocks), dk and
+    # dv out at 2 heads
     assert any("f32[2,256,64]" in line for line in calls)
+    assert "GridMapping(grid=(2, 4, 1)" in text
 
 
 @pytest.mark.parametrize("mask", [None, "bool"])
@@ -571,7 +574,7 @@ SCOPES = ("conv_in", "short_conv", "conv_out", "q_proj", "k_proj", "v_proj",
           "cast")
 BUILD_COUNTERS = ("jit_short_conv_layers", "jit_gqa_attention_layers",
                   "jit_moe_layers", "jit_moe_experts_held",
-                  "jit_recompute_segments")
+                  "jit_recompute_segments", "jit_flash_fused_backwards")
 
 
 @pytest.fixture(scope="module")
@@ -620,12 +623,13 @@ def test_the_compiled_step_names_its_device_work(compiled):
 
 
 def test_the_compiled_step_counts_its_layers_once(compiled):
-    # four operators, one grouped attention, four expert layers of four
-    # held experts, five layers recomputed; 2 calls x 2 steps
+    # four operators, one grouped attention (its backward the fused
+    # kernel, once, recomputed segment or not), four expert layers of
+    # four held experts, five layers recomputed; 2 calls x 2 steps
     assert compiled["built"] == {
         "jit_short_conv_layers": 4, "jit_gqa_attention_layers": 1,
         "jit_moe_layers": 4, "jit_moe_experts_held": 16,
-        "jit_recompute_segments": 5}
+        "jit_recompute_segments": 5, "jit_flash_fused_backwards": 1}
     routed = compiled["routed"]
     assert routed["moe_steps"] == 4 * 4
     tokens = 2 * 128

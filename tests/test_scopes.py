@@ -318,6 +318,29 @@ def test_flash_path_keeps_its_scope_through_the_custom_vjp(monkeypatch):
     assert "xla" not in kinds_of(step.scope_table())
 
 
+@pytest.mark.parametrize("path,fused", [("flash", 2), ("xla", 0)])
+def test_a_build_counts_its_fused_flash_backwards(monkeypatch, path, fused):
+    """Two attention layers: `jit_flash_fused_backwards` is the number
+    of attention backwards the step's trace staged on the one backward
+    kernel, and nothing where the gate keeps attention on XLA's path."""
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.nn.functional import attention
+
+    monkeypatch.setattr(fa, "is_available", lambda: path == "flash")
+    monkeypatch.setattr(fa, "flash_attention_bshd", functools.partial(
+        fa.flash_attention_bshd, interpret=True))
+    monkeypatch.setattr(attention, "_FLASH_MIN_SEQ", 128)
+    step, *_ = build_step(seq=128, clip=False)
+    before = monitor.stat_get("jit_flash_fused_backwards")
+    ids = paddle.to_tensor(np.random.RandomState(2).randint(
+        0, VOCAB, (K, 1, 128)).astype("int32"))
+    for _call in range(2):  # the second call builds nothing
+        assert np.isfinite(step(ids, ids).numpy()).all()
+    assert monitor.stat_get("jit_flash_fused_backwards") - before == fused
+    assert (path in kinds_of(step.scope_table())) and (
+        {"flash", "xla"} - {path}).isdisjoint(kinds_of(step.scope_table()))
+
+
 def test_zero3_on_four_devices_names_its_collectives():
     from paddle_tpu.distributed import parallel_env
 

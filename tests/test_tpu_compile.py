@@ -88,8 +88,8 @@ def test_flash_kernel_compiles_for_v5e(v5e_chip, shape, dtype, causal,
     text = jax.jit(fn).lower(x, x, x).compile(
         compiler_options={"xla_backend_optimization_level": 3}).as_text()
     # the kernel itself, not an XLA rewrite: forward is one Mosaic call,
-    # backward re-runs it for the residuals then the dq and dkv kernels
-    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    # backward re-runs it for the residuals then the one backward kernel
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -113,7 +113,37 @@ def test_flash_kernel_with_narrower_values_compiles_for_v5e(v5e_chip,
                              sharding=v5e_chip)
     text = jax.jit(fn).lower(qk, qk, v).compile(
         compiler_options={"xla_backend_optimization_level": 3}).as_text()
-    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
+
+
+def test_fused_backward_fits_the_vmem_it_asks_for_at_the_joyai_cells_call(
+        v5e_chip):
+    """The JoyAI cell's own call, all of b4 x 32 heads: Q and dO whole,
+    the float32 `dq` of a query head resident across its key blocks and
+    the bf16 `dq` block beside it need more than the 16 MiB a Mosaic
+    call gets unasked (at b1 the same widths fit, so the case above
+    cannot see it). The kernel states its limit from its operands'
+    shapes: a resident block that outgrows it fails here, not on the
+    chip, and the limit stays far inside a v5e core's 128 MiB."""
+    import re
+
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def loss(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    qk = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16,
+                              sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16,
+                             sharding=v5e_chip)
+    (limit,) = re.findall(r"vmem_limit_bytes=(\d+)",
+                          str(jax.make_jaxpr(grads)(qk, qk, v)))
+    assert 16 * 2 ** 20 < int(limit) <= 32 * 2 ** 20
+    text = jax.jit(grads).lower(qk, qk, v).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= 2
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -121,9 +151,10 @@ def test_flash_kernel_with_grouped_heads_compiles_for_v5e(v5e_chip,
                                                           direction):
     """LFM2's call shape at its cell's size: 32 query heads over 8
     key/value heads, 64 wide (half the lane axis), s 8,192 (a key/value
-    head whole in VMEM beside 512 x 512 score tiles; the dkv kernel's
-    grid over the group's four query heads). K and V enter the kernels
-    at their 8 heads: no operand of a Mosaic call is a 32-head K."""
+    head whole in VMEM beside 512 x 512 score tiles; the backward's
+    grid over the group's four query heads, their float32 dk and dv
+    resident whole). K and V enter the kernels at their 8 heads: no
+    operand of a Mosaic call is a 32-head K."""
     from paddle_tpu.kernels import flash_attention as fa
 
     def fwd(q, k, v):
@@ -139,7 +170,7 @@ def test_flash_kernel_with_grouped_heads_compiles_for_v5e(v5e_chip,
                               sharding=v5e_chip)
     text = jax.jit(fn).lower(q, kv, kv).compile(
         compiler_options={"xla_backend_optimization_level": 3}).as_text()
-    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "= " in line]
     assert calls and all("bf16[32,8192,64]" in line
