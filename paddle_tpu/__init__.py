@@ -6,6 +6,10 @@ with tape autograd, whole-program XLA compilation via @to_static, device-mesh
 parallelism (dp/mp/pp/sharding) through GSPMD + shard_map, bf16-first AMP,
 and pallas kernels for the fused hot ops.
 """
+import time as _time
+
+_import_t0 = _time.perf_counter_ns()  # `import_ns`, at the end of the file
+
 __version__ = "0.1.0"
 
 # core
@@ -103,3 +107,10 @@ def set_grad_enabled(flag):
 def flops(net, input_size, custom_ops=None, print_detail=False):
     from .hapi.model import flops as _flops
     return _flops(net, input_size)
+
+
+# from this file's first statement to here, once a process: what
+# `import paddle_tpu` costs a start (jax's own import too, where this is
+# the first to ask for it)
+monitor.stat_add("import_ns", _time.perf_counter_ns() - _import_t0)
+del _time, _import_t0

@@ -76,6 +76,7 @@ def _bind(lib):
         "pt_flag_get": (I, [CP, CP, I]),
         "pt_flag_list": (I, [CP, I]),
         "pt_stat_add": (None, [CP, LL]),
+        "pt_stat_max": (None, [CP, LL]),
         "pt_stat_get": (LL, [CP]),
         "pt_stat_reset": (None, [CP]),
         "pt_stat_list": (I, [CP, I]),

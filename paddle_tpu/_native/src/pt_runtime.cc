@@ -115,6 +115,15 @@ PT_API void pt_stat_add(const char* name, long long v) {
   stat_cell(name)->fetch_add(v, std::memory_order_relaxed);
 }
 
+// keep the largest value seen (a worst case beside the sums)
+PT_API void pt_stat_max(const char* name, long long v) {
+  std::atomic<long long>* cell = stat_cell(name);
+  long long cur = cell->load(std::memory_order_relaxed);
+  while (cur < v && !cell->compare_exchange_weak(
+                        cur, v, std::memory_order_relaxed)) {
+  }
+}
+
 PT_API long long pt_stat_get(const char* name) {
   return stat_cell(name)->load(std::memory_order_relaxed);
 }

@@ -18,6 +18,8 @@ by fleet meta-optimizers).
 """
 import contextlib
 import functools
+import gc
+import logging
 import time
 import weakref
 
@@ -32,27 +34,50 @@ from ..core.tensor import Tensor
 from ..observability import scopes as _scopes
 from ..observability import tracing as _obs
 from ..testing import faults as _faults
+from . import compile_cache
 
 _is_tracing = False
+_log = logging.getLogger("paddle_tpu.jit")
 
 # the host phases of one step call, in order (`_CallPhases`)
 CALL_PHASES = ("flatten", "snapshot", "place", "key", "launch", "wrap")
 _PHASE_COUNTER = {name: f'to_static_call_ns{{phase="{name}"}}'
                   for name in CALL_PHASES}
+# a cached call's worst case beside its mean
+_PHASE_MAX = {name: f'to_static_call_max_ns{{phase="{name}"}}'
+              for name in ("place", "launch")}
+# the phases of a building call, disjoint and in order: a cached call's
+# without `launch`, which is here in its four parts (jax's own trace of
+# the real program, its lowering, the executable's load or compile, and
+# what is left of `launch`: cache key, transfers, dispatch), after the
+# analysis trace of `_build`
+BUILD_PHASES = ("flatten", "snapshot", "place", "key", "analysis_trace",
+                *compile_cache.JAX_STEPS, "launch_rest", "wrap")
+_BUILD_COUNTER = {name: f'to_static_build_ns{{phase="{name}"}}'
+                  for name in BUILD_PHASES}
+_jax = compile_cache.this_thread
 
 
 class _CallPhases:
     """The phases of one `StaticFunction` call: ``with phases("place"):``
     is a child span of `executor/step` while tracing is on (and so an
     annotation in any profile being captured), and always its
-    nanoseconds; `commit()` adds a call that hit the program cache to
+    nanoseconds. `commit()` adds a call that hit the program cache to
     the always-on counters ``to_static_calls`` and
-    ``to_static_call_ns{phase=}``."""
+    ``to_static_call_ns{phase=}``, and either to
+    ``to_static_calls_recompiled`` and ``to_static_recompiled_launch_ns``
+    (jax made a program under it: it re-specialised below the program
+    cache) or to the maxima ``to_static_call_max_ns{phase=}``.
+    `commit_build()` adds the building call to
+    ``to_static_build_ns{phase=}``, once a build."""
 
-    __slots__ = ("ns",)
+    __slots__ = ("ns", "jax_ns", "jax_steps")
 
     def __init__(self):
         self.ns = {}
+        # the thread's count of jax steps only rises, so a call nested in
+        # this one takes nothing from what this one sees
+        self.jax_steps = _jax.steps
 
     @contextlib.contextmanager
     def __call__(self, name):
@@ -64,9 +89,68 @@ class _CallPhases:
                 self.ns[name] = time.perf_counter_ns() - t0
 
     def commit(self):
+        ns = self.ns
         monitor.stat_add("to_static_calls", 1)
-        for name, ns in self.ns.items():
-            monitor.stat_add(_PHASE_COUNTER[name], ns)
+        for name, counter in _PHASE_COUNTER.items():
+            monitor.stat_add(counter, ns[name])
+        # a call under which jax made a program is no steady call: it is
+        # counted and kept out of the worst case
+        if _jax.steps != self.jax_steps:
+            monitor.stat_add("to_static_calls_recompiled", 1)
+            monitor.stat_add("to_static_recompiled_launch_ns", ns["launch"])
+        else:
+            for name, counter in _PHASE_MAX.items():
+                monitor.stat_max(counter, ns[name])
+        if _host_pauses["during"] != "steady":  # the first cached call
+            _host_pauses["during"] = "steady"
+            # 0 is a reading too: listed from here on
+            monitor.stat_add("to_static_calls_recompiled", 0)
+
+    def commit_build(self):
+        ns = dict(self.ns, **self.jax_ns)
+        ns["launch_rest"] = max(
+            ns.pop("launch") - sum(self.jax_ns.values()), 0)
+        for name, counter in _BUILD_COUNTER.items():
+            monitor.stat_add(counter, ns[name])
+
+
+# -- the collector's pauses -----------------------------------------------
+# One `gc.callbacks` entry from the first build on: every collection's
+# nanoseconds, by whether the first cached call has committed yet, and
+# the longest. A pause of seconds inside a window is otherwise nobody's.
+_GC_WARN_NS = 500_000_000
+_gc_clock = time.perf_counter_ns
+_host_pauses = {"during": "setup", "open": None}
+_GC_COUNTERS = {during: tuple(f'host_gc_{kind}{{during="{during}"}}'
+                              for kind in ("ns", "collections", "max_ns"))
+                for during in ("setup", "steady")}
+
+
+def _on_gc(phase, info):
+    if phase == "start":
+        _host_pauses["open"] = (_obs.begin_span("host/gc", cat="executor"),
+                                _gc_clock())
+        return
+    if _host_pauses["open"] is None:  # watched from inside a collection
+        return
+    span, t0 = _host_pauses["open"]
+    ns = _gc_clock() - t0
+    span.end()
+    _host_pauses["open"] = None
+    total, collections, longest = _GC_COUNTERS[_host_pauses["during"]]
+    monitor.stat_add(total, ns)
+    monitor.stat_add(collections, 1)
+    monitor.stat_max(longest, ns)
+    if ns > _GC_WARN_NS:
+        _log.warning(
+            "host paused %.3f s in one garbage collection (generation %d, "
+            "%d objects collected, during %s)", ns / 1e9,
+            info["generation"], info["collected"], _host_pauses["during"])
+
+
+def _watch_host_pauses():
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 # step hooks: callables run inside every traced step body, after the
@@ -127,6 +211,28 @@ _DATA_DEPENDENT_ERRORS = _data_dependent_errors()
 
 def in_tracing():
     return _is_tracing
+
+
+_capture_depth = [0]
+
+
+@contextlib.contextmanager
+def capture_pass():
+    """A control-flow capture pass (`nn/control_flow.py:_capture`: the
+    body run once to find what it reads): its time while a step trace
+    runs, the outermost pass alone, in ``jit_capture_pass_ns`` and as a
+    ``jit/capture_pass`` span. Every trace of the body pays it."""
+    if not _is_tracing or _capture_depth[0]:
+        yield
+        return
+    _capture_depth[0] += 1
+    t0 = time.perf_counter_ns()
+    try:
+        with _obs.trace_span("jit/capture_pass", cat="jit"):
+            yield
+    finally:
+        _capture_depth[0] -= 1
+        monitor.stat_add("jit_capture_pass_ns", time.perf_counter_ns() - t0)
 
 
 # what the newest trace of a step body staged, by kind: rolled regions'
@@ -391,7 +497,6 @@ class StaticFunction:
         XLA compiler options (``jit.xla_flags``): unknown-flag errors
         degrade to an unflagged recompile with the fallback recorded as
         provenance — see :meth:`xla_flags`."""
-        from . import compile_cache
         from . import xla_flags as _xla_flags_mod
         # the module's name carries the metadata schema, so that a
         # persistent cache never serves this program an executable from
@@ -495,48 +600,64 @@ class StaticFunction:
                    tuple(t._grad is not None for _, t in state_items),
                    mesh is not None)
             entry = self._cache.get(key)
-        hit = entry is not None
-        if not hit:
-            t0 = time.perf_counter_ns()
-            scopes_before = _scopes.entered()
-            with _obs.trace_span("jit/compile", cat="jit",
-                                 fn=getattr(self, "__name__", "fn"),
-                                 cache_size=len(self._cache)):
-                try:
-                    entry = self._build(treedef, leaves, dyn_idx, state_items)
-                except _DATA_DEPENDENT_ERRORS as e:
-                    # data-dependent python control flow: fall back to the AST
-                    # transformation (reference: program_translator.py always
-                    # AST-transforms; here the plain trace is the fast path)
-                    if not self._try_ast_fallback(e):
-                        raise
-                    entry = self._build(treedef, leaves, dyn_idx, state_items)
-            # once a build, so unguarded: the python trace of the body
-            # (the backend compile happens lazily on the first execution;
-            # compile_cache's jax.monitoring mirror counts it into
-            # jit_backend_compile_ns)
-            monitor.stat_add("jit_cache_miss", 1)
-            monitor.stat_add("jit_build_ns", time.perf_counter_ns() - t0)
-            for kind, count in _STRUCTURE.items():
-                monitor.stat_add("jit_" + kind, count)
-            entry[2]["traced_with_scopes"] = (
-                _scopes.entered() > scopes_before)
-            from ..analysis import debug_enabled
-            if debug_enabled():
-                # analysis debug mode: the fresh build's state partition
-                # must be hazard-free before the entry is ever run
-                from ..analysis import VerifyError, errors
-                bad = errors(self.verify())
-                if bad:
-                    raise VerifyError(
-                        bad, context=f"to_static build of "
-                        f"{getattr(self, '__name__', 'fn')!r}")
-            self._cache[key] = entry
-        else:
+        if entry is not None:
             _obs.count("jit_cache_hit", cat="jit")
+            out = self._run(entry, phases, dyn_vals)
+            phases.commit()
+            return out
+        # the building call, counted apart: a program jax makes from
+        # here to the end of this call's launch is the step's
+        with compile_cache.owned_by("step"):
+            entry = self._cache[key] = self._new_entry(
+                phases, treedef, leaves, dyn_idx, state_items)
+            # the analysis trace is over: jax's own steps, which come in
+            # this call's launch, are the build's
+            _jax.build = phases.jax_ns = dict.fromkeys(
+                compile_cache.JAX_STEPS, 0)
+            out = self._run(entry, phases, dyn_vals)
+        phases.commit_build()
+        return out
+
+    def _new_entry(self, phases, treedef, leaves, dyn_idx, state_items):
+        """A program-cache miss: `_build`'s analysis trace of the body
+        (jax's own trace, the lowering and the executable come lazily,
+        in the first `launch`) and the once-a-build counters."""
+        _watch_host_pauses()
+        scopes_before = _scopes.entered()
+        with _obs.trace_span("jit/compile", cat="jit",
+                             fn=getattr(self, "__name__", "fn"),
+                             cache_size=len(self._cache)):
+            t0 = time.perf_counter_ns()
+            try:
+                entry = self._build(treedef, leaves, dyn_idx, state_items)
+            except _DATA_DEPENDENT_ERRORS as e:
+                # data-dependent python control flow: fall back to the AST
+                # transformation (reference: program_translator.py always
+                # AST-transforms; here the plain trace is the fast path)
+                if not self._try_ast_fallback(e):
+                    raise
+                entry = self._build(treedef, leaves, dyn_idx, state_items)
+            phases.ns["analysis_trace"] = time.perf_counter_ns() - t0
+        monitor.stat_add("jit_cache_miss", 1)
+        monitor.stat_add("jit_build_ns", phases.ns["analysis_trace"])
+        for kind, count in _STRUCTURE.items():
+            monitor.stat_add("jit_" + kind, count)
+        entry[2]["traced_with_scopes"] = _scopes.entered() > scopes_before
+        from ..analysis import debug_enabled
+        if debug_enabled():
+            # analysis debug mode: the fresh build's state partition
+            # must be hazard-free before the entry is ever run
+            from ..analysis import VerifyError, errors
+            bad = errors(self.verify())
+            if bad:
+                raise VerifyError(
+                    bad, context=f"to_static build of "
+                    f"{getattr(self, '__name__', 'fn')!r}")
+        return entry
+
+    def _run(self, entry, phases, dyn_vals):
         compiled, out_wrap, aux = entry
         self._last_aux = aux
-
         # chaos seam: an injected RESOURCE_EXHAUSTED here simulates a
         # training-step allocation failure on the exact path a real XLA
         # OOM surfaces (the flight recorder classifies and dumps it)
@@ -544,10 +665,7 @@ class StaticFunction:
         with phases("launch"):
             out_flat = compiled(dyn_vals)
         with phases("wrap"):
-            out = out_wrap(out_flat)
-        if hit:  # the building call is counted apart (jit_build_ns)
-            phases.commit()
-        return out
+            return out_wrap(out_flat)
 
     def _make_aux(self, get_jitted, **meta):
         """Per-entry introspection handle: captures abstract twins of the
@@ -581,7 +699,8 @@ class StaticFunction:
                     "program has not executed yet; run the step once "
                     "before asking for its compiled HLO")
             from ..observability import memory
-            compiled = get_jitted().lower(*ex).compile()
+            with compile_cache.owned_by("introspect"):
+                compiled = get_jitted().lower(*ex).compile()
             hlo = compiled.as_text()
             try:
                 aux["memory"] = memory.program_stats(compiled)
@@ -933,7 +1052,6 @@ class StaticFunction:
         return pure_fn
 
     def _build(self, treedef, template_leaves, dyn_idx, state_items):
-        from . import compile_cache
         compile_cache.ensure_enabled()  # backend is initialized by now
         if self._scan_steps is not None:
             return self._build_scan(treedef, template_leaves, dyn_idx,

@@ -14,6 +14,17 @@ def stat_add(name, value=1):
         _py_stats[name] = _py_stats.get(name, 0) + int(value)
 
 
+def stat_max(name, value):
+    """Keep the largest value seen under ``name``: a worst case beside
+    the sums (``to_static_call_max_ns``, ``host_gc_max_ns``). Listed by
+    :func:`stats` like any counter; :func:`stat_reset` starts it over."""
+    L = _native.lib()
+    if L is not None:
+        L.pt_stat_max(name.encode(), int(value))
+    elif int(value) > _py_stats.get(name, 0):
+        _py_stats[name] = int(value)
+
+
 def stat_get(name):
     L = _native.lib()
     if L is not None:

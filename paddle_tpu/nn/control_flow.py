@@ -90,7 +90,8 @@ def _capture(branch, *args):
         args, is_leaf=lambda x: isinstance(x, Tensor))
     created = {id(a) for a in arg_leaves if isinstance(a, Tensor)}
     cap.mark_created([a for a in arg_leaves if isinstance(a, Tensor)])
-    with dispatch.capture_ops(cap), _suspend_static_hook():
+    from ..jit.to_static import capture_pass
+    with dispatch.capture_ops(cap), _suspend_static_hook(), capture_pass():
         out = branch(*args)
     # a branch may return an external tensor *directly* (no op reads it);
     # it must still become an operand — diff or not — or its value at

@@ -8,9 +8,12 @@ A StepTimer marks step boundaries; over a sliding window it derives
   ``flops_per_step=6 * n_params * tokens_per_step``; pass the per-model
   ``flops_per_token`` override — e.g. ``model.flops_per_token(seq)`` —
   for exact attention-aware accounting),
-- compile-stall fraction: time the window spent building/compiling
-  programs (``jit_build_ns`` + ``executor_compile_ns`` + XLA
-  ``jit_backend_compile_ns``, all maintained by the instrumentation),
+- compile-stall fraction: host time the window spent making programs —
+  a ``to_static`` building call's analysis trace, jax's own trace of
+  the body, the lowering and the executable's load or compile
+  (``to_static_build_ns{phase=}``), the lowering and executable of
+  every program that is not a step's (``jit_program_ns{program=}``),
+  and ``executor_compile_ns``; all always on,
 - data-wait fraction: time the window spent blocked on input
   (``dataloader_wait_ns``).
 
@@ -46,8 +49,11 @@ def peak_bf16_flops(device_kind=None):
             "state one")
     return PEAK_BF16_FLOPS[device_kind]
 
-_COMPILE_COUNTERS = ("jit_build_ns", "executor_compile_ns",
-                     "jit_backend_compile_ns")
+_COMPILE_COUNTERS = (
+    *(f'to_static_build_ns{{phase="{phase}"}}' for phase in
+      ("analysis_trace", "jit_trace", "lower", "executable")),
+    'jit_program_ns{program="eager"}', 'jit_program_ns{program="introspect"}',
+    "executor_compile_ns")
 _WAIT_COUNTER = "dataloader_wait_ns"
 
 

@@ -23,11 +23,12 @@ This module is the unified emission API the runtime instruments against:
   ``dispatch`` (per-op spans through the core.dispatch observer seam) is
   OFF by default even under ``enable()`` — it is sampled, and still the
   only category with per-op cost.
-- a ``jax.monitoring`` listener mirrors XLA compile events (trace time,
-  backend compile wall time) into the span stream — the compile-cache
-  visibility the CUPTI timeline gave the reference's device side. The
-  counters of the same events (``jit_backend_compile_ns``) are always on
-  (``jit/compile_cache.py``).
+- jax's own steps of making a program (trace, lowering, backend compile
+  or cache load) are ``jax/<leaf>`` spans of category ``jit`` — the
+  compile-cache visibility the CUPTI timeline gave the reference's
+  device side. The program's one ``jax.monitoring`` mirror opens them
+  (``jit/compile_cache.py``), beside the always-on counters of the same
+  events; this module listens to nothing.
 - every ``Span`` is also a ``jax.profiler.TraceAnnotation("pt/<name>")``
   for its lifetime: whenever anyone captures a device trace, the
   program's spans are in the same xplane on the same clock
@@ -49,7 +50,8 @@ from jax.profiler import TraceAnnotation
 from .. import monitor, profiler
 from . import flight, runlog
 
-__all__ = ["enable", "disable", "enabled", "trace_span", "current_span",
+__all__ = ["enable", "disable", "enabled", "trace_span", "begin_span",
+           "current_span",
            "count", "now_ns", "epoch_offset_ns", "CATEGORIES",
            "DEFAULT_CATEGORIES",
            "trace_context", "attach_context", "mint_context",
@@ -260,6 +262,10 @@ class Span:
               self.span_id, self.parent_id, self.attrs or None)
         return False
 
+    def end(self):
+        """Close a span opened by :func:`begin_span`."""
+        self.__exit__(None, None, None)
+
 
 class _NullSpan:
     """Shared disabled span — no state, no allocation per use."""
@@ -275,6 +281,9 @@ class _NullSpan:
     def set_attr(self, **kwargs):
         return self
 
+    def end(self):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -286,6 +295,17 @@ def trace_span(name, cat="user", **attrs):
     if cats is None or cat not in cats:
         return NULL_SPAN
     return Span(name, cat, attrs)
+
+
+def begin_span(name, cat="user", **attrs):
+    """Open a span whose end comes in another call: a pair of callbacks
+    (jax's start mark and duration event, the collector's start and
+    stop). The caller keeps the span and ends it with ``span.end()``,
+    on the same thread and in stack order. Wherever one block holds
+    both ends, ``with trace_span(...)`` is the form."""
+    span = trace_span(name, cat, **attrs)
+    span.__enter__()
+    return span
 
 
 def current_span():
@@ -300,42 +320,6 @@ def count(name, value=1, cat=None):
     if cats is None or (cat is not None and cat not in cats):
         return
     monitor.stat_add(name, value)
-
-
-# -- jax compile-cache hook -----------------------------------------------
-
-_jax_hook_installed = [False]
-
-
-def _install_jax_hook():
-    """Mirror jax compile events into the span stream. jax.monitoring has
-    no unregister-one API, so the listener installs once and gates itself
-    on the enabled flag."""
-    if _jax_hook_installed[0]:
-        return
-    try:
-        from jax import monitoring as _jm
-    except Exception:
-        return
-
-    def _on_duration(event, duration, **kwargs):
-        cats = _enabled_cats[0]
-        if cats is None or "jit" not in cats or "compile" not in event:
-            return
-        dur_ns = int(duration * 1e9)
-        end = profiler._now_ns()
-        # e.g. /jax/core/compile/backend_compile_duration -> jax/backend_compile
-        leaf = event.rsplit("/", 1)[-1]
-        if leaf.endswith("_duration"):
-            leaf = leaf[: -len("_duration")]
-        profiler.record_span(f"jax/{leaf}", "jit", end - dur_ns, end)
-
-    _jm.register_event_duration_secs_listener(_on_duration)
-    _jax_hook_installed[0] = True
-    # the counters of the same events (jit_backend_compile_ns, ...) are
-    # always on and live with the compile cache's mirror
-    from ..jit import compile_cache
-    compile_cache._install_event_mirror()
 
 
 # -- sampled op-dispatch observer -----------------------------------------
@@ -399,7 +383,6 @@ def enable(categories=None, dispatch_sample_rate=0.01):
             f"valid: {list(CATEGORIES)}")
     _enabled_cats[0] = cats
     profiler.enable_collection()
-    _install_jax_hook()
     runlog.maybe_start_from_env()   # PADDLE_TPU_RUNLOG_DIR
     flight.maybe_install_from_env()  # PADDLE_TPU_FLIGHT_DIR
     from ..core import dispatch

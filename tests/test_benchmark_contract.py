@@ -5,7 +5,7 @@ changed) names a scope, a counter or an ``op_name`` mark; a renamed one
 makes the metric read ``null`` on the chip and nothing fail here. One
 case a file: a tiny step of each family its ``workloads`` name
 (``BENCHMARK.json``), built through the public API, must still carry
-that name.
+that name. And the reader ``host_stat``'s three answers, a case each.
 """
 import glob
 import json
@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from chipbench.readers import host_stat
 from paddle_tpu import monitor
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.joyai import JoyAIFlashConfig, JoyAIFlashForCausalLM
@@ -43,15 +44,26 @@ def _metric_files():
             data = json.load(f)
         name = os.path.basename(path)[:-len(".json")]
         args = data.get("args", {})
-        if name in families and (data["reader"] == "program_stat" or
-                                 args.get("scopes") or args.get("marks")):
-            out[name] = (args, families[name])
+        if name in families and (
+                data["reader"] in ("program_stat", "host_stat")
+                or args.get("scopes") or args.get("marks")):
+            out[name] = (args, families[name], data["reader"])
     return out
 
 
 METRICS = _metric_files()
-COUNTERS = sorted({a[key] for a, _f in METRICS.values()
-                   for key in ("counter", "per", "times") if key in a})
+COUNTER_KEYS = ("counter", "per", "times", "witness")
+COUNTERS = sorted({a[key] for a, _f, _r in METRICS.values()
+                   for key in COUNTER_KEYS if key in a})
+
+
+def _held_to_its_value(counter):
+    """What it reads, not its change over a fixture's calls: a counter
+    written once a process (the import) or before the calls (the eager
+    programs of building a model), or a largest value seen, which need
+    not rise in a later fixture of this module."""
+    return (counter == "import_ns" or "_max_ns" in counter
+            or 'program="eager"' in counter)
 
 
 def _compiled(model, forward_loss, seq=SEQ):
@@ -71,16 +83,22 @@ def _compiled(model, forward_loss, seq=SEQ):
     step = paddle.jit.to_static(one_step, scan_steps=K)
     ids = paddle.to_tensor(np.random.RandomState(0).randint(
         0, VOCAB, (K, 2, seq)).astype("int32"))
-    before = {c: monitor.stat_get(c) for c in COUNTERS}
-    for _call in range(2):  # the build, then one call the counters keep
+    before = monitor.stats()
+    # the build, a call the counters keep, and a third for the maxima
+    # (jax may make a program under the second: no steady call)
+    for _call in range(3):
         assert np.isfinite(step(ids, ids).numpy()).all()
     table = step.scope_table()
     assert not table["stale"]
+    after = monitor.stats()  # what the program wrote, and nothing a
+    # reading here brought into being
     return {"components": {c for rec in table["instructions"].values()
                            for c in rec["path"].split("/") if c},
             "op_names": re.findall(r'op_name="([^"]*)"', step.hlo_text()),
-            "counted": {c: monitor.stat_get(c) - v
-                        for c, v in before.items()}}
+            "written": set(after),
+            "counted": {c: after.get(c, 0) - (
+                            0 if _held_to_its_value(c) else before.get(c, 0))
+                        for c in COUNTERS}}
 
 
 @pytest.fixture(scope="module")
@@ -146,13 +164,32 @@ def lfm2():
 
 @pytest.mark.parametrize("metric", sorted(METRICS))
 def test_the_program_still_carries_what_the_metric_reads(metric, request):
-    args, families = METRICS[metric]
+    args, families, reader = METRICS[metric]
     for family in families:
         program = request.getfixturevalue(family)
         for name in set(args.get("scopes", ())) - MAY_ROOT_NONE:
             assert name in program["components"], (family, name)
-        for key in ("counter", "per", "times"):
-            if key in args:
+        for key in COUNTER_KEYS:
+            if key not in args:
+                continue
+            if reader == "host_stat" and key == "counter":
+                # 0 is a reading: the program must have written it
+                assert args[key] in program["written"], (family, args[key])
+            else:
                 assert program["counted"][args[key]] > 0, (family, args[key])
         for mark in args.get("marks", ()):
             assert any(mark in n for n in program["op_names"]), (family, mark)
+        if reader == "host_stat":
+            assert host_stat.read(None, args) is not None, family
+
+
+@pytest.mark.parametrize("counted, witnessed, answer", [
+    (3_000_000, 5, 3.0), (0, 5, 0.0), (0, 0, None)],
+    ids=["a-number", "zero-is-a-reading", "a-commit-without-the-counter"])
+def test_host_stat_answers(counted, witnessed, answer):
+    args = {"counter": f"contract_counted_{counted}_{witnessed}",
+            "witness": f"contract_witness_{counted}_{witnessed}",
+            "scale": 1e-6}
+    monitor.stat_add(args["counter"], counted)
+    monitor.stat_add(args["witness"], witnessed)
+    assert host_stat.read(None, args) == answer
