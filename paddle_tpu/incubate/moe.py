@@ -90,7 +90,8 @@ _held_layers = weakref.WeakSet()
 # a layer's counting buffers and the `monitor` stats they are summed into
 _COUNTERS = {"routed_pairs": "moe_routed_pairs", "steps": "moe_steps",
              "load_max": "moe_expert_load_max",
-             "rows_worked": "moe_rows_worked"}
+             "rows_worked": "moe_rows_worked",
+             "rows_folded": "moe_rows_folded"}
 
 
 class HeldExpertsLayer(Layer):
@@ -112,12 +113,14 @@ class HeldExpertsLayer(Layer):
     The routed pairs are worked through by a loop over blocks of sorted
     pair slots whose trip count is read on the device (`parallel.moe.
     held_experts_ffn`): a pass costs what the pairs routed here cost,
-    rounded up to a block. Four non-persistable int32 buffers count
+    rounded up to a block. Five non-persistable int32 buffers count
     inside a compiled step, with no host sync: `routed_pairs`, `steps`
     (applications of the layer), `load_max` (the busiest held expert's
-    pairs, summed over them) and `rows_worked` (the rows of the blocks
+    pairs, summed over them), `rows_worked` (the rows of the blocks
     the loop ran, summed over them: over `routed_pairs` it is the
-    padding the loop pays); `routing_stats()` fetches them."""
+    padding the loop pays) and `rows_folded` (the routed pairs summed
+    into another row of their token before a block's add, summed over
+    them); `routing_stats()` fetches them."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_size=1,
                  ep_rank=0, routed_scaling_factor=1.0, norm_eps=1e-20):
@@ -155,16 +158,16 @@ class HeldExpertsLayer(Layer):
         shape = tuple(unwrap(x).shape)
 
         def _moe(v, rw, wg, wu, wd, bias):
-            y, pairs, load = held_experts_ffn(
+            y, *counts = held_experts_ffn(
                 v.reshape(-1, shape[-1]), rw, bias, wg, wu, wd,
                 top_k=self.top_k, first_expert=self.first_expert,
                 scale=self.scale, norm_eps=self.norm_eps)
             # the counts leave the op as float32 (an op's outputs are
             # floating point); a layer application routes < 2**24 pairs
-            return (y.reshape(shape), pairs.astype(jnp.float32),
-                    load.astype(jnp.float32))
+            return (y.reshape(shape),) + tuple(
+                count.astype(jnp.float32) for count in counts)
 
-        out, pairs, load = call_op(
+        out, pairs, load, folded = call_op(
             _moe, x, self.router_weight, self.w_gate, self.w_up,
             self.w_down, self.e_score_correction_bias,
             op_name="held_experts_ffn")
@@ -172,7 +175,8 @@ class HeldExpertsLayer(Layer):
         slots = int(np.prod(shape[:-1])) * self.top_k
         for name, more in (("routed_pairs", routed),
                            ("load_max", unwrap(load)), ("steps", 1),
-                           ("rows_worked", rows_worked(routed, slots))):
+                           ("rows_worked", rows_worked(routed, slots)),
+                           ("rows_folded", unwrap(folded))):
             setattr(self, name, wrap(unwrap(getattr(self, name))
                                      + jnp.asarray(more, jnp.int32)))
         note_structure("moe_layers")
@@ -183,9 +187,10 @@ class HeldExpertsLayer(Layer):
 def routing_stats():
     """What the live `HeldExpertsLayer`s have counted so far, fetched in
     one `device_get`: {"moe_routed_pairs", "moe_steps",
-    "moe_expert_load_max", "moe_rows_worked"} as python integers, also
-    written to `paddle_tpu.monitor` under those names. Call it between
-    steps, never inside one: it waits for the device."""
+    "moe_expert_load_max", "moe_rows_worked", "moe_rows_folded"} as
+    python integers, also written to `paddle_tpu.monitor` under those
+    names. Call it between steps, never inside one: it waits for the
+    device."""
     from .. import monitor
 
     layers = list(_held_layers)

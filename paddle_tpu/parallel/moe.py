@@ -17,6 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..kernels import put_rows as _put_rows
+
 
 def switch_route(x, gate_w, num_experts, capacity):
     """Top-1 routing. x: [T, D]; gate_w: [D, E].
@@ -168,39 +170,100 @@ def _dispatch(first, block, x, gates, token, expert, starts, ends):
     return tok, alive, rows, chosen, g, here
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _routed_blocks(block, staged, x, gates, w_gate, w_up, w_down, token,
-                   expert, starts, ends):
+def _by_token(first, block, by_token, in_order):
+    """The block of sorted slots from `first` on in token order: where
+    each of its rows lies in the block, and their tokens (`tokens` for a
+    slot past the routed pairs, after every token)."""
+    return (jax.lax.dynamic_slice_in_dim(by_token, first, block),
+            jax.lax.dynamic_slice_in_dim(in_order, first, block))
+
+
+# rows of a routed layer's float32 sums past its tokens: where a block's
+# rows that are summed into another row of their token go
+_SPARE = 8
+
+
+def _sum_rows(tokens, width):
+    """A float32 [tokens, width] sum laid out as [tokens + _SPARE, n, L]:
+    a row whole lane tiles (L = 128) where the width allows, so that one
+    DMA writes it (`kernels.put_rows`)."""
+    lanes = 128 if width % 128 == 0 else width
+    return jnp.zeros((tokens + _SPARE, width // lanes, lanes), jnp.float32)
+
+
+def _add_by_token(acc, rows, tok, repeats):
+    """acc (`_sum_rows`) plus a block's rows [B, n, L] float32, which are
+    in token order with `tok` [B] their tokens (T: a row to leave out),
+    each token's rows summed in float32 into its first and the sums added
+    with indices that are truly unique. A token repeats at most `repeats`
+    times in a block: ceil(log2(repeats)) shifted adds sum its rows. On
+    the chip the sums' rows of acc are gathered, added and written back
+    by one DMA a row (`kernels.put_rows`): XLA:TPU's scatter adds a row
+    at a time, unique indices or not."""
+    tokens, ahead = acc.shape[0] - _SPARE, 1
+    while ahead < repeats:
+        same = jnp.concatenate([tok[ahead:] == tok[:-ahead],
+                                jnp.zeros((ahead,), bool)])
+        later = jnp.concatenate([rows[ahead:],
+                                 jnp.zeros((ahead,) + rows.shape[1:],
+                                           rows.dtype)])
+        rows = rows + jnp.where(same[:, None, None], later, 0)
+        ahead *= 2
+    at = jnp.arange(tok.shape[0])
+    first = (tok < tokens) & ((at == 0) | (tok != jnp.roll(tok, 1)))
+    if _put_rows.is_available() and _put_rows.supports(acc.shape,
+                                                       rows.shape):
+        # a row summed into its token's first, or past the routed pairs,
+        # goes to the spare row `tokens`
+        dest = jnp.where(first, tok, tokens)
+        return _put_rows.put_rows(acc, dest, acc[dest] + rows)
+    # ... to an index of its own, among the spare rows or past them
+    return acc.at[jnp.where(first, tok, tokens + at)].add(
+        rows, unique_indices=True, mode="drop")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed_blocks(block, staged, repeats, x, gates, w_gate, w_up, w_down,
+                   token, expert, starts, ends, by_token, in_order):
     """sum over the routed pairs of gate x expert(row), added by token:
     [T, D] in x's dtype. One loop over the live blocks forward, one
-    backward; nothing of a block is kept between them."""
+    backward; nothing of a block is kept between them. A block's rows are
+    added in token order (`by_token`, `in_order`: `held_experts_ffn`),
+    each token once."""
     from ..observability.scopes import scope
 
     def one_block(i, acc):
         with scope("dispatch"):
-            tok, alive, rows, _chosen, g, here = _dispatch(
+            _tok, _alive, rows, _chosen, g, here = _dispatch(
                 i * block, block, x, gates, token, expert, starts, ends)
         with scope("experts"):
             h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, here))
                  * jax.lax.ragged_dot(rows, w_up, here))
             out = jax.lax.ragged_dot(h, w_down, here)
         with scope("combine"):
-            out = jnp.where(alive, out, 0).astype(jnp.float32)
-            return acc.at[tok].add(out * g)
+            # a row past the routed pairs, whatever the grouped product
+            # left in it, is summed with no routed row and dropped
+            order, order_tok = _by_token(i * block, block, by_token,
+                                         in_order)
+            out = out.reshape(block, -1, acc.shape[2])[order]
+            return _add_by_token(
+                acc, out.astype(jnp.float32) * g[order][:, :, None],
+                order_tok, repeats)
 
     y = jax.lax.fori_loop(0, _live_blocks(block, ends[-1]), one_block,
-                          jnp.zeros(x.shape, jnp.float32))
-    return y.astype(x.dtype)
+                          _sum_rows(*x.shape))
+    return y[:x.shape[0]].reshape(x.shape).astype(x.dtype)
 
 
-def _routed_blocks_fwd(block, staged, *operands):
-    return _routed_blocks(block, staged, *operands), operands
+def _routed_blocks_fwd(block, staged, repeats, *operands):
+    return _routed_blocks(block, staged, repeats, *operands), operands
 
 
-def _routed_blocks_bwd(block, staged, operands, dy):
+def _routed_blocks_bwd(block, staged, repeats, operands, dy):
     from ..observability.scopes import scope
 
-    x, gates, w_gate, w_up, w_down, token, expert, starts, ends = operands
+    (x, gates, w_gate, w_up, w_down, token, expert, starts, ends, by_token,
+     in_order) = operands
     f32 = jnp.float32
     live = _live_blocks(block, ends[-1])
     span = staged * block
@@ -242,7 +305,11 @@ def _routed_blocks_bwd(block, staged, operands, dy):
         with scope("dispatch"):
             dgates = dgates.at[tok].add(jnp.where(chosen, dg, 0))
         with scope("combine"):
-            dx = dx.at[tok].add(jnp.where(alive, d_rows, 0))
+            order, order_tok = _by_token(i * block, block, by_token,
+                                         in_order)
+            dx = _add_by_token(
+                dx, d_rows.reshape(block, -1, dx.shape[2])[order],
+                order_tok, repeats)
             at = (i - base) * block
             put = jax.lax.dynamic_update_slice_in_dim
             staging = (put(s_rows, rows, at, 0), put(s_dy, dy_rows, at, 0),
@@ -270,7 +337,7 @@ def _routed_blocks_bwd(block, staged, operands, dy):
         return (dx, dgates, staging), grads
 
     width, hidden = w_gate.shape[1:]
-    carry = (jnp.zeros(x.shape, f32), jnp.zeros(gates.shape, f32),
+    carry = (_sum_rows(*x.shape), jnp.zeros(gates.shape, f32),
              tuple(jnp.zeros((span, n), x.dtype)
                    for n in (width, width, hidden, hidden, hidden)))
     # the first `staged` blocks' weight gradients start the sums; where
@@ -285,9 +352,10 @@ def _routed_blocks_bwd(block, staged, operands, dy):
 
     (dx, dgates, _staging), grads = jax.lax.fori_loop(
         1, (live + staged - 1) // staged, more, (carry, grads))
+    dx = dx[:x.shape[0]].reshape(x.shape)
     return (dx.astype(x.dtype), dgates.astype(gates.dtype)) + tuple(
         g.astype(w.dtype) for g, w in zip(grads, (w_gate, w_up, w_down))
-    ) + (None,) * 4
+    ) + (None,) * 6
 
 
 _routed_blocks.defvjp(_routed_blocks_fwd, _routed_blocks_bwd)
@@ -311,19 +379,26 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
     expert and worked through in blocks of `_BLOCK` sorted slots by a
     loop whose trip count, ceil(routed / `_BLOCK`), is read on the device
     at run time: a block's rows are gathered, the three products run as
-    grouped products (`jax.lax.ragged_dot`) and the results are added
-    back by token, weighted by g, into a float32 sum. All T * top_k pair
-    slots have a place, so the result is the same whether every token
-    chooses held experts (every block runs) or none does (none runs).
-    The backward is a second loop over the same blocks
+    grouped products (`jax.lax.ragged_dot`) and the results, weighted by
+    g, are added back by token into a float32 sum, each token once: put
+    in token order (a second sort of the slots by block and token, once a
+    call), a token's rows of the block summed in float32, and the sums
+    added at indices that do not repeat; on the chip the sum's rows are
+    gathered, added and written back by one DMA a row
+    (`kernels.put_rows`), where XLA:TPU's scatter adds a row at a time.
+    All T * top_k pair slots have a place, so the result is the same
+    whether every token chooses held experts (every block runs) or none
+    does (none runs). The backward is a second loop over the same blocks
     (`jax.custom_vjp`): it gathers a block's rows again, repeats its
-    first two products and forms the gradients, which meet in float32;
-    no pass keeps anything of a block for another. Where `_BLOCK` does
-    not divide the slots they are one block.
+    first two products and forms the gradients, which meet in float32
+    (dx added by token as the forward adds y); no pass keeps anything of
+    a block for another. Where `_BLOCK` does not divide the slots they
+    are one block.
 
     Returns (y [T, D] in x's dtype, routed pairs, the busiest held
-    expert's pairs), the two counts int32 scalars. In a compiled step
-    the device time goes under the scopes `router`, `dispatch`,
+    expert's pairs, the routed pairs summed into another row of their
+    token before the add), the three counts int32 scalars. In a compiled
+    step the device time goes under the scopes `router`, `dispatch`,
     `experts` and `combine`."""
     from ..observability.scopes import scope
 
@@ -357,9 +432,22 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
                         dtype=jnp.int32)
         ends = jnp.cumsum(sizes)
         starts, routed = ends - sizes, ends[-1]
+        # every block's slots in token order, once for the forward, its
+        # replay and the backward: where each lies in its block, and its
+        # token (`tokens` past the routed pairs, after every token)
+        block = _block_rows(slots)
+        at = jnp.arange(slots, dtype=jnp.int32)
+        token = order // top_k
+        _, in_order, by_token = jax.lax.sort(
+            (at // block, jnp.where(at < routed, token, tokens), at % block),
+            num_keys=2)
+        # the held slots summed into their token's first of the block
+        firsts = (in_order < tokens) & ((at % block == 0) | (
+            in_order != jnp.roll(in_order, 1)))
+        folded = routed - jnp.sum(firsts, dtype=jnp.int32)
 
-    block = _block_rows(slots)
-    y = _routed_blocks(block, _staged_blocks(slots, block, held, experts), x,
-                       gates, w_gate, w_up, w_down, order // top_k,
-                       key + first_expert, starts, ends)
-    return y, routed, jnp.max(sizes)
+    y = _routed_blocks(block, _staged_blocks(slots, block, held, experts),
+                       min(top_k, held), x, gates, w_gate, w_up, w_down,
+                       token, key + first_expert, starts, ends, by_token,
+                       in_order)
+    return y, routed, jnp.max(sizes), folded

@@ -223,7 +223,7 @@ def test_no_pair_is_dropped_whatever_the_routing(kind):
     bias = jnp.asarray({"all_held": np.where(mine, 10.0, 0.0),
                         "none_held": np.where(mine, -10.0, 0.0),
                         "spread": np.asarray(SPREAD)}[kind], jnp.float32)
-    got, pairs, load = jax.jit(lambda: _share(t, bias, 8, 4))()
+    got, pairs, load, _folded = jax.jit(lambda: _share(t, bias, 8, 4))()
     units = _whole_layer(dict(t, gate=t["gate"].at[:8].set(0).at[12:].set(0)),
                          bias, 4, 2.5)  # the other experts' units give 0
     np.testing.assert_allclose(got, units, rtol=2e-5, atol=2e-6)
@@ -247,8 +247,41 @@ def _routing_bias(kind):
         # ... experts 8 and 9: 96 pairs, not a multiple of 36
         "ragged_tail": (np.where((np.arange(16) == 8) | (np.arange(16) == 9),
                                  10.0, np.where(mine, -10.0, 0.0)), 96),
+        # `FEW` tokens choose all four held experts and no other token
+        # any (`_few_choose_every_held`): groups of five, so that a block
+        # of 16 holds three or four groups of the same tokens
+        "few_everywhere": (np.zeros(16), 4 * len(FEW)),
     }[kind]
     return jnp.asarray(bias, jnp.float32), pairs
+
+
+FEW = (2, 11, 23, 30, 47)
+
+
+def _folded_by_numpy(x, router, bias, first, held, top_k, block):
+    """The routed pairs that share a block with an earlier pair of their
+    token, counted from the routing in numpy: held pairs sorted by expert
+    then token, in blocks of `block` slots."""
+    score = 1 / (1 + np.exp(-np.asarray(x, np.float64) @ np.asarray(
+        router, np.float64))) + np.asarray(bias, np.float64)
+    sel = np.argsort(-score, axis=1, kind="stable")[:, :top_k] - first
+    token, expert = np.nonzero((sel[:, :, None] == np.arange(held)).any(1))
+    slot = np.lexsort((token, expert))
+    blocks = np.arange(len(slot)) // block
+    return len(slot) - len(set(zip(blocks, token[slot])))
+
+
+def _few_choose_every_held(t):
+    """x's first coordinate +3 for the tokens `FEW` and -3 for the
+    others, and held experts 8..11 scored by it alone, 8 times over:
+    their scores are sigmoid(+-24), above or below every other's (the
+    others' router columns scaled to width 32's spread)."""
+    x0 = np.where(np.isin(np.arange(t["x"].shape[0]), FEW), 3.0, -3.0)
+    held = np.zeros(t["router"].shape, np.float32)
+    held[0, 8:12] = 8.0
+    router = np.where(np.arange(16) // 4 == 2, held, t["router"] * np.sqrt(
+        32 / t["x"].shape[1]))
+    return dict(t, x=t["x"].at[:, 0].set(x0), router=jnp.asarray(router))
 
 
 # (block, staged blocks): the 192 slots as 12 blocks of which the
@@ -260,12 +293,35 @@ BLOCKINGS = {"b16x2": (16, 2), "b48x16": (48, 16), "b36_one": (36, 16)}
 
 @pytest.mark.parametrize("blocking", list(BLOCKINGS))
 @pytest.mark.parametrize("kind", ["all_held", "none_held", "spread",
-                                  "one_block", "ragged_tail"])
+                                  "one_block", "ragged_tail",
+                                  "few_everywhere"])
 def test_the_loops_gradients_are_the_dense_formulations(kind, blocking,
                                                         monkeypatch):
     """x, the router and the three weights: the hand-written backward
     over a run-time number of blocks against `jax.grad` of every token
-    through every held expert."""
+    through every held expert, where a block's rows repeat tokens too
+    (`all_held` as one block holds every token four times;
+    `few_everywhere` in blocks of 16 three or four times)."""
+    _loop_against_dense(kind, blocking, monkeypatch)
+
+
+@pytest.mark.parametrize("kind,blocking", [
+    ("all_held", "b36_one"), ("few_everywhere", "b16x2"),
+    ("spread", "b48x16"), ("ragged_tail", "b16x2")])
+def test_the_chip_s_row_kernel_adds_as_the_dense_formulations(
+        kind, blocking, monkeypatch):
+    """The same at width 128, where a row is a whole lane tile, on the
+    chip's branch of the combine: the sums' rows gathered, added and
+    written back by `kernels.put_rows` (interpreted)."""
+    from paddle_tpu.kernels import put_rows
+
+    monkeypatch.setattr(put_rows, "is_available", lambda: True)
+    monkeypatch.setattr(put_rows, "put_rows", functools.partial(
+        put_rows.put_rows, interpret=True))
+    _loop_against_dense(kind, blocking, monkeypatch, width=128)
+
+
+def _loop_against_dense(kind, blocking, monkeypatch, width=32):
     block, staged = BLOCKINGS[blocking]
     monkeypatch.setattr(pmoe, "_BLOCK", block)
     monkeypatch.setattr(pmoe, "_STAGED", staged)
@@ -280,28 +336,36 @@ def test_the_loops_gradients_are_the_dense_formulations(kind, blocking,
         return jnp.where(past[:, None], jnp.nan, out)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", unwritten_past_the_groups)
-    t = _layer_inputs()
+    t = _layer_inputs(width=width)
+    if kind == "few_everywhere":
+        t = _few_choose_every_held(t)
     bias, pairs = _routing_bias(kind)
     weight = jnp.asarray(np.random.default_rng(9).normal(size=t["x"].shape),
                          jnp.float32)
     keys = ("x", "router", "gate", "up", "down")
 
     def loop(*leaves):
-        y, routed, _load = _share(dict(zip(keys, leaves)), bias, 8, 4)
-        return jnp.sum(y * weight), routed
+        y, routed, _load, folded = _share(dict(zip(keys, leaves)), bias,
+                                          8, 4)
+        return jnp.sum(y * weight), (routed, folded)
 
     def dense(*leaves):
         return jnp.sum(_whole_layer(dict(zip(keys, leaves)), bias, 4, 2.5,
                                     experts=range(8, 12)) * weight)
 
     leaves = [t[k] for k in keys]
-    (value, routed), got = jax.jit(jax.value_and_grad(
+    (value, (routed, folded)), got = jax.jit(jax.value_and_grad(
         loop, argnums=range(5), has_aux=True))(*leaves)
     want_value, want = jax.value_and_grad(dense, argnums=range(5))(*leaves)
     if pairs is not None:
         assert int(routed) == pairs
     one = 192 if 192 % block else block
     assert int(pmoe.rows_worked(routed, 192)) == one * -(-int(routed) // one)
+    assert int(folded) == _folded_by_numpy(t["x"], t["router"], bias, 8, 4,
+                                           4, one)
+    if (kind, blocking) in (("all_held", "b36_one"),
+                            ("few_everywhere", "b16x2")):
+        assert int(folded) == {"all_held": 144, "few_everywhere": 11}[kind]
     np.testing.assert_allclose(value, want_value, rtol=2e-5, atol=2e-5)
     for key, g, w in zip(keys, got, want):
         np.testing.assert_allclose(
@@ -338,6 +402,24 @@ def test_the_layer_counts_on_the_device_and_routing_stats_fetches(
     assert worked == 3 * one * -(-(pairs // 3) // one)
     assert worked == int(layer.rows_worked.numpy())
     assert monitor.stat_get("moe_rows_worked") == after["moe_rows_worked"]
+    # the pairs summed into another row of their token before a block's
+    # add, as numpy counts them from the same routing; then every token
+    # routed to the four held experts (one block: 192 rows of 48
+    # tokens) and none
+    folded = after["moe_rows_folded"] - before["moe_rows_folded"]
+    flat, router = x.numpy().reshape(48, 32), layer.router_weight.numpy()
+    assert folded == 3 * _folded_by_numpy(flat, router, np.zeros(16), 8, 4,
+                                          4, one)
+    assert folded == int(layer.rows_folded.numpy())
+    assert monitor.stat_get("moe_rows_folded") == after["moe_rows_folded"]
+    for bias, want in ((10.0, {16: 0, 1024: 144}[block]), (-10.0, 0)):
+        held = np.where(np.arange(16) // 4 == 2, bias, 0.0)
+        layer.e_score_correction_bias = paddle.to_tensor(
+            held.astype("float32"))
+        start = moe_mod.routing_stats()["moe_rows_folded"]
+        layer(x)
+        assert want == _folded_by_numpy(flat, router, held, 8, 4, 4, one)
+        assert moe_mod.routing_stats()["moe_rows_folded"] - start == want
     with pytest.raises(ValueError, match="ep_size"):
         moe_mod.HeldExpertsLayer(32, 24, 16, 4, ep_size=3)
 

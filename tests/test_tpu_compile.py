@@ -260,7 +260,11 @@ def held_experts_program(v5e_chip):
         loss.backward()
         return loss
 
+    from paddle_tpu.kernels import put_rows
+
     with pytest.MonkeyPatch.context() as patch:
+        # the chip's branch of the combine's gate
+        patch.setattr(put_rows, "is_available", lambda: True)
         compiled = _stage_for_v5e(fwd_bwd, (), v5e_chip, patch)
     return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
 
@@ -301,37 +305,59 @@ def test_held_experts_device_time_keeps_its_names_for_v5e(
     """What keeps `moe.experts_roofline` and `moe.route_share` honest:
     every grouped product, forward, replayed and backward, is either
     called `ragged-dot-none*` (the reader takes those by name) or
-    carries the scope `experts`; the row gathers carry `dispatch` and
-    the scatter-adds `combine` or, the gates' gradient, `dispatch`,
-    in the backward (`transpose(` in the name) as in the forward."""
+    carries the scope `experts`; the row gathers carry `dispatch`, or
+    `combine` where they put a block's results in token order or take
+    the sum's rows they add to, and the gates' gradient's scatter-add
+    `dispatch`, in the backward (`transpose(` in the name) as in the
+    forward. The sums y and dx take their rows by the Mosaic kernel
+    `put_rows` under `combine`, one call a block each way: no scatter
+    of [*, 2048] rows is left in the block loops."""
     import re
 
     text, _temp = held_experts_program
     products = _grouped_products(text)
     assert all(name.startswith("ragged-dot-none") or "pt.experts" in op
                for name, op in products), products
-    ops = re.findall(r'op_name="([^"]*/(?:gather|scatter-add))"', text)
-    loops = [op for op in ops if "/while/body/" in op]
-    assert loops and all("pt.held_experts_ffn" in op for op in loops)
+    found = [(line, re.search(r'op_name="([^"]*)"', line).group(1))
+             for line in text.splitlines()
+             if re.search(r'op_name="[^"]*/(?:gather|scatter-add)"', line)
+             or "tpu_custom_call" in line and 'op_name="' in line]
+    loops = [(line, op) for line, op in found if "/while/body/" in op]
+    assert loops and all("pt.held_experts_ffn" in op for _line, op in loops)
     for backward in (False, True):
-        mine = [op for op in loops if ("transpose(" in op) == backward]
-        gathers = [op for op in mine if op.endswith("/gather")]
-        scatters = [op for op in mine if op.endswith("/scatter-add")]
-        assert gathers and all("pt.dispatch/" in op for op in gathers), mine
-        assert any("pt.combine/" in op for op in scatters), mine
-        assert all("pt.combine/" in op or "pt.dispatch/" in op
-                   for op in scatters), mine
+        mine = [(line, op) for line, op in loops
+                if ("transpose(" in op) == backward]
+        gathers = [op for _line, op in mine if op.endswith("/gather")]
+        assert any("pt.dispatch/" in op for op in gathers), mine
+        assert any("pt.combine/" in op for op in gathers), mine
+        assert all("pt.dispatch/" in op or "pt.combine/" in op
+                   for op in gathers), mine
+        kernels = [op for line, op in mine if "tpu_custom_call" in line]
+        assert kernels and all("pt.combine/" in op for op in kernels), mine
+        # the scatters themselves, not the fusions named after them
+        scatters = [(line, op) for line, op in mine
+                    if op.endswith("/scatter-add") and " scatter(" in line]
+        assert not [op for line, op in scatters
+                    if ",2048]" in line.split("=")[1]], scatters
+        assert all("pt.dispatch/" in op for _line, op in scatters), scatters
+        assert bool(scatters) == backward, scatters
 
 
 # `held_experts_program`'s temporaries at the parent commit (00c0e96:
 # four chunks of 32,768 slots under `lax.cond`, each a remat region)
-PARENT_HELD_EXPERTS_TEMP_BYTES = 2_130_259_456  # the loop: 1,222,008,832
+PARENT_HELD_EXPERTS_TEMP_BYTES = 2_130_259_456
+# ... and of the block loop whose scatter-adds took repeating indices
+BLOCK_LOOP_TEMP_BYTES = 1_222_008_832
 
 
 def test_held_experts_layer_keeps_less_than_the_chunked_loop_for_v5e(
         held_experts_program):
+    """The token order of every block (int32 [T * top_k] twice a layer
+    application) and a block's rows put in it add a few MB to the
+    loop's temporaries, not a second [T, D] sum."""
     _text, temp = held_experts_program
     assert temp < PARENT_HELD_EXPERTS_TEMP_BYTES
+    assert temp <= BLOCK_LOOP_TEMP_BYTES + 64 * 2 ** 20
 
 
 def test_decoder_block_evaluates_gelus_erfc_once_for_v5e(v5e_chip,
