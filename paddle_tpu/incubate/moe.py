@@ -1,8 +1,8 @@
 """Dygraph MoE layers over parallel.moe: `MoELayer` (name-compatible with
 the later reference releases' paddle.incubate.distributed.models.moe.
 MoELayer; this snapshot has no MoE), Switch top-1 routing with a capacity;
-and `HeldExpertsLayer`, sigmoid-scored top-k routing over a whole expert
-set of which this device holds a contiguous share, no pair dropped, with
+and `HeldExpertsLayer`, sigmoid- or softmax-scored top-k routing over a whole
+expert set of which this device holds a contiguous share, no pair dropped, with
 `routing_stats()` for what its layers counted on the device."""
 import weakref
 import zlib
@@ -110,6 +110,10 @@ class HeldExpertsLayer(Layer):
     selection and nothing else, and no rule moves it here (`Layer.to`
     casts it with the rest; the scores it joins are float32).
 
+    With `scoring="softmax"` (Qwen3-Next) the scores are a softmax over
+    all `num_experts` and there is no selection bias: the gates are the
+    chosen scores over their sum (pass `norm_eps=0`), scaled.
+
     The routed pairs are worked through by a loop over blocks of sorted
     pair slots whose trip count is read on the device (`parallel.moe.
     held_experts_ffn`): a pass costs what the pairs routed here cost,
@@ -123,7 +127,8 @@ class HeldExpertsLayer(Layer):
     them); `routing_stats()` fetches them."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, ep_size=1,
-                 ep_rank=0, routed_scaling_factor=1.0, norm_eps=1e-20):
+                 ep_rank=0, routed_scaling_factor=1.0, norm_eps=1e-20,
+                 scoring="sigmoid"):
         super().__init__()
         if num_experts % ep_size or not 0 <= ep_rank < ep_size:
             raise ValueError(
@@ -134,6 +139,9 @@ class HeldExpertsLayer(Layer):
         self.first_expert = ep_rank * held
         self.scale = float(routed_scaling_factor)
         self.norm_eps = float(norm_eps)
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown scoring {scoring!r}")
+        self.scoring = scoring
         from ..nn.initializer import Normal
 
         normal = Normal(0.0, 0.02)
@@ -145,8 +153,10 @@ class HeldExpertsLayer(Layer):
             [held, d_model, d_hidden], default_initializer=normal)
         self.w_down = self.create_parameter(
             [held, d_hidden, d_model], default_initializer=normal)
-        self.register_buffer("e_score_correction_bias",
-                             wrap(jnp.zeros((num_experts,), jnp.float32)))
+        if scoring == "sigmoid":
+            self.register_buffer(
+                "e_score_correction_bias",
+                wrap(jnp.zeros((num_experts,), jnp.float32)))
         for name in _COUNTERS:
             self.register_buffer(name, wrap(jnp.zeros((), jnp.int32)),
                                  persistable=False)
@@ -157,20 +167,22 @@ class HeldExpertsLayer(Layer):
 
         shape = tuple(unwrap(x).shape)
 
-        def _moe(v, rw, wg, wu, wd, bias):
+        def _moe(v, rw, wg, wu, wd, *bias):
             y, *counts = held_experts_ffn(
-                v.reshape(-1, shape[-1]), rw, bias, wg, wu, wd,
-                top_k=self.top_k, first_expert=self.first_expert,
-                scale=self.scale, norm_eps=self.norm_eps)
+                v.reshape(-1, shape[-1]), rw, bias[0] if bias else None,
+                wg, wu, wd, top_k=self.top_k, first_expert=self.first_expert,
+                scale=self.scale, norm_eps=self.norm_eps,
+                scoring=self.scoring)
             # the counts leave the op as float32 (an op's outputs are
             # floating point); a layer application routes < 2**24 pairs
             return (y.reshape(shape),) + tuple(
                 count.astype(jnp.float32) for count in counts)
 
+        bias = (self.e_score_correction_bias,) if self.scoring == "sigmoid" \
+            else ()
         out, pairs, load, folded = call_op(
             _moe, x, self.router_weight, self.w_gate, self.w_up,
-            self.w_down, self.e_score_correction_bias,
-            op_name="held_experts_ffn")
+            self.w_down, *bias, op_name="held_experts_ffn")
         routed = unwrap(pairs).astype(jnp.int32)
         slots = int(np.prod(shape[:-1])) * self.top_k
         for name, more in (("routed_pairs", routed),

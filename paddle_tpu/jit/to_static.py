@@ -238,13 +238,15 @@ def capture_pass():
 # what the newest trace of a step body staged, by kind: rolled regions'
 # trip counts, remat segments, activation factors saved for the backward
 # (`F.gelu`'s erfc), ZeRO buckets exchanged in their gradients' 16-bit type,
-# held-expert layers and their experts, short-convolution and grouped-query
-# attention layers, attention backwards on the fused flash kernel. Every trace
-# of a build's boundary step stages the same: each restarts `jit_<kind>`.
+# held-expert layers and their experts, short-convolution, Gated DeltaNet and
+# grouped-query attention layers, attention backwards on the fused flash
+# kernel. Every trace of a build's boundary step stages the same: each
+# restarts `jit_<kind>`.
 _STRUCTURE = dict.fromkeys((
     "rolled_loop_trips", "recompute_segments", "saved_activation_factors",
     "zero_exchanged_buckets", "moe_layers", "moe_experts_held",
-    "short_conv_layers", "gqa_attention_layers", "flash_fused_backwards"), 0)
+    "short_conv_layers", "gdn_layers", "gqa_attention_layers",
+    "flash_fused_backwards"), 0)
 
 
 def note_structure(kind, count=1):

@@ -17,6 +17,9 @@ from .joyai import (  # noqa: F401
 from .lfm2 import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeModel, Lfm2MoeForCausalLM,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig, Qwen3NextModel, Qwen3NextForCausalLM,
+)
 from .ctr import (  # noqa: F401
     WideAndDeep, synthetic_ctr_batches, build_ctr_scan_step,
     train_ctr_windows,

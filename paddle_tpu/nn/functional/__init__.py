@@ -29,7 +29,10 @@ from .loss import (  # noqa: F401
 )
 from .attention import scaled_dot_product_attention  # noqa: F401
 from .rotary import rotary_embedding  # noqa: F401
-from .short_conv import gated_short_conv  # noqa: F401
+from .short_conv import gated_short_conv, causal_conv_silu  # noqa: F401
+from .delta_rule import (  # noqa: F401
+    gated_delta_rule, chunked_delta_rule, gated_rms_norm,
+)
 from .vision import (  # noqa: F401
     affine_grid, grid_sample, temporal_shift, channel_shuffle,
     shuffle_channel, space_to_depth, affine_channel, local_response_norm,

@@ -362,15 +362,18 @@ _routed_blocks.defvjp(_routed_blocks_fwd, _routed_blocks_bwd)
 
 
 def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
-                     top_k, first_expert, scale, norm_eps=1e-20):
-    """Sigmoid-scored top-k routing over ALL experts and the part of the
-    result that the experts held here give, with no pair dropped.
+                     top_k, first_expert, scale, norm_eps=1e-20,
+                     scoring="sigmoid"):
+    """Top-k routing over ALL experts and the part of the result that the
+    experts held here give, with no pair dropped.
 
     x [T, D]; router_w [D, E] over the whole expert set; select_bias [E]
-    joins the scores for the selection only; w_gate / w_up [H, D, F] and
-    w_down [H, F, D] are experts `first_expert .. first_expert + H - 1`,
-    gated SiLU units. With s = sigmoid(x router_w) in float32 and `sel`
-    the top_k of s + select_bias (ties to the lower index),
+    (or None: zeros) joins the scores for the selection only; w_gate /
+    w_up [H, D, F] and w_down [H, F, D] are experts `first_expert ..
+    first_expert + H - 1`, gated SiLU units. With s = sigmoid(x router_w)
+    (`scoring="sigmoid"`) or softmax(x router_w) over all E experts
+    (`"softmax"`), in float32, and `sel` the top_k of s + select_bias
+    (ties to the lower index),
 
         g_i = scale * s_i / (sum_{j in sel} s_j + norm_eps)   i in sel
         y   = sum_{i in sel, i held here} g_i E_i(x)
@@ -406,11 +409,14 @@ def held_experts_ffn(x, router_w, select_bias, w_gate, w_up, w_down, *,
     held = w_gate.shape[0]
     slots = tokens * top_k
 
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown scoring {scoring!r}")
     with scope("router"):
-        s = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, sel = jax.lax.top_k(s + select_bias.astype(jnp.float32), top_k)
+        s = (jax.nn.sigmoid if scoring == "sigmoid" else jax.nn.softmax)(
+            jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+        _, sel = jax.lax.top_k(s if select_bias is None else
+                               s + select_bias.astype(jnp.float32), top_k)
         # the gates as a dense [T, E] array, 0 where an expert was not
         # chosen: elementwise work, where picking T * top_k scores out
         # and putting their gradients back are scalar gathers and

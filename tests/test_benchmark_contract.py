@@ -22,6 +22,8 @@ from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.joyai import JoyAIFlashConfig, JoyAIFlashForCausalLM
 from paddle_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
 from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                          Qwen3NextForCausalLM)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB, SEQ, K = 128, 16, 2
@@ -159,6 +161,19 @@ def lfm2():
         layer_types=["conv", "full_attention", "conv"],
         num_attention_heads=4, num_key_value_heads=2, num_dense_layers=1,
         num_experts=8, num_experts_per_tok=2, ep_size=2, ep_rank=1,
+        max_position_embeddings=128)).enable_layer_recompute("kernels"))
+
+
+@pytest.fixture(scope="module")
+def qwen3():
+    paddle.seed(7)
+    return _compiled_at_the_flash_gate(Qwen3NextForCausalLM(Qwen3NextConfig(
+        vocab_size=VOCAB, hidden_size=32, moe_intermediate_size=24,
+        shared_expert_intermediate_size=24, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8, num_experts=8,
+        num_experts_per_tok=2, ep_size=2, ep_rank=1,
         max_position_embeddings=128)).enable_layer_recompute("kernels"))
 
 

@@ -178,6 +178,56 @@ def test_flash_kernel_with_grouped_heads_compiles_for_v5e(v5e_chip,
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kernel_with_wide_grouped_heads_compiles_for_v5e(v5e_chip,
+                                                               direction):
+    """Qwen3-Next's call at its cell's size: 16 query heads over 2
+    key/value heads, 256 wide, b4 x 8,192 (the backward's float32 dk
+    and dv of a key/value head resident across its group of eight)."""
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def fwd(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    q = jax.ShapeDtypeStruct((4, 8192, 16, 256), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((4, 8192, 2, 256), jnp.bfloat16,
+                              sharding=v5e_chip)
+    text = jax.jit(fn).lower(q, kv, kv).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
+
+
+def test_the_delta_rule_compiles_for_v5e_inside_its_room(v5e_chip):
+    """Qwen3-Next's Gated DeltaNet rule as its cell calls it, forward and
+    backward: b4 x 8,192, q and k at 16 key heads and v at 32 value
+    heads of 128, bf16, q and k normalized inside the sweep. Each sweep
+    forms a chunk's part a step, so what the pair needs beside its
+    operands stays under 2 GiB (the whole-sequence form took 6.5 GB: the
+    state, the optimizer's and the rest of a layer did not fit beside
+    it)."""
+    from paddle_tpu.nn.functional import delta_rule
+
+    def loss(*args):
+        return delta_rule.chunked_delta_rule(*args).astype(
+            jnp.float32).sum()
+
+    keys = jax.ShapeDtypeStruct((4, 8192, 16, 128), jnp.bfloat16,
+                                sharding=v5e_chip)
+    values = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16,
+                                  sharding=v5e_chip)
+    gate = jax.ShapeDtypeStruct((4, 8192, 32), jnp.float32,
+                                sharding=v5e_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        keys, keys, values, gate, gate).compile(
+        compiler_options={"xla_backend_optimization_level": 3})
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_short_conv_kernel_compiles_for_v5e(v5e_chip, direction):
     """LFM2's operator at its cell's size: u [4, 8192, 3 x 2048] bf16,
     3 taps; 128 positions and all 6,144 channels a grid step, the
@@ -196,6 +246,27 @@ def test_short_conv_kernel_compiles_for_v5e(v5e_chip, direction):
     else:
         fn = lambda u, w, d: jax.vjp(kernel.short_conv, u, w)[1](d)  # noqa: E731
         args = (u, taps, dout)
+    text = jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_backend_optimization_level": 3}).as_text()
+    assert text.count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_silu_short_conv_kernel_compiles_for_v5e(v5e_chip, direction):
+    """Gated DeltaNet's convolution at the Qwen3-Next cell's size: x [4,
+    8192, 8192] bf16 (q, k and v side by side), 4 taps, through the SiLU
+    form of the same kernels: inside the VMEM a kernel may take."""
+    from paddle_tpu.kernels import short_conv as kernel
+
+    x = jax.ShapeDtypeStruct((4, 8192, 8192), jnp.bfloat16,
+                             sharding=v5e_chip)
+    taps = jax.ShapeDtypeStruct((8192, 4), jnp.bfloat16, sharding=v5e_chip)
+    assert kernel.supports(x.shape, taps.shape)
+    if direction == "fwd":
+        fn, args = kernel.silu_conv, (x, taps)
+    else:
+        fn = lambda x, w, d: jax.vjp(kernel.silu_conv, x, w)[1](d)  # noqa: E731
+        args = (x, taps, x)
     text = jax.jit(fn).lower(*args).compile(
         compiler_options={"xla_backend_optimization_level": 3}).as_text()
     assert text.count("tpu_custom_call") >= 1
