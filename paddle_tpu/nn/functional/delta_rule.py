@@ -14,7 +14,7 @@ normalized (q / (|q| sqrt(d_k)), k / |k|), G the cumulative sum of g
 from the chunk's start (float32), Gamma_ij = exp(G_i - G_j) for j <= i
 and 0 above, and K_b = diag(beta) K,
 
-    T   = (I + strictly_lower(K_b K^T * Gamma))^-1       unit lower
+    A   = strictly_lower(K_b K^T * Gamma)     T = (I + A)^-1  (unit lower)
     U   = T diag(beta) V          W = T (K_b * exp(G))
     P   = lower(Q K^T * Gamma)    Q_e = Q * exp(G)    K_d = K * exp(G_C - G)
 
@@ -25,25 +25,39 @@ sequence's start):
     O   = Q_e S + P V'
     S  <- exp(G_C) S + K_d^T V'
 
-The forward and the backward are one `jax.custom_vjp`, each a sweep over
-the chunks whose step forms its chunk's within-chunk part (T by a
-triangular solve), every batch row and head at once: the forward keeps
-the state entering each chunk, in float32, and nothing else it formed
-(at 4 x 8,192 tokens and 32 heads of 128: 1 GB); the backward
-sweeps back with the gradient of the state, forms the chunk's part
-again and takes the chunk's gradients through its vjp. No array a chunk
-long is kept for every chunk (at 4 x 8,192 tokens and 32 heads of 128
-those would be 0.5 GB each). The decays are float32 products and never
-enter a matrix product.
+T is formed by matrix products, by block doubling: from T = I (every
+1 x 1 diagonal block inverted), each of ceil(log2(C)) levels joins
+adjacent s-blocks into 2s-blocks by T <- T - T M T, M being A on the
+lower-left s x s quadrant of each 2s diagonal block (exact: T is block
+diagonal, so (T M)^2 = 0). Every intermediate T is the inverse of
+principal blocks of (I + A), so no entry grows past the final T's, as
+the powers of A in a Neumann series would. A chunk that is no power of
+two needs no padding: each level's last block is cut short at C, and
+is inverted all the same. The derivative is dT = -T dA T (a
+`jax.custom_jvp`), so the backward takes two products and does not
+replay the doubling. The inverse and its application run at
+`Precision.HIGHEST`, float32 accuracy: one bfloat16 pass there would
+be another result.
 
-The matrix products take their operands as they are (float32 here) at
-the backend's default precision: on the TPU one bfloat16 pass with a
-float32 sum, as the flash kernel and `experts` multiply.
+The forward and the backward are one `jax.custom_vjp`, each a sweep over
+the chunks whose step forms its chunk's within-chunk part, every batch
+row and head at once: the forward keeps the state entering each chunk,
+in float32, and nothing else it formed (at 4 x 8,192 tokens and 32
+heads of 128: 1 GB); the backward sweeps back with the gradient of the
+state, forms the chunk's part again and takes the chunk's gradients
+through its vjp. No array a chunk long is kept for every chunk (at 4 x
+8,192 tokens and 32 heads of 128 those would be 0.5 GB each). The
+decays are float32 products and never enter a matrix product.
+
+The other matrix products take their operands as they are (float32
+here) at the backend's default precision: on the TPU one bfloat16 pass
+with a float32 sum, as the flash kernel and `experts` multiply.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.dispatch import call_op
 
@@ -52,6 +66,42 @@ CHUNK = 64
 
 def _mm(spec, a, b):
     return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _mm_highest(spec, a, b):
+    return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.custom_jvp
+def _unit_lower_inverse(a):
+    """(I + a)^-1 of a strictly lower a [..., C, C] by block doubling
+    at `Precision.HIGHEST` (the module's docstring), any C."""
+    chunk = a.shape[-1]
+    rows, cols = np.arange(chunk)[:, None], np.arange(chunk)[None, :]
+
+    def quadrant(half):
+        """A on the lower-left `half` x `half` quadrant of each diagonal
+        block 2 `half` wide, 0 elsewhere."""
+        return jnp.where((rows // (2 * half) == cols // (2 * half))
+                         & (rows % (2 * half) >= half)
+                         & (cols % (2 * half) < half), a, 0.0)
+
+    # the first level's T is I, so its T - T M T is I - M
+    t = jnp.eye(chunk, dtype=a.dtype) - quadrant(1)
+    half = 2
+    while half < chunk:
+        tm = _mm_highest("...ij,...jk->...ik", t, quadrant(half))
+        t = t - _mm_highest("...ij,...jk->...ik", tm, t)
+        half *= 2
+    return t
+
+
+@_unit_lower_inverse.defjvp
+def _unit_lower_inverse_jvp(primals, tangents):
+    (a,), (da,) = primals, tangents
+    t = _unit_lower_inverse(a)
+    return t, -_mm_highest("...ij,...jk->...ik", t,
+                           _mm_highest("...ij,...jk->...ik", da, t))
 
 
 def _masks(chunk):
@@ -69,7 +119,9 @@ def _unit(x):
 def _within(q, k, v, g, beta):
     """One chunk's within-chunk part, float32: q, k [B, H_k, C, d_k]
     (normalized here), v [B, H, C, d_v], g, beta [B, H, C] -> (U, W,
-    Q_e, P, K_d, exp(G_C)), value head i reading key head i // (H / H_k)."""
+    Q_e, P, K_d, exp(G_C)), value head i reading key head i // (H / H_k).
+    T = (I + A)^-1 by `_unit_lower_inverse`'s products and applied by
+    one more, all at `Precision.HIGHEST`; C need not be a power of two."""
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     q, k = _unit(q) * q.shape[-1] ** -0.5, _unit(k)
     group = v.shape[1] // q.shape[1]
@@ -82,11 +134,9 @@ def _within(q, k, v, g, beta):
     gamma = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
     kb = k * beta[..., None]
     a = jnp.where(strict, _mm("...id,...jd->...ij", kb, k) * gamma, 0.0)
-    eye = jnp.eye(chunk, dtype=a.dtype)
     rhs = jnp.concatenate([v * beta[..., None],
                            kb * jnp.exp(gc)[..., None]], axis=-1)
-    uw = jax.lax.linalg.triangular_solve(
-        a + eye, rhs, left_side=True, lower=True, unit_diagonal=True)
+    uw = _mm_highest("...ij,...jd->...id", _unit_lower_inverse(a), rhs)
     u, w = uw[..., :v.shape[-1]], uw[..., v.shape[-1]:]
     p = _mm("...id,...jd->...ij", q, k) * gamma
     qe = q * jnp.exp(gc)[..., None]

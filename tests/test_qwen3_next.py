@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.test_util import check_grads
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -172,22 +173,77 @@ def test_the_chunked_rule_is_the_recurrence(seq, decay):
     last chunk padded), with g near 0 (the state lasts the sequence), g
     strongly negative (each chunk nearly forgets the last) and both:
     o and the gradients of all five inputs."""
-    args = _rule_inputs(seq, decay)
+    _check_against_recurrence(_rule_inputs(seq, decay), 16)
+
+
+def test_a_chunk_that_is_no_power_of_two_is_the_recurrence():
+    """A chunk of 24: the inverse's last block of each level is cut short.
+    o and the gradients of all five inputs, over 100 positions (the last
+    chunk padded)."""
+    _check_against_recurrence(_rule_inputs(100, "mixed"), 24)
+
+
+def _check_against_recurrence(args, chunk):
     weights = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got = dr.chunked_delta_rule(*args, chunk=16)
+        got = dr.chunked_delta_rule(*args, chunk=chunk)
         want = _recurrence(*args)
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
         grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * weights),
                           argnums=range(5))(*args)
-                 for f in (functools.partial(dr.chunked_delta_rule, chunk=16),
-                           _recurrence)]
+                 for f in (functools.partial(dr.chunked_delta_rule,
+                                             chunk=chunk), _recurrence)]
+    seq = args[0].shape[1]
     for name, g_got, g_want in zip("q k v g beta".split(), *grads):
         scale = float(jnp.abs(g_want).max())
         # one position: the decay meets a zero state and has no gradient
         assert scale > 0 or (name, seq) == ("g", 1), name
         assert float(jnp.abs(g_got - g_want).max()) <= 3e-5 * scale, name
+
+
+def _chunk_a(chunk, keys, dtype):
+    """A = strictly_lower(diag(beta) K K^T * Gamma) of one chunk, as the
+    rule forms it, for two heads: keys random, or sharing one direction
+    at 4 x the noise with beta near 1 (A's entries then near 1)."""
+    rng = np.random.default_rng(chunk)
+    k = rng.normal(size=(2, chunk, 16))
+    if keys == "correlated":
+        k = k + 4.0 * rng.normal(size=(2, 1, 16))
+        beta = rng.uniform(0.9, 1.0, (2, chunk))
+    else:
+        beta = rng.uniform(0.0, 1.0, (2, chunk))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    gc = np.cumsum(-rng.uniform(0.0, 0.1, (2, chunk)), axis=-1)
+    gamma = np.exp(gc[:, :, None] - gc[:, None, :])
+    a = np.tril(beta[:, :, None] * (k @ k.transpose(0, 2, 1)) * gamma, -1)
+    return jnp.asarray(a, dtype)
+
+
+@pytest.mark.parametrize("keys", ["random", "correlated"])
+@pytest.mark.parametrize("chunk", [8, 16, 24, 64], ids=lambda c: f"c{c}")
+def test_the_chunk_inverse_is_the_inverse_and_so_is_its_derivative(chunk,
+                                                                   keys):
+    """T = (I + A)^-1 by block doubling (at 24 the last block of each
+    level is cut short) against numpy's inverse in float64, every
+    product of it and of its derivative at HIGHEST; the derivative dT =
+    -T dA T against finite differences, forward and reverse, of A's
+    strictly lower part (the only part the rule ever gives it)."""
+    a = _chunk_a(chunk, keys, jnp.float32)
+    want = np.linalg.inv(np.eye(chunk) + np.asarray(a, np.float64))
+    got = np.asarray(dr._unit_lower_inverse(a), np.float64)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+    text = jax.jit(lambda a: jax.jvp(dr._unit_lower_inverse, (a,),
+                                     (a,))).lower(a).as_text()
+    products = [line for line in text.splitlines() if "dot_general" in line]
+    assert len(products) == 2 * (chunk - 1).bit_length()
+    assert all("precision = [HIGHEST, HIGHEST]" in p for p in products)
+    with jax.enable_x64(True):
+        check_grads(
+            lambda a: dr._unit_lower_inverse(jnp.tril(a, -1)),
+            (_chunk_a(chunk, keys, jnp.float64),), order=1,
+            modes=("fwd", "rev"))
 
 
 def test_the_rule_s_chunk_is_its_own_business():
